@@ -98,7 +98,7 @@ def main() -> dict:
     # serve-tick megakernel (interpret) vs the quantized reference tick,
     # timed as the jitted q32 twin (the same integer numerics as XLA)
     from benchmarks.fleet_megakernel import _serve_tick_fixture
-    tick_pallas, tick_q32, agree = _serve_tick_fixture(nw)
+    tick_pallas, tick_q32, agree = _serve_tick_fixture(nw, interpret=True)
     emit("kernels.serve_tick_agrees_q32", 0.0, str(agree))
     emit("kernels.serve_tick_q32_twin_8k", timeit(tick_q32), "one tick")
     emit("kernels.serve_tick_interpret_8k", timeit(tick_pallas),

@@ -111,5 +111,17 @@ def host_metadata() -> dict:
     }
 
 
+def require_pallas_target(interpret: bool) -> None:
+    """Refuse to run the compiled Pallas kernels where JAX finds no TPU.
+    Timing the Pallas interpreter is asked for with ``--interpret``;
+    it is never chosen for the caller."""
+    import jax
+    if not interpret and jax.default_backend() != "tpu":
+        raise SystemExit(
+            f"the Pallas kernels are compiled for the TPU and JAX runs on "
+            f"{jax.default_backend()!r}; pass --interpret to run them "
+            f"through the Pallas interpreter instead")
+
+
 def emit(name: str, us_per_call: float, derived: str):
     print(f"{name},{us_per_call:.1f},{derived}")

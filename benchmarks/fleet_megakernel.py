@@ -20,8 +20,9 @@ Claims checked:
   memory-bound, which is why fusing the ~70-op jnp chain into one
   VMEM-resident pass is the right lever.
 
-    python -m benchmarks.fleet_megakernel                # full gate
-    python -m benchmarks.fleet_megakernel --sizes 1024   # quick look
+    python -m benchmarks.fleet_megakernel                # full gate, TPU
+    python -m benchmarks.fleet_megakernel --interpret --sizes 1024
+                                             # quick look without a TPU
 
 JSON lands in experiments/fleet_megakernel.json; docs/experiments.md
 documents the schema, docs/kernels.md the dtype/quantization contract.
@@ -33,7 +34,8 @@ import json
 import time
 from pathlib import Path
 
-from benchmarks.common import emit, host_metadata, timeit_split
+from benchmarks.common import (emit, host_metadata,
+                               require_pallas_target, timeit_split)
 from benchmarks.fleet_throughput import (DT, MIX, PERIOD_S, TRACES,
                                          _quant_agreement, _workloads)
 from benchmarks.roofline import serve_tick_roofline
@@ -44,7 +46,8 @@ KERNELS = ("xla", "q32", "pallas")
 
 
 def _serve_runner(n: int, duration_s: float, kernel: str, seed: int = 0,
-                  charge_frac: float = 0.9, mesh_fleet: int = 1):
+                  charge_frac: float = 0.9, mesh_fleet: int = 1,
+                  interpret: bool = False):
     """A zero-arg callable running the full fused serve launch; reset
     between calls so every invocation after the first is the warm
     compiled scan over fresh state.
@@ -65,7 +68,7 @@ def _serve_runner(n: int, duration_s: float, kernel: str, seed: int = 0,
     power = make_power_matrix(TRACES, min(32, n), duration_s, DT, seed)
     wls = _workloads()
     pool = build_dispatch_pool(power, DT, n, wls, seed, backend="jax",
-                               kernel=kernel)
+                               kernel=kernel, interpret=interpret)
     sched = FleetScheduler(pool, wls, sched="reactive",
                            shards=mesh_fleet)
     stream = RequestStream(n / PERIOD_S, MIX, n_steps, DT, seed=seed + 1)
@@ -92,11 +95,13 @@ def _serve_runner(n: int, duration_s: float, kernel: str, seed: int = 0,
     return run, out
 
 
-def _serve_tick_fixture(n: int, seed: int = 0):
+def _serve_tick_fixture(n: int, seed: int = 0, *, interpret: bool):
     """One-tick fixture for the kernel sweep (benchmarks/bench_kernels):
     a charged quantized fleet mid-serve. Returns zero-arg callables
-    running one Pallas-interpret tick and one jitted q32-twin tick over
-    the same state, plus their exact-agreement bit."""
+    running one Pallas tick (through the interpreter if ``interpret``)
+    and one jitted q32-twin tick over the same state, plus their
+    exact-agreement bit."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -117,23 +122,22 @@ def _serve_tick_fixture(n: int, seed: int = 0):
     s.p_wl = rng.integers(0, 3, n).astype(np.int32)
     s.p_units = rng.integers(1, 4, n).astype(np.int32)
     s.p_batch = rng.integers(1, 4, n).astype(np.int32)
-    import jax
-    from jax.experimental import enable_x64
 
-    bk_p = JaxFleetBackend(pool.params, kernel="pallas")
+    bk_p = JaxFleetBackend(pool.params, kernel="pallas",
+                           interpret=interpret)
     bk_q = JaxFleetBackend(pool.params, kernel="q32")
-    with enable_x64():
+    with jax.enable_x64(True):
         st = tuple(jnp.asarray(getattr(s, f)) for f in STATE_FIELDS)
         ev0 = tuple(jnp.zeros(n, jnp.int32) for _ in range(4))
         i = jnp.asarray(7, jnp.int64)
         tq = jax.jit(lambda st, ev: bk_q._tick_q(st, ev, i))
 
     def tick_pallas():
-        with enable_x64():
+        with jax.enable_x64(True):
             return bk_p._tick_pallas(st, ev0, i)
 
     def tick_q32():
-        with enable_x64():
+        with jax.enable_x64(True):
             return tq(st, ev0)
 
     (st_p, ev_p), (st_q, ev_q) = tick_pallas(), tick_q32()
@@ -144,7 +148,7 @@ def _serve_tick_fixture(n: int, seed: int = 0):
 
 def kernel_scaling(sizes=SIZES, duration_s: float = 10.0,
                    iters: int = 2, seed: int = 0,
-                   mesh_fleet: int = 1) -> dict:
+                   mesh_fleet: int = 1, interpret: bool = False) -> dict:
     """Warm wall-clock per kernel per fleet size (cold includes the
     one-off serve-scan trace+compile). ``mesh_fleet > 1`` shards the
     serve scan K ways (docs/sharded_fleet.md) — the Pallas megakernel
@@ -156,7 +160,8 @@ def kernel_scaling(sizes=SIZES, duration_s: float = 10.0,
         per: dict = {}
         for kernel in kernels:
             run, out = _serve_runner(n, duration_s, kernel, seed,
-                                     mesh_fleet=mesh_fleet)
+                                     mesh_fleet=mesh_fleet,
+                                     interpret=interpret)
             split = timeit_split(run, iters=iters)
             split["completed"] = out["summary"]["completed"]
             per[kernel] = split
@@ -182,13 +187,22 @@ def main(argv: list[str] | None = None) -> dict:
                     help="shard the timed serve scans K ways over the "
                          "fleet mesh (drops the single-device Pallas "
                          "column; K must divide every --sizes entry)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernel through the Pallas "
+                         "interpreter (a correctness artifact off the "
+                         "TPU, not a kernel timing); without it the "
+                         "benchmark refuses to run where JAX finds no "
+                         "TPU")
     args = ap.parse_args(argv)
     sizes = tuple(int(s) for s in args.sizes.split(","))
+    require_pallas_target(args.interpret)
 
     t0 = time.perf_counter()
-    agree = _quant_agreement(256, 30.0, 16, kernel="pallas")
+    agree = _quant_agreement(256, 30.0, 16, kernel="pallas",
+                             interpret=args.interpret)
     scaling = kernel_scaling(sizes, args.duration, args.iters,
-                             mesh_fleet=args.mesh_fleet)
+                             mesh_fleet=args.mesh_fleet,
+                             interpret=args.interpret)
     total = time.perf_counter() - t0
 
     res = {
@@ -209,6 +223,7 @@ def main(argv: list[str] | None = None) -> dict:
                        "measured CPU speedup of the quantized tick.",
         "duration_s": args.duration,
         "mesh_fleet": args.mesh_fleet,
+        "pallas_interpret": args.interpret,
         "host": host_metadata(),
     }
     us = total * 1e6 / max(len(sizes) * len(KERNELS), 1)

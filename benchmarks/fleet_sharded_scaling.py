@@ -4,10 +4,11 @@ Claims checked (see docs/sharded_fleet.md):
 - the sharded serve scan (``--mesh-fleet K``) carries one *logical*
   launch to >=1M workers: the worker-scaling curve records warm
   ticks/s and worker-ticks/s per fleet size for K=1 (the unsharded
-  scan) and K=8 (shard_map over a forced-host-device CPU mesh — the
-  benchmark re-execs itself with
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` when fewer
-  devices exist);
+  scan) and K=8 (shard_map over a forced-host-device CPU mesh: before
+  jax is imported the benchmark sets
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=8``, which only the
+  CPU platform reads; K never exceeds the devices that exist, so a
+  four-chip TPU host runs K=4);
 - cross-shard work stealing earns its keep on a *skewed* fleet: with
   shards 0..K/2-1 pinned to occluded mobile solar (SIM) and the rest
   to rich outdoor solar (SOR), the rebalance-on run completes more
@@ -36,21 +37,20 @@ SIZES = (16384, 131072, 1048576)
 MESHES = (1, 8)
 
 
-def _reexec_with_devices(k: int) -> None:
-    """Restart the interpreter with K forced host devices when the
-    current process has fewer — XLA fixes the device count at backend
-    init, so the flag must be in the environment before jax wakes up."""
+def _mesh_sizes(k: int) -> tuple[int, ...]:
+    """The mesh sizes to measure: 1 and up to ``k`` devices. XLA fixes
+    the host device count when its backend starts, so K forced CPU
+    devices are requested only while jax is not yet imported; the
+    flag is ignored by accelerator platforms. Never asks for more
+    devices than exist."""
+    if "jax" not in sys.modules:
+        flags = os.environ.get("XLA_FLAGS", "")
+        if "xla_force_host_platform_device_count" not in flags:
+            os.environ["XLA_FLAGS"] = (
+                f"{flags} --xla_force_host_platform_device_count={k}"
+                .strip())
     import jax
-
-    if jax.device_count() >= k or os.environ.get("_SHARDED_SCALING_EXEC"):
-        return
-    flags = os.environ.get("XLA_FLAGS", "")
-    os.environ["XLA_FLAGS"] = (
-        f"{flags} --xla_force_host_platform_device_count={k}".strip())
-    os.environ["_SHARDED_SCALING_EXEC"] = "1"
-    os.execv(sys.executable, [sys.executable, "-m",
-                              "benchmarks.fleet_sharded_scaling",
-                              *sys.argv[1:]])
+    return tuple(sorted({1, min(k, jax.device_count())}))
 
 
 def scaling_curve(sizes=SIZES, meshes=MESHES, duration_s: float = 1.0,
@@ -87,7 +87,8 @@ def rebalance_delta(n: int = 1024, k: int = 8, duration_s: float = 60.0,
     """Completed-request delta of cross-shard work stealing on an
     occlusion-skewed fleet: shards 0..K/2-1 harvest occluded mobile
     solar (SIM), shards K/2..K-1 rich outdoor solar (SOR) — same
-    stream, same workers, only the rebalance cadence changes."""
+    stream, same workers, only the rebalance cadence changes. The
+    shards run on a K-device mesh, so K must not exceed the devices."""
     import numpy as np
 
     from benchmarks.fleet_throughput import DT, MIX, PERIOD_S, _workloads
@@ -140,27 +141,31 @@ def main(argv: list[str] | None = None) -> dict:
                     help="quick look: 4096 workers, rebalance delta at "
                          "N=512 over 30 simulated seconds")
     args = ap.parse_args(argv or sys.argv[1:])
-    _reexec_with_devices(max(MESHES))
+    meshes = _mesh_sizes(max(MESHES))
 
     from benchmarks.common import emit, host_metadata
 
     sizes = ((4096,) if args.smoke
              else tuple(int(s) for s in args.sizes.split(",")))
     t0 = time.perf_counter()
-    curve = scaling_curve(sizes, MESHES, args.duration, args.iters)
-    delta = (rebalance_delta(512, 8, 30.0) if args.smoke
-             else rebalance_delta())
+    curve = scaling_curve(sizes, meshes, args.duration, args.iters)
+    # work stealing needs two shards; each runs on a device of its own
+    kmax = max(meshes)
+    delta = (None if kmax < 2
+             else rebalance_delta(512, kmax, 30.0) if args.smoke
+             else rebalance_delta(k=kmax))
     total = time.perf_counter() - t0
     res = {"scaling": curve, "rebalance": delta,
-           "mesh_sizes": list(MESHES), "duration_s": args.duration,
+           "mesh_sizes": list(meshes), "duration_s": args.duration,
            "host": host_metadata()}
-    us = total * 1e6 / max(len(sizes) * len(MESHES) + 2, 1)
+    us = total * 1e6 / max(len(sizes) * len(meshes) + 2, 1)
     top = str(max(int(x) for x in curve))
-    for k in MESHES:
+    for k in meshes:
         emit(f"fleet.sharded_worker_ticks_per_s_at_{top}_k{k}", us,
              f"{curve[top][str(k)]['worker_ticks_per_s']:.2e}")
-    emit("fleet.sharded_rebalance_completed_delta", us,
-         str(delta["completed_delta"]))
+    if delta is not None:
+        emit("fleet.sharded_rebalance_completed_delta", us,
+             str(delta["completed_delta"]))
     if not args.smoke:
         out = Path("experiments")
         out.mkdir(exist_ok=True)

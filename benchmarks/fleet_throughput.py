@@ -60,7 +60,8 @@ from pathlib import Path
 
 import numpy as np
 
-from benchmarks.common import emit, host_metadata
+from benchmarks.common import (emit, host_metadata,
+                               require_pallas_target)
 from repro.core.energy import power_matrix
 from repro.core.forecast import FAMILY_FORECASTER, FORECASTER_MODES
 from repro.launch.fleet import (hetero_capacitors, make_power_matrix,
@@ -570,11 +571,12 @@ def run_control_plane_suite(n_workers: int = 1024,
 
 
 def _quant_agreement(n_workers: int, duration_s: float, n_rows: int,
-                     seed: int = 0, kernel: str = "pallas") -> dict:
+                     seed: int = 0, kernel: str = "pallas",
+                     interpret: bool = False) -> dict:
     """One definition of *kernel* agreement: the float64 XLA serve scan,
     the int32-quantized pure-XLA twin (``q32``), the NumPy quantized
-    reference driver, and the fused Pallas megakernel (interpret mode on
-    CPU) all serve the same stream over one trace bank. The three
+    reference driver, and the fused Pallas megakernel (through the
+    Pallas interpreter when ``interpret``) all serve the same stream over one trace bank. The three
     quantized paths trace the same integer tick (``repro.fleet.qtick``)
     and must agree EXACTLY on every request-lifecycle counter; the
     float64 reference must agree within the pinned quantization
@@ -591,7 +593,8 @@ def _quant_agreement(n_workers: int, duration_s: float, n_rows: int,
                              ("jax_kernel", "jax", kernel)):
         res[name] = run_scheduled(power, DT, n_workers, _workloads(),
                                   rate_rps=rate, mix=MIX, n_steps=n_steps,
-                                  seed=seed, backend=backend, kernel=k)
+                                  seed=seed, backend=backend, kernel=k,
+                                  interpret=interpret)
     qpaths = ("numpy_q32", "jax_q32", "jax_kernel")
     exact = all(res[a][k] == res[qpaths[0]][k]
                 for a in qpaths[1:] for k in _COUNT_KEYS)
@@ -639,7 +642,7 @@ def _sharded_agreement(n_workers: int, duration_s: float, n_rows: int,
     n_steps = int(duration_s / DT)
     rate = n_workers / PERIOD_S
     has_mesh = jax.device_count() >= mesh_fleet
-    runs = [("numpy_twin", "numpy", "auto"),
+    runs = [("numpy_twin", "numpy", "mesh"),
             ("jax_single", "jax", "single")]
     if has_mesh:
         runs.append(("jax_mesh", "jax", "mesh"))
@@ -710,7 +713,7 @@ def _stream_agreement(n_workers: int, duration_s: float, n_rows: int,
                       chunk_ticks: int, *, backend: str = "jax",
                       kernel: str = "xla", mesh_fleet: int = 1,
                       rebalance_every_s: float = 0.0,
-                      fleet_placement: str = "auto",
+                      fleet_placement: str = "mesh",
                       seed: int = 0) -> dict:
     """Whole-trace vs chunked-stream bit-equality for one config: the
     same pool/scheduler/arrival world served as a single launch and as
@@ -828,7 +831,7 @@ def run_persist_smoke(persist: str, n_workers: int = 128,
 
 
 def run_smoke(n_workers: int = 256, duration_s: float = 30.0,
-              kernel: str = "xla") -> dict:
+              kernel: str = "xla", interpret: bool = False) -> dict:
     """CI gate: short shared trace, both backends, counts must match
     exactly (exercises the scan path on interpret-mode-only hosts) —
     for the local-mode pools, the fused forecast control plane, the
@@ -839,7 +842,10 @@ def run_smoke(n_workers: int = 256, duration_s: float = 30.0,
     other (exact) and against the float64 reference (pinned
     tolerance)."""
     if kernel != "xla":
-        kres = _quant_agreement(n_workers, duration_s, 16, kernel=kernel)
+        if kernel == "pallas":
+            require_pallas_target(interpret)
+        kres = _quant_agreement(n_workers, duration_s, 16, kernel=kernel,
+                                interpret=interpret)
         if not (kres["quantized_counts_exact"]
                 and kres["f64_within_tolerance"]):
             print(json.dumps(kres, indent=1), file=sys.stderr)
@@ -957,7 +963,11 @@ def main(argv: list[str] | None = None) -> dict:
                     help="serve-tick kernel the --smoke gate exercises: "
                          "the float64 XLA chain (xla), the quantized "
                          "int32 XLA twin (q32), or the fused Pallas "
-                         "megakernel (pallas; interpret mode on CPU)")
+                         "megakernel (pallas; compiled for the TPU "
+                         "unless --interpret)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas megakernel through the Pallas "
+                         "interpreter (for hosts without a TPU)")
     ap.add_argument("--stream", action="store_true",
                     help="with --smoke: run the streaming gate instead "
                          "— chunked ``--stream`` serve must be "
@@ -982,7 +992,7 @@ def main(argv: list[str] | None = None) -> dict:
             return run_sharded_smoke(
                 mesh_fleet=args.mesh_fleet,
                 rebalance_every_s=args.rebalance_every)
-        return run_smoke(kernel=args.kernel)
+        return run_smoke(kernel=args.kernel, interpret=args.interpret)
     if args.forecasters:
         return run_forecaster_suite(backend=args.backend)
     if args.control_plane:

@@ -11,14 +11,16 @@ step would compute, with the data-dependent unit loop as a fleet-wide
 ``n_ticks`` is a single ``lax.scan`` over that step — 100k+ workers fit
 one accelerator launch instead of 100k Python-object updates per tick.
 
-Numerical contract: under ``jax.experimental.enable_x64`` every operation
-runs in IEEE double like the NumPy reference. XLA:CPU contracts
-multiply-add chains into FMAs (not disableable via flags as of jax
-0.4.37), so capacitor *voltages* can drift from NumPy by ~1 ulp; every
-discrete outcome — emitted / skipped / acquired / power-cycle counts,
-drawn energies, emission times — agrees exactly on shared traces because
-threshold comparisons sit ulps away from the knife edge with probability
-~1e-13 per event (tests/test_fleet_backends.py pins count equality).
+Numerical contract: under ``jax.enable_x64(True)`` every operation runs
+in IEEE double on the CPU, like the NumPy reference. XLA:CPU contracts
+multiply-add chains into FMAs, so capacitor *voltages* can drift from
+NumPy by ~1 ulp; every discrete outcome — emitted / skipped / acquired /
+power-cycle counts, drawn energies, emission times — agrees exactly on
+shared traces because threshold comparisons sit ulps away from the knife
+edge with probability ~1e-13 per event (tests/test_fleet_backends.py pins
+count equality). XLA:TPU emulates float64 and does not round as IEEE
+does, so on the chip that agreement is measured (``chip_smoke.py``), not
+promised; the int32 ``q32``/``pallas`` ticks do not depend on it.
 
 Events (dispatch mode) are materialized as fixed-capacity (N,) arrays —
 code / time / ticket / units per worker — instead of Python tuple lists.
@@ -35,7 +37,7 @@ same tick they occur and never reach the host at all.
 
 Optionally the harvest stage runs through the Pallas capacitor-bank
 kernel (``repro.kernels.fleet_step``) — the TPU fast path; interpret mode
-keeps it testable on CPU-only environments.
+(``interpret=True``) keeps it testable on CPU-only environments.
 
 ``kernel`` selects the device-tick numerics/implementation:
 
@@ -43,9 +45,10 @@ keeps it testable on CPU-only environments.
 - ``"q32"`` — the int32 quantized tick (``repro.fleet.qtick``) traced
   as pure XLA: same scan, integer energy quanta, no sqrt;
 - ``"pallas"`` — the same quantized tick fused into one VMEM-resident
-  Pallas pass per tick (``repro.kernels.serve_tick``), compiled on TPU
-  and interpret-mode (still pure XLA, still bit-exact vs ``q32``) on
-  CPU. Quantized kernels are dispatch-mode only and need a quantized
+  Pallas pass per tick (``repro.kernels.serve_tick``), compiled for the
+  TPU, or interpret-mode (still pure XLA, still bit-exact vs ``q32``)
+  when the caller passes ``interpret=True``, as the CPU tests do.
+  Quantized kernels are dispatch-mode only and need a quantized
   ``FleetState`` (``init_state(n, quantized=True)``) plus
   ``FleetParams.quantum_j`` — ``FleetWorkerPool(kernel=...)`` wires all
   three.
@@ -61,7 +64,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.energy import (capacitor_draw, capacitor_harvest,
                                capacitor_usable_energy,
@@ -82,18 +84,21 @@ class JaxFleetBackend:
     """Compiled scan runner for one ``FleetParams`` configuration."""
 
     def __init__(self, params: FleetParams, *, use_pallas: bool = False,
-                 kernel: str = "xla", fleet_placement: str = "auto"):
+                 kernel: str = "xla", fleet_placement: str = "mesh",
+                 interpret: bool = False):
         self.p = params
         self.use_pallas = use_pallas
         self.kernel = kernel
         self.fleet_placement = fleet_placement
-        self.interpret = jax.default_backend() != "tpu"
+        # Pallas kernels run interpreted only when the caller asks (CPU
+        # tests); compiled otherwise, so a host without a TPU fails loudly
+        self.interpret = interpret
         if kernel not in ("xla", "q32", "pallas"):
             raise ValueError(f"unknown kernel {kernel!r}")
-        if fleet_placement not in ("auto", "mesh", "single"):
+        if fleet_placement not in ("mesh", "single"):
             raise ValueError(
                 f"unknown fleet_placement {fleet_placement!r} "
-                "(auto | mesh | single)")
+                "(mesh | single)")
         if kernel != "xla":
             if params.mode != "dispatch":
                 raise ValueError(
@@ -123,7 +128,7 @@ class JaxFleetBackend:
                     f"policy {type(params.policy).__name__}'s decide_batch "
                     "cannot run under jax tracing; the jax backend needs "
                     "an xp-aware closed form (see core.policies)")
-        with enable_x64():
+        with jax.enable_x64(True):
             self.power = jnp.asarray(params.power)
             self.trace_index = jnp.asarray(params.trace_index)
             self.phase = (None if params.phase is None
@@ -171,7 +176,7 @@ class JaxFleetBackend:
         host-side state and decoded dispatch events (empty in local mode).
         """
         p = self.p
-        with enable_x64():
+        with jax.enable_x64(True):
             st = tuple(jnp.asarray(x) for x in state_as_tuple(state))
             n = p.n
             if self.kernel == "xla":
@@ -276,11 +281,11 @@ class JaxFleetBackend:
         if not S.sched_params_compatible(self._serve_sp, sp):
             self._serve_compiled = {}
         self._serve_sp = sp
-        with enable_x64():
+        with jax.enable_x64(True):
             fs = tuple(jnp.asarray(x) for x in state_as_tuple(state))
             ss = tuple(jnp.asarray(x)
                        for x in sched_state_as_tuple(sched_state))
-            pw = {f: jnp.asarray(getattr(sp, f)) for f in S.FC_FIELDS}
+            pw = self._worker_inputs(sp)
             fn = self._serve_compiled.get(key)
             if fn is None:
                 fn = self._build_serve(sp, n_ticks, int(dispatch_every),
@@ -317,7 +322,7 @@ class JaxFleetBackend:
         host driver's) and cached on device."""
         if self._pow_cs is None:
             from repro.obs.telemetry import power_cumsum
-            with enable_x64():
+            with jax.enable_x64(True):
                 self._pow_cs = jnp.asarray(
                     power_cumsum(np.asarray(self.p.power)))
         return self._pow_cs
@@ -445,15 +450,17 @@ class JaxFleetBackend:
         obs_cs = (self._power_cumsum()
                   if op is not None and sp.forecast else None)
 
-        # the FC_* forecast tables arrive as the runtime `pw` dict (the
-        # streaming loop's causal refits swap them between chunks without
-        # re-tracing); the body closure is built inside the traced
-        # function so the scheduler passes read the traced tables, while
-        # every other SchedParams field stays a baked constant
+        # the per-worker tables (FC_* forecasts included) arrive as the
+        # runtime `pw` dict: the streaming loop's causal refits swap the
+        # forecasts between chunks without re-tracing, and (N,)-sized
+        # arrays baked in as constants would cost the TPU compiler
+        # minutes of constant folding. The body closure is built inside
+        # the traced function so the passes read the traced tables; the
+        # small workload tables stay baked constants
         def make_body(pw):
-            spt = dataclasses.replace(sp, **pw)
-            return self._serve_body(self, spt, dispatch_every, op=op,
-                                    obs_cs=obs_cs)
+            return self._serve_body(self._view(pw, self.p.n),
+                                    dataclasses.replace(sp, **pw["sp"]),
+                                    dispatch_every, op=op, obs_cs=obs_cs)
 
         if op is None:
             def serve_fn(fs, ss, pw, arr, i0):
@@ -477,16 +484,6 @@ class JaxFleetBackend:
 
     # -- sharded serve scan (--mesh-fleet K: shard_map over the fleet axis) --
 
-    def _resolve_placement(self, k: int) -> bool:
-        """True -> real K-device mesh (``shard_map``), False -> the
-        single-device ``vmap`` evaluation of the same K-shard program
-        (bit-identical by construction; see docs/sharded_fleet.md)."""
-        if self.fleet_placement == "mesh":
-            return True
-        if self.fleet_placement == "single":
-            return False
-        return jax.device_count() >= k
-
     def _run_serve_sharded(self, state: FleetState, sp: SchedParams,
                            sched_state: SchedState, arrivals, *, i0,
                            dispatch_every, obs):
@@ -494,10 +491,11 @@ class JaxFleetBackend:
         split into K contiguous row-shards, each with its own control
         plane (per-shard ring queues, ``max_queue // K`` admission),
         and the whole K-shard program runs as ONE logical launch —
-        ``shard_map`` over a ``(fleet,)`` mesh when K devices exist,
-        otherwise a ``vmap`` with the same named axis. The two
-        placements (and the NumPy host twin) are bit-identical: the
-        shard split is semantic, the placement is not."""
+        ``shard_map`` over a K-device ``(fleet,)`` mesh, or, only when
+        ``fleet_placement="single"`` asks for it, a one-device ``vmap``
+        with the same named axis. The two placements (and the NumPy
+        host twin) are bit-identical: the shard split is semantic, the
+        placement is not."""
         from repro.fleet import sched as S
         p = self.p
         K = sp.shards
@@ -518,7 +516,7 @@ class JaxFleetBackend:
                 f"positive multiple of dispatch_every={dispatch_every}: "
                 "the work-stealing exchange runs inside the dispatch "
                 "pass")
-        use_mesh = self._resolve_placement(K)
+        use_mesh = self.fleet_placement == "mesh"
         arrivals = np.asarray(arrivals, dtype=np.int64)
         n_ticks = arrivals.shape[0]
         arr = S.split_counts(arrivals, K)  # (K, n_ticks, W)
@@ -534,24 +532,13 @@ class JaxFleetBackend:
             a = np.asarray(x)
             return np.ascontiguousarray(a.reshape((K, ns) + a.shape[1:]))
 
-        with enable_x64():
+        with jax.enable_x64(True):
             fs = tuple(jnp.asarray(resh(x))
                        for x in state_as_tuple(state))
             ss = tuple(jnp.asarray(x)  # already stacked (K, ...)
                        for x in sched_state_as_tuple(sched_state))
             sh = {"fs": fs, "ss": ss, "arr": jnp.asarray(arr),
-                  "ti": jnp.asarray(resh(p.trace_index)),
-                  "ph": jnp.asarray(resh(p.phase)
-                                    if p.phase is not None
-                                    else np.zeros((K, ns), np.int64)),
-                  "C": jnp.asarray(resh(p.C)),
-                  "v_max": jnp.asarray(resh(p.v_max)),
-                  "AP": jnp.asarray(resh(p.active_power_w)),
-                  "sp": {f: jnp.asarray(resh(getattr(sp, f)))
-                         for f in S.PER_WORKER_FIELDS}}
-            if self.kernel != "xla":
-                sh["qp"] = {f: jnp.asarray(resh(getattr(self._qp, f)))
-                            for f in ("E_ON", "E_OFF", "E_MAX", "ESTEP")}
+                  **self._worker_inputs(sp, resh)}
             fn = self._serve_compiled.get(key)
             if fn is None:
                 fn = self._build_serve_sharded(sp, n_ticks,
@@ -559,6 +546,10 @@ class JaxFleetBackend:
                                                use_mesh)
                 self._serve_compiled[key] = fn
             out = fn(sh, jnp.asarray(i0, jnp.int64))
+            spanned = len(jax.tree.leaves(out)[0].sharding.device_set)
+            if use_mesh and spanned != K:
+                raise RuntimeError(
+                    f"the {K}-shard mesh serve ran on {spanned} device(s)")
             if op is None:
                 fs, ss = out
             else:
@@ -580,12 +571,9 @@ class JaxFleetBackend:
         from jax.sharding import PartitionSpec as P
 
         from repro.fleet import sched as S
-        from repro.sharding.context import (FLEET_AXIS, make_fleet_mesh,
-                                            shard_map_compat)
-        p = self.p
+        from repro.sharding.context import FLEET_AXIS, make_fleet_mesh
         K = sp.shards
-        ns = p.n // K
-        quant = self.kernel != "xla"
+        ns = self.p.n // K
         obs_cs = (self._power_cumsum()
                   if op is not None and sp.forecast else None)
         if op is not None:
@@ -595,17 +583,8 @@ class JaxFleetBackend:
 
         def per_shard(sh, i0):
             # the shard view: same backend methods, per-worker constants
-            # swapped for this shard's contiguous rows (phase=0 rows are
-            # synthesized when global phase is None: (i+0)%T == i%T)
-            view = copy.copy(self)
-            view.p = dataclasses.replace(p, n=ns)
-            view.trace_index = sh["ti"]
-            view.phase = sh["ph"]
-            view.C = sh["C"]
-            view.v_max = sh["v_max"]
-            view.AP = sh["AP"]
-            if quant:
-                view._qp = dataclasses.replace(self._qp, **sh["qp"])
+            # swapped for this shard's contiguous rows
+            view = self._view(sh, ns)
             sps = S.shard_sched_params(sp, per_worker=sh["sp"])
 
             rebalance = None
@@ -653,13 +632,50 @@ class JaxFleetBackend:
                 out = per_shard(jax.tree.map(lambda x: x[0], sh), i0)
                 return jax.tree.map(lambda x: x[None], out)
 
-            mapped = shard_map_compat(shard_fn, mesh=mesh,
-                                      in_specs=(P(FLEET_AXIS), P()),
-                                      out_specs=P(FLEET_AXIS))
+            mapped = jax.shard_map(shard_fn, mesh=mesh,
+                                   in_specs=(P(FLEET_AXIS), P()),
+                                   out_specs=P(FLEET_AXIS), check_vma=False)
         else:
             mapped = jax.vmap(per_shard, in_axes=(0, None),
                               axis_name=FLEET_AXIS)
         return jax.jit(mapped)
+
+    # per-worker (N,) arrays the tick and the control plane read: they
+    # enter the compiled serve programs as runtime inputs
+    _QP_WORKER_FIELDS = ("E_ON", "E_OFF", "E_MAX", "ESTEP")
+
+    def _worker_inputs(self, sp: SchedParams, resh=None) -> dict:
+        """The per-worker runtime inputs of a serve program, as device
+        arrays; ``resh`` reshapes each (N, ...) host array first (the
+        sharded build's (K, N/K, ...) split). A ``None`` phase becomes
+        zeros: ``(i + 0) % T == i % T``."""
+        from repro.fleet import sched as S
+        p = self.p
+        wk = {"ti": p.trace_index,
+              "ph": (np.zeros(p.n, np.int64) if p.phase is None
+                     else p.phase),
+              "C": p.C, "v_max": p.v_max, "AP": p.active_power_w,
+              "sp": {f: getattr(sp, f) for f in S.PER_WORKER_FIELDS}}
+        if self.kernel != "xla":
+            wk["qp"] = {f: getattr(self._qp, f)
+                        for f in self._QP_WORKER_FIELDS}
+        return jax.tree.map(lambda x: jnp.asarray(
+            np.asarray(x) if resh is None else resh(x)), wk)
+
+    def _view(self, wk: dict, n: int) -> "JaxFleetBackend":
+        """A shallow copy of this backend whose per-worker constants are
+        the traced rows of ``wk`` (from :meth:`_worker_inputs`) for
+        ``n`` workers: the tick methods then read them unchanged."""
+        view = copy.copy(self)
+        view.p = dataclasses.replace(self.p, n=n)
+        view.trace_index = wk["ti"]
+        view.phase = wk["ph"]
+        view.C = wk["C"]
+        view.v_max = wk["v_max"]
+        view.AP = wk["AP"]
+        if self.kernel != "xla":
+            view._qp = dataclasses.replace(self._qp, **wk["qp"])
+        return view
 
     def _usable(self, v):
         return capacitor_usable_energy(v, capacitance_f=self.C,
@@ -701,9 +717,10 @@ class JaxFleetBackend:
 
     def _tick_pallas(self, st, ev, i):
         """Quantized tick as one fused Pallas pass per tick
-        (``repro.kernels.serve_tick``): compiled on TPU, interpret-mode
-        on CPU. The kernel emits a fresh event log; it is merged into
-        the carried log first-event-wins so macro-step runs keep the
+        (``repro.kernels.serve_tick``): compiled for the TPU, or
+        interpret-mode when the backend was built with ``interpret``.
+        The kernel emits a fresh event log; it is merged into the carried
+        log first-event-wins so macro-step runs keep the
         one-event-per-worker invariant."""
         from repro.fleet import qtick as Q
         from repro.kernels import serve_tick as K

@@ -128,7 +128,7 @@ def sched_summary(sp, ss, duration_s: float, pool=None,
         # (0 on unsharded runs; see docs/sharded_fleet.md)
         "rebalanced": int(np.asarray(ss.rebalanced).sum()),
         "throughput_rps": completed / max(duration_s, 1e-9),
-        "latency_mean_s": float(ss.lat_sum) / max(completed, 1),
+        "latency_mean_s": int(ss.lat_sum) * sp.dt / max(completed, 1),
         "latency_p50_s": _hist_percentile(np.asarray(ss.lat_hist),
                                           sp.lat_max_s, 0.50),
         "latency_p95_s": _hist_percentile(np.asarray(ss.lat_hist),

@@ -24,7 +24,7 @@ Mechanisms (array formulation of the PR-1 semantics):
   with their original arrival time (the paper prefers fresh samples, so a
   retried old request must not leapfrog shedding).
 - **Shedding** — the longest stale prefix of each queue (age beyond
-  ``shed_after_s``) is dropped via a cumulative-product prefix scan.
+  ``shed_after_s``) is dropped up to the first fresh entry.
 - **Routing** — dispatchable workers are ranked by a *budget score*
   (stable argsort, richest first); queues are served oldest-head-first.
   Reactive mode scores instantaneous usable energy; forecast mode scores
@@ -334,28 +334,130 @@ def power_lags(power, trace_index, i, T, order: int, phase=None, xp=np):
 # ---------------------------------------------------------------------------
 
 
-def _argsort(a, xp):
-    """Stable argsort on both array namespaces: ties break by index, so
-    the NumPy and JAX control planes rank identically."""
+def _argsort(key, valid, xp):
+    """Rank -> index: the stable ascending argsort of
+    ``where(valid, key, +inf)`` (ties break by index), identical on both
+    array namespaces.
+
+    Under jax the sort runs on :func:`_order_key`'s int64 image of the
+    float64 key: XLA:TPU emulates float64, and a sort on an emulated
+    float64 comparator takes minutes to compile at fleet sizes (as does a
+    sort with several key operands); one int64 key compiles in well under
+    a minute. ``key`` must be finite where ``valid``."""
     if xp is np:
-        return np.argsort(a, kind="stable")
-    return xp.argsort(a, stable=True)
+        return np.argsort(np.where(valid, key, np.inf), kind="stable")
+    from jax import lax
+    k = xp.where(valid, _order_key(xp.where(valid, key, 0.0), xp),
+                 np.iinfo(np.int64).max)
+    idx = lax.iota(xp.int32, k.shape[0])
+    return lax.sort((k, idx), num_keys=1, is_stable=True)[1]
+
+
+def _order_key(x, xp):
+    """An int64 key with the order of the finite float64 ``x`` (equal
+    values, -0.0 and 0.0 included, get equal keys), built without a
+    64-bit bitcast, which XLA:TPU does not implement.
+
+    ``hi`` is the float32 rounding of ``x``; the remainder ``r = x - hi``
+    is exact, at most half a float32 ulp of ``hi`` and a multiple of
+    ``x``'s float64 ulp, so ``r * 2**(53 - e)`` (``e`` the exponent of
+    ``hi``) is an integer in [-2**29, 2**29]. The key is the order-
+    preserving int32 image of ``hi``'s bits, times 2**31, plus that
+    integer offset by 2**30: ``hi`` orders first, and equal ``hi`` means
+    equal ``e``, where the scaled remainders order exactly. Magnitudes
+    in float32's normal range (2**-126 to 2**128) order exactly; smaller
+    ones order by their float32 rounding alone."""
+    from jax import lax
+    y = xp.where(x == 0, 0.0, x)  # -0.0 -> 0.0
+    hi = y.astype(xp.float32)
+    bits = lax.bitcast_convert_type(hi, xp.int32)
+    ord32 = xp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    e = ((bits >> 23) & 0xFF) - 127
+    # 2**(53 - e) as two exact float32 powers of two
+    s = 53 - e
+    s1 = xp.clip(s, -126, 127)
+    p1 = lax.bitcast_convert_type((s1 + 127) << 23, xp.float32)
+    p2 = lax.bitcast_convert_type((xp.clip(s - s1, -126, 127) + 127) << 23,
+                                  xp.float32)
+    r = (y - hi.astype(y.dtype)) * p1.astype(y.dtype) * p2.astype(y.dtype)
+    r = xp.where(e > -127, r, 0.0)  # hi == 0: tie with 0.0
+    return (ord32.astype(xp.int64) << 31) + (r.astype(xp.int64) + (1 << 30))
+
+
+def _cumsum(x, xp):
+    """Inclusive prefix sum along axis 0 of non-negative int64 counts
+    whose total the caller bounds below 2**31 (batch sizes: at most
+    ``n * B`` request slots, which the (N, B) state arrays cap; capped
+    per-workload counts). Under jax it is summed in int32: XLA:TPU lowers ``cumsum`` to a
+    ``reduce_window`` whose emulated-int64 form runs out of scoped VMEM
+    once fused into the serve scan body. Exact, so bit-identical to
+    NumPy's int64 sums."""
+    if xp is np:
+        return np.cumsum(x, axis=0)
+    return xp.cumsum(x.astype(xp.int32), axis=0).astype(x.dtype)
+
+
+# (N, B) per-slot arrays under jax: XLA:TPU lays out the narrow slot axis
+# B minor, and a gather or scatter indexed by such an array, or a reshape
+# between (N, B) and the flat (N*B,) order, costs it about a minute of
+# compile per call site at fleet sizes. The helpers below work one slot
+# column at a time instead ((N,) vectors: about a second each) and keep
+# the flat row-major (worker, slot) semantics of the NumPy forms.
+
+
+def _cols(x):
+    return [x[:, j] for j in range(x.shape[1])]
+
+
+def _take(a, idx, xp):
+    """``xp.take(a, idx)`` for a 1-D table ``a`` and an (N,) or (N, B)
+    index array."""
+    if xp is np or idx.ndim == 1:
+        return xp.take(a, idx)
+    return xp.stack([xp.take(a, c) for c in _cols(idx)], axis=1)
 
 
 def _scatter_set(a, idx, v, xp):
+    """``a[idx] = v`` on a copy. ``idx`` is (N,) or (N, B) with ``v`` of
+    its shape; a 2-D ``a`` with (N,) ``idx`` scatters whole rows. Where
+    indices repeat, the NumPy order decides (the last write wins), and the
+    callers send only discarded writes to a repeated index."""
     if xp is np:
         out = a.copy()
         out[idx] = v
         return out
+    if a.ndim == 2:  # row scatter, one slot column at a time
+        return xp.stack([c.at[idx].set(vc)
+                         for c, vc in zip(_cols(a), _cols(v))], axis=1)
+    if idx.ndim == 2:
+        for c, vc in zip(_cols(idx), _cols(v)):
+            a = a.at[c].set(vc)
+        return a
     return a.at[idx].set(v)
 
 
 def _scatter_add(a, idx, v, xp):
+    """``np.add.at(a, idx, v)`` on a copy, for a scalar ``v`` and an (N,)
+    or (N, B) ``idx``."""
     if xp is np:
         out = a.copy()
         np.add.at(out, idx, v)
         return out
-    return a.at[idx].add(v)
+    for c in (_cols(idx) if idx.ndim == 2 else [idx]):
+        a = a.at[c].add(v)
+    return a
+
+
+def _cumsum_slots(m, xp):
+    """Inclusive prefix sum of an (N, B) int64 count array in flat
+    row-major (worker, slot) order, returned as (N, B): the row totals'
+    prefix plus the prefix within each row. Same bound as
+    :func:`_cumsum`."""
+    if xp is np:
+        return np.cumsum(m.reshape(-1)).reshape(m.shape)
+    rows = xp.sum(m, axis=1)
+    within = xp.cumsum(m.astype(xp.int32), axis=1).astype(m.dtype)
+    return (_cumsum(rows, xp) - rows)[:, None] + within
 
 
 # ---------------------------------------------------------------------------
@@ -391,8 +493,11 @@ def _admit_impl(sp: SchedParams, ss, counts, t, xp):
     counts = xp.asarray(counts).astype(xp.int64)
     backlog = xp.sum(ss.q_len)
     space = xp.maximum(sp.max_queue - backlog, 0)
-    cum = xp.cumsum(counts)
-    adm = xp.clip(space - (cum - counts), 0, counts)
+    # arrivals ahead of each workload's in this tick; each count is capped
+    # at max_queue + 1 first (a larger prefix admits nothing either way),
+    # which bounds the sum for _cumsum
+    capped = xp.minimum(counts, sp.max_queue + 1)
+    adm = xp.clip(space - (_cumsum(capped, xp) - capped), 0, counts)
     if xp is np:
         # reference-driver fast path: write exactly the admitted slots
         # (same values the masked whole-ring write below produces — the
@@ -428,7 +533,8 @@ def shed(sp: SchedParams, ss, t, xp=np):
     phys = (ss.q_head[:, None] + j) % sp.Q
     log_t = xp.take_along_axis(ss.q_t, phys, axis=1)
     stale = (j < ss.q_len[:, None]) & (t - log_t > sp.shed_after_s)
-    n_shed = xp.sum(xp.cumprod(stale.astype(xp.int64), axis=1), axis=1)
+    # the stale prefix ends at the first fresh (or empty) slot
+    n_shed = xp.min(xp.where(stale, sp.Q, j), axis=1)
     return ss._replace(
         q_head=(ss.q_head + n_shed) % sp.Q,
         q_len=ss.q_len - n_shed,
@@ -490,8 +596,8 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
     refinement on the *planning* budget (forecast inflow funds in-flight
     units). Queue consumption is a cumulative-sum slice per workload."""
     i64 = xp.int64
-    score = xp.where(dispatchable, budget_plan, -xp.inf)
-    order = _argsort(-score, xp)  # rank -> worker id, richest first
+    # rank -> worker id: dispatchable workers richest first
+    order = _argsort(-budget_plan, dispatchable, xp)
     elig = xp.take(dispatchable, order)
     bn = xp.take(budget_now, order)
     bp = xp.take(budget_plan, order)
@@ -500,11 +606,9 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
         # first (a params constant, so the order is static under tracing)
         wl_order = xp.asarray(sp.WL_RANK)
     else:
-        head_t = xp.where(
-            ss.q_len > 0,
-            xp.take_along_axis(ss.q_t, ss.q_head[:, None], axis=1)[:, 0],
-            xp.inf)
-        wl_order = _argsort(head_t, xp)
+        head_t = xp.take_along_axis(ss.q_t, ss.q_head[:, None],
+                                    axis=1)[:, 0]
+        wl_order = _argsort(head_t, ss.q_len > 0, xp)  # empty queues last
     q_head, q_len = ss.q_head, ss.q_len
     taken = xp.zeros(sp.n, dtype=bool)
     a_wl = xp.zeros(sp.n, dtype=i64)
@@ -570,7 +674,7 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
             p_req, u_cap)
         ok = elig & ~taken & afford & (u_want > 0)
         b = xp.where(ok, b_want, 0)
-        c = xp.cumsum(b)
+        c = _cumsum(b, xp)  # b <= B per worker
         start = c - b
         actual = xp.clip(qrem - start, 0, b)
         got = ok & (actual > 0)
@@ -583,8 +687,8 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
         row_t = xp.take(ss.q_t, wl, axis=0)
         row_r = xp.take(ss.q_r, wl, axis=0)
         take_mask = got[:, None] & (jB < actual[:, None])
-        g_arr = xp.where(take_mask, xp.take(row_t, phys), g_arr)
-        g_retry = xp.where(take_mask, xp.take(row_r, phys), g_retry)
+        g_arr = xp.where(take_mask, _take(row_t, phys, xp), g_arr)
+        g_retry = xp.where(take_mask, _take(row_r, phys, xp), g_retry)
         consumed = xp.sum(actual)
         onehot = xp.arange(sp.W) == wl
         q_head = xp.where(onehot, (q_head + consumed) % sp.Q, q_head)
@@ -643,20 +747,17 @@ def _requeue_impl(sp: SchedParams, ss, slots, xp):
     give_up = slots & (newr > sp.max_retries)
     keep = slots & ~give_up
     q_t, q_r, q_head, q_len = ss.q_t, ss.q_r, ss.q_head, ss.q_len
-    flat_keep = keep.reshape(-1)
-    flat_t = ss.f_arr.reshape(-1)
-    flat_r = newr.reshape(-1)
-    flat_wl = xp.broadcast_to(ss.f_wl[:, None], keep.shape).reshape(-1)
     for w in range(sp.W):  # static: one front-insert pass per queue
-        m = flat_keep & (flat_wl == w)
+        # (N, B) slots kept for queue w, ranked in (worker, slot) order
+        m = keep & (ss.f_wl == w)[:, None]
         kcount = xp.sum(m.astype(xp.int64))
-        rank = xp.cumsum(m.astype(xp.int64)) - 1
+        rank = _cumsum_slots(m.astype(xp.int64), xp) - 1
         headnew = (q_head[w] - kcount) % sp.Q
         phys = xp.where(m, (headnew + rank) % sp.Q, sp.Q)  # Q: dump slot
         ext_t = xp.concatenate([q_t[w], xp.zeros(1)])
-        ext_t = _scatter_set(ext_t, phys, xp.where(m, flat_t, 0.0), xp)
+        ext_t = _scatter_set(ext_t, phys, xp.where(m, ss.f_arr, 0.0), xp)
         ext_r = xp.concatenate([q_r[w], xp.zeros(1, dtype=xp.int64)])
-        ext_r = _scatter_set(ext_r, phys, xp.where(m, flat_r, 0), xp)
+        ext_r = _scatter_set(ext_r, phys, xp.where(m, newr, 0), xp)
         onehot = xp.arange(sp.W) == w
         q_t = xp.where(onehot[:, None], ext_t[None, :sp.Q], q_t)
         q_r = xp.where(onehot[:, None], ext_r[None, :sp.Q], q_r)
@@ -699,9 +800,12 @@ def _collect_impl(sp: SchedParams, ss, emit, lost, units_done, t, xp):
     lo = lost & act
     b = ss.f_n
     u = ss.f_units
-    safe_u = xp.maximum(u, 1)
-    full = xp.where(u > 0, units_done // safe_u, b)
-    part = xp.where(u > 0, units_done % safe_u, 0)
+    # int32 division (one batch's units are far below 2**31): XLA:TPU's
+    # emulated int64 division costs ~20 s of compile per call site
+    i32 = xp.int32
+    ud, su = units_done.astype(i32), xp.maximum(u, 1).astype(i32)
+    full = xp.where(u > 0, (ud // su).astype(b.dtype), b)
+    part = xp.where(u > 0, (ud % su).astype(b.dtype), 0)
     nfull = xp.minimum(full, b)
     haspart = (part > 0) & (full < b)
     jB = xp.arange(sp.B)[None, :]
@@ -720,13 +824,13 @@ def _collect_impl(sp: SchedParams, ss, emit, lost, units_done, t, xp):
     idx = xp.clip((lat / binw).astype(xp.int64), 0, sp.lat_bins - 1)
     idx = xp.where(comp, idx, sp.lat_bins)  # non-completions -> dump bin
     hist_ext = _scatter_add(xp.zeros(sp.lat_bins + 1, dtype=xp.int64),
-                            idx.reshape(-1), 1, xp)
-    # per-workload aggregates via the small one-hot W axis
-    wl1h = ss.f_wl[:, None, None] == xp.arange(sp.W)[None, None, :]
-    compc = (comp[:, :, None] & wl1h).astype(xp.int64)
+                            idx, 1, xp)
+    # per-workload aggregates, one static pass per workload over the
+    # (N, B) slots (no (N, B, W) one-hot tensors)
+    wl = ss.f_wl[:, None]
     Uw = sp.ACC.shape[1]
-    accv = xp.take(xp.asarray(sp.ACC).reshape(-1),
-                   ss.f_wl[:, None] * Uw + xp.clip(units_slot, 0, Uw - 1))
+    accv = _take(xp.asarray(sp.ACC).reshape(-1),
+                 wl * Uw + xp.clip(units_slot, 0, Uw - 1), xp)
     # quality ledger: each completion is scored against a deterministic
     # oracle sample — per workload, this tick's completions are numbered
     # in flat (worker, slot) order continuing the run-long completed_wl
@@ -734,27 +838,38 @@ def _collect_impl(sp: SchedParams, ss, emit, lost, units_done, t, xp):
     # correctness (0/1) and the table-priced spend (integer nanojoules)
     # are gathered from the precomputed (workload, sample, units)
     # tables. Integer arithmetic only: both backends ledger bit-exactly.
-    cc2 = compc.reshape(-1, sp.W)  # (N*B, W)
-    sample = ((ss.completed_wl[None, :] + xp.cumsum(cc2, axis=0) - cc2)
-              % xp.asarray(sp.S_Q)[None, :])
     Smax, Uq = sp.QTAB.shape[1], sp.QTAB.shape[2]
     uq = xp.clip(units_slot, 0, Uq - 1)
-    qv = xp.take(xp.asarray(sp.QTAB).reshape(-1),
-                 (xp.arange(sp.W)[None, :] * Smax + sample) * Uq
-                 + uq.reshape(-1)[:, None])
-    jnj = xp.take(xp.asarray(sp.QJ_NJ).reshape(-1),
-                  ss.f_wl[:, None] * Uq + uq)
+    jnj = _take(xp.asarray(sp.QJ_NJ).reshape(-1), wl * Uq + uq, xp)
+    agg = {k: [] for k in ("n", "units", "acc", "meas", "nj")}
+    for w in range(sp.W):  # static: one pass per workload
+        m = comp & (wl == w)
+        mi = m.astype(xp.int64)
+        # the run-long counter is reduced first, so the per-slot modulo
+        # runs in int32 (ranks are below n * B)
+        s_q = sp.S_Q[w].astype(i32)
+        sample = ((ss.completed_wl[w] % sp.S_Q[w]).astype(i32)
+                  + (_cumsum_slots(mi, xp) - mi).astype(i32)) % s_q
+        qv = _take(xp.asarray(sp.QTAB).reshape(-1),
+                   (w * Smax + sample) * Uq + uq, xp)
+        agg["n"].append(xp.sum(mi))
+        agg["units"].append(xp.sum(xp.where(m, units_slot, 0)))
+        agg["acc"].append(xp.sum(xp.where(m, accv, 0.0)))
+        agg["meas"].append(xp.sum(xp.where(m, qv, 0)))
+        agg["nj"].append(xp.sum(xp.where(m, jnj, 0)))
+    agg = {k: xp.stack(v) for k, v in agg.items()}
+    # the latency sum counts whole ticks (arrivals and completions are
+    # stamped on the tick grid): an integer is the same in every
+    # reduction order and under XLA:TPU's emulated float64
+    lat_ticks = xp.rint(lat / sp.dt).astype(xp.int64)
     ss = ss._replace(
         completed=ss.completed + xp.sum(comp),
-        completed_wl=ss.completed_wl + xp.sum(compc, axis=(0, 1)),
-        units_wl=ss.units_wl + xp.sum(units_slot[:, :, None] * compc,
-                                      axis=(0, 1)),
-        acc_wl=ss.acc_wl + xp.sum(xp.where(comp, accv, 0.0)[:, :, None]
-                                  * compc, axis=(0, 1)),
-        meas_wl=ss.meas_wl + xp.sum(qv * cc2, axis=0),
-        joules_nj_wl=ss.joules_nj_wl + xp.sum(
-            jnj.reshape(-1)[:, None] * cc2, axis=0),
-        lat_sum=ss.lat_sum + xp.sum(xp.where(comp, lat, 0.0)),
+        completed_wl=ss.completed_wl + agg["n"],
+        units_wl=ss.units_wl + agg["units"],
+        acc_wl=ss.acc_wl + agg["acc"],
+        meas_wl=ss.meas_wl + agg["meas"],
+        joules_nj_wl=ss.joules_nj_wl + agg["nj"],
+        lat_sum=ss.lat_sum + xp.sum(xp.where(comp, lat_ticks, 0)),
         lat_hist=ss.lat_hist + hist_ext[:sp.lat_bins])
     ss = _requeue(sp, ss, unfinished, xp)
     return ss._replace(f_n=xp.where(em | lo, 0, ss.f_n))
@@ -914,7 +1029,7 @@ def rebalance_moves(sp: SchedParams, q_len, give, xp=np):
     ``min(q_len[w], rebalance_max)`` (vectorized greedy fill via the
     availability cumsum). ``give`` is an int64 scalar."""
     capw = xp.minimum(q_len, sp.rebalance_max)
-    c = xp.cumsum(capw)
+    c = _cumsum(capw, xp)  # capw <= rebalance_max per workload
     return xp.clip(give - (c - capw), 0, capw).astype(xp.int64)
 
 

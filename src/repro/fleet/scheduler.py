@@ -571,9 +571,7 @@ _CHUNK_COUNTERS = ("submitted", "completed", "shed", "rejected",
 
 def _chunk_snapshot(state) -> dict:
     v = _sched.merged_sched_view(state)
-    snap = {f: int(getattr(v, f)) for f in _CHUNK_COUNTERS
-            if f != "lat_sum"}
-    snap["lat_sum"] = float(np.asarray(v.lat_sum))
+    snap = {f: int(getattr(v, f)) for f in _CHUNK_COUNTERS}
     snap["lat_hist"] = np.asarray(v.lat_hist).copy()
     return snap
 
@@ -656,7 +654,7 @@ def run_fleet_stream(pool: FleetWorkerPool, sched: FleetScheduler,
         rec = {"tick0": done, "ticks": k,
                "wall_s": wall,
                "throughput_rps": completed / (k * dt),
-               "mean_latency_s": (lat_sum / completed
+               "mean_latency_s": (lat_sum * dt / completed
                                   if completed else 0.0),
                "p50_s": _hist_percentile(hist, sp.lat_max_s, 0.50),
                "p95_s": _hist_percentile(hist, sp.lat_max_s, 0.95),

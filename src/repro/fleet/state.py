@@ -299,7 +299,7 @@ class SchedState:
     completed_wl: np.ndarray  # (W,)
     units_wl: np.ndarray  # (W,)
     acc_wl: np.ndarray  # (W,)
-    lat_sum: np.ndarray
+    lat_sum: np.ndarray  # int64 completed requests' latency, in ticks
     lat_hist: np.ndarray  # (lat_bins,)
     batch_hist: np.ndarray  # (B+1,) assignments by batch size
     # quality ledger (repro.quality.ledger): measured-correct completions
@@ -330,7 +330,7 @@ def init_sched_state(sp: SchedParams) -> SchedState:
         submitted=i(), rejected=i(), shed=i(), lost=i(), evicted=i(),
         requeued=i(), completed=i(),
         completed_wl=i(sp.W), units_wl=i(sp.W), acc_wl=f(sp.W),
-        lat_sum=f(), lat_hist=i(sp.lat_bins), batch_hist=i(sp.B + 1),
+        lat_sum=i(), lat_hist=i(sp.lat_bins), batch_hist=i(sp.B + 1),
         meas_wl=i(sp.W), joules_nj_wl=i(sp.W), rebalanced=i())
 
 

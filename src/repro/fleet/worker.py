@@ -128,8 +128,9 @@ class FleetWorkerPool:
                  backend: str = "numpy",
                  use_pallas: bool = False,
                  kernel: str = "xla",
-                 fleet_placement: str = "auto",
-                 persist: str = "none"):
+                 fleet_placement: str = "mesh",
+                 persist: str = "none",
+                 interpret: bool = False):
         if mode not in ("local", "dispatch"):
             raise ValueError(f"unknown pool mode {mode!r}")
         if backend not in BACKENDS:
@@ -202,9 +203,11 @@ class FleetWorkerPool:
         self.use_pallas = use_pallas
         self.kernel = kernel
         # sharded-serve evaluation: "mesh" (shard_map over a real fleet
-        # mesh), "single" (one-device vmap), "auto" (mesh iff enough
-        # devices) — placements are bit-identical, see backend_jax
+        # mesh; raises without K devices) or "single" (one-device vmap,
+        # only when asked) — placements are bit-identical, see backend_jax
         self.fleet_placement = fleet_placement
+        # Pallas kernels interpret only when asked (CPU tests)
+        self.interpret = interpret
         self._jax = None  # lazily-built JaxFleetBackend
         self.results: list[list[EmittedResult]] = [[] for _ in range(n)]
         self.events: list[tuple] = []
@@ -316,7 +319,8 @@ class FleetWorkerPool:
                 self._jax = JaxFleetBackend(
                     self.params, use_pallas=self.use_pallas,
                     kernel=self.kernel,
-                    fleet_placement=self.fleet_placement)
+                    fleet_placement=self.fleet_placement,
+                    interpret=self.interpret)
             self.state, events = self._jax.run(self.state, i0, n_ticks)
             self.events.extend(events)
             self.steps_done = i0 + n_ticks
@@ -341,7 +345,8 @@ class FleetWorkerPool:
             self._jax = JaxFleetBackend(
                 self.params, use_pallas=self.use_pallas,
                 kernel=self.kernel,
-                fleet_placement=self.fleet_placement)
+                fleet_placement=self.fleet_placement,
+                interpret=self.interpret)
         self.state, sched.state = self._jax.run_serve(
             self.state, sched.params, sched.state, arrivals,
             i0=self.steps_done, dispatch_every=dispatch_every, obs=obs)

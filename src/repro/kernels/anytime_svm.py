@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(p_ref,  # scalar prefetch: (1,) int32 = feature prefix length
             x_ref, w_ref, b_ref, o_ref, acc_ref,
@@ -79,7 +77,7 @@ def anytime_svm_scores(x, w, b, p_features, *, block_b: int = 8,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(p_arr, x, w, b.reshape(1, C))

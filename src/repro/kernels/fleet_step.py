@@ -19,8 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.tiling import LANES, pad_to_tiles, tile_rows, untile
 
 
@@ -56,7 +56,7 @@ def harvest_step(v, power_w, capacitance_f, v_max, *, eff: float, dt: float,
         in_specs=[spec, spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(prep(v, 0.0), prep(power_w, 0.0), prep(capacitance_f, 1.0),

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 _HALO = 3  # 1 (sobel) + 2 (gaussian)
 
@@ -55,7 +54,7 @@ def _kernel(keep_ref, img_ref, o_ref, *, tile: int, k_harris: float,
         ext = tile + 2 * pad
         y0 = jnp.clip(ti * tile - pad, 0, img_h - ext)
         x0 = jnp.clip(tj * tile - pad, 0, img_w - ext)
-        patch = pl.load(img_ref, (pl.dslice(y0, ext), pl.dslice(x0, ext)))
+        patch = img_ref[pl.ds(y0, ext), pl.ds(x0, ext)]
         patch = patch.astype(jnp.float32)
         ix = _sep_conv(patch, (1 / 8, 2 / 8, 1 / 8), (-1.0, 0.0, 1.0))
         iy = _sep_conv(patch, (-1.0, 0.0, 1.0), (1 / 8, 2 / 8, 1 / 8))
@@ -99,7 +98,7 @@ def harris_pallas(img, tile_keep, *, tile: int = 16, k_harris: float = 0.05,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, W), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(keep, img)

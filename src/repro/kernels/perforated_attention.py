@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -118,7 +117,7 @@ def perforated_attention(q, k, v, block_keep, *, causal: bool = True,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, Dh), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(keep, qf, kf, vf)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref,
             *, chunk: int, n_chunks: int):
@@ -88,7 +86,7 @@ def rwkv6_wkv(r, k, v, logw, u, *, chunk: int = 32,
         out_specs=pl.BlockSpec((1, chunk, N), lambda bh, ic: (bh, ic, 0)),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B * H, L, N), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf)

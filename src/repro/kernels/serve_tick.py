@@ -31,8 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.tiling import LANES, pad_to_tiles, tile_rows, untile
 
 # event codes (match repro.fleet.qtick / backend_jax)
@@ -53,6 +53,8 @@ BOOL_FIELDS = ("on", "has_work", "p_pending")
 # per-block ledger lanes (first 8 lanes of each (1, 128) output row)
 LEDGER_SLOTS = ("n_emit", "n_lost", "units_emitted", "n_wake",
                 "n_acquired", "qh_quanta", "e_work_quanta", "reserved")
+
+LED_ROWS = 8  # ledger tile height; only row 0 carries the slots
 
 _N_RW = len(RW_FIELDS)
 _N_RO = len(RO_FIELDS)
@@ -151,12 +153,18 @@ def _serve_tick_kernel(*refs, u_max: int):
     run = working & (w_units_done < w_target)
     emit_now = jnp.zeros_like(run)
 
+    # the loop carries its masks as int32 0/1: Mosaic cannot legalize a
+    # while-loop yield of i1 vectors
+    as_i32 = lambda m: m.astype(i32)  # noqa: E731
+
     def cond(c):
-        return jnp.any(c[7])
+        return jnp.max(c[7]) > 0
 
     def body(c):
         (E, on, has_work, e_work, w_left, w_units_done, e_step, run,
          emit_now, ev) = c
+        on, has_work, run, emit_now = (
+            x != 0 for x in (on, has_work, run, emit_now))
         # unit boundary: start the next unit only if unit + emit-reserve
         # are affordable now (the paper's BLE-packet reserve)
         starting = run & (w_left <= 0)
@@ -184,13 +192,14 @@ def _serve_tick_kernel(*refs, u_max: int):
         fin = run & (w_left <= 0)
         w_units_done = w_units_done + fin.astype(i32)
         run = run & (e_step > 0) & (w_units_done < w_target)
-        return (E, on, has_work, e_work, w_left, w_units_done, e_step,
-                run, emit_now, ev)
+        return (E, as_i32(on), as_i32(has_work), e_work, w_left,
+                w_units_done, e_step, as_i32(run), as_i32(emit_now), ev)
 
-    carry = (E, on, has_work, e_work, w_left, w_units_done, e_step, run,
-             emit_now, ev)
+    carry = (E, as_i32(on), as_i32(has_work), e_work, w_left, w_units_done,
+             e_step, as_i32(run), as_i32(emit_now), ev)
     (E, on, has_work, e_work, w_left, w_units_done, _, _, emit_now,
      ev) = lax.while_loop(cond, body, carry)
+    on, has_work, emit_now = on != 0, has_work != 0, emit_now != 0
 
     # 5. emission (BLE packet / host transfer)
     finish = (working & has_work & on
@@ -224,10 +233,20 @@ def _serve_tick_kernel(*refs, u_max: int):
     for r, x in zip(ev_refs, ev):
         r[...] = x
 
-    # per-block event/ledger partial reduction, 8 int32 lanes per block
-    lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
-    tot = lambda x: jnp.sum(x, dtype=i32)  # noqa: E731
-    put = lambda slot, val: jnp.where(lane == slot, val, 0)  # noqa: E731
+    # per-block event/ledger partial reduction, 8 int32 lanes in row 0
+    # of the block's (8, 128) ledger tile (Mosaic tiles are 8 rows high)
+    lane = lax.broadcasted_iota(jnp.int32, (LED_ROWS, LANES), 1)
+    row0 = lax.broadcasted_iota(jnp.int32, (LED_ROWS, LANES), 0) == 0
+
+    def tot(x):
+        # (bm, 128) -> (1, 1) one axis at a time: Mosaic lowers a
+        # reduction to a scalar through a jnp.sum that widens int32 to
+        # int64 when the caller traces under x64
+        col = jnp.sum(x, axis=0, keepdims=True, dtype=i32)
+        return jnp.sum(col, axis=1, keepdims=True, dtype=i32)
+
+    put = lambda slot, val: jnp.where(row0 & (lane == slot), val,  # noqa: E731
+                                      0)
     led_ref[...] = (
         put(0, tot(esucc.astype(i32)))
         + put(1, tot((evc == EV_LOST).astype(i32)))
@@ -279,22 +298,26 @@ def serve_tick(rw, ro, consts, tables, qh, i, *, u_max: int,
                    full(tables["emitc"])])
     i32 = jnp.int32
     out_shape = ([jax.ShapeDtypeStruct((rows, LANES), i32)] * (_N_RW + 4)
-                 + [jax.ShapeDtypeStruct((grid, LANES), i32)])
+                 + [jax.ShapeDtypeStruct((grid * LED_ROWS, LANES), i32)])
     out_specs = ([tile] * (_N_RW + 4)
-                 + [pl.BlockSpec((1, LANES), lambda g: (g, 0))])
-    outs = pl.pallas_call(
-        functools.partial(_serve_tick_kernel, u_max=u_max),
-        grid=(grid,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(*args)
+                 + [pl.BlockSpec((LED_ROWS, LANES), lambda g: (g, 0))])
+    # the kernel is int32 end to end; traced under the caller's x64 mode
+    # its Python literals would be int64 constants that Mosaic cannot
+    # narrow, so trace and lower it with x64 off
+    with jax.enable_x64(False):
+        outs = pl.pallas_call(
+            functools.partial(_serve_tick_kernel, u_max=u_max),
+            grid=(grid,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            interpret=interpret,
+        )(*args)
     rw_out = {}
     for f, y in zip(RW_FIELDS, outs[:_N_RW]):
         y = untile(y, n)
         rw_out[f] = (y != 0) if f in BOOL_FIELDS else y
     ev = tuple(untile(y, n) for y in outs[_N_RW:_N_RW + 4])
-    return rw_out, ev, outs[_N_RW + 4]
+    return rw_out, ev, outs[_N_RW + 4][::LED_ROWS]

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref,
             *, chunk: int):
@@ -90,7 +88,7 @@ def ssd_scan_pallas(x, dt, A, B_mat, C_mat, *, chunk: int = 64,
         out_specs=pl.BlockSpec((1, chunk, P), lambda bh, ic: (bh, ic, 0)),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((Bsz * H, L, P), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xf, dtf, af, bf, cf)

@@ -223,8 +223,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                     # through a cache-sized masked all-reduce (measured,
                     # §Perf iterations 2-3 — refuted). shard_map pins the
                     # slice to each shard's local blocks.
-                    from repro.sharding import shard_map_compat
-
                     stride = 4
                     kept = shape.seq_len // stride
                     local_seq = shape.seq_len // ctx.tp_size
@@ -244,10 +242,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                         return x
 
                     state_specs = jax.tree.map(lambda s: s.spec, state_sh)
-                    slice_fn = shard_map_compat(
+                    slice_fn = jax.shard_map(
                         lambda st: jax.tree.map(_slice_local, st),
                         mesh=ctx.mesh, in_specs=(state_specs,),
-                        out_specs=state_specs, check=False)
+                        out_specs=state_specs, check_vma=False)
 
                     def serve_step(params, state, token, cache_len):
                         small = slice_fn(state)

@@ -12,7 +12,8 @@
     PYTHONPATH=src python -m repro.launch.fleet --workers 256 \
         --quality measured --sched quality --traces SIM,RF
     PYTHONPATH=src python -m repro.launch.fleet --workers 4096 \
-        --backend jax --scheduler on --mesh-fleet 8 --rebalance-every 1
+        --backend jax --scheduler on --mesh-fleet 8 --rebalance-every 1 \
+        --fleet-placement single
 
 Builds a harvest-powered worker fleet over a mix of energy-trace families,
 then serves one global HAR + Harris + LM request stream either through the
@@ -41,6 +42,8 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+from pathlib import Path
 
 import numpy as np
 
@@ -58,6 +61,26 @@ WORKLOAD_FACTORIES = {
     "harris": harris_workload,
     "lm": lm_workload,
 }
+
+
+# the persistent compile cache's home when JAX_COMPILATION_CACHE_DIR is
+# unset: a fixed path, since the directory is part of every entry's key
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def init_compile_cache() -> str:
+    """Place JAX's persistent compilation cache before the first compile.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and JAX reads it
+    itself; otherwise the cache lives at the repository's fixed
+    ``.jax_cache``. Streaming chunks share one length, so each serve
+    program compiles once per cache. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
+    return str(REPO_CACHE_DIR)
 
 
 def trace_family_labels(trace_names: list[str], n_rows: int) -> list[str]:
@@ -113,8 +136,9 @@ def build_dispatch_pool(power: np.ndarray, dt: float, n_workers: int,
                         v_max: np.ndarray | None = None,
                         active_power_w: np.ndarray | None = None,
                         kernel: str = "xla",
-                        fleet_placement: str = "auto",
-                        persist: str = "none") -> FleetWorkerPool:
+                        fleet_placement: str = "mesh",
+                        persist: str = "none",
+                        interpret: bool = False) -> FleetWorkerPool:
     rng = np.random.default_rng(seed)
     return FleetWorkerPool(
         power, dt, workloads=[w.costs for w in workloads], mode="dispatch",
@@ -123,7 +147,8 @@ def build_dispatch_pool(power: np.ndarray, dt: float, n_workers: int,
         phase=rng.integers(0, power.shape[1], n_workers),
         backend=backend, capacitance_f=capacitance_f, v_max=v_max,
         active_power_w=active_power_w, kernel=kernel,
-        fleet_placement=fleet_placement, persist=persist)
+        fleet_placement=fleet_placement, persist=persist,
+        interpret=interpret)
 
 
 def run_scheduled(power: np.ndarray, dt: float, n_workers: int,
@@ -143,18 +168,19 @@ def run_scheduled(power: np.ndarray, dt: float, n_workers: int,
                   obs_print: bool = False, kernel: str = "xla",
                   mesh_fleet: int = 1, rebalance_every_s: float = 0.0,
                   rebalance_max: int = 8,
-                  fleet_placement: str = "auto",
+                  fleet_placement: str = "mesh",
                   stream_mode: bool = False, chunk_ticks: int = 0,
                   refit_every_s: float = 0.0,
                   slo_p95_s: float = 0.0,
                   persist: str = "none",
-                  grace_s: float = 20.0) -> dict:
+                  grace_s: float = 20.0,
+                  interpret: bool = False) -> dict:
     pool = build_dispatch_pool(power, dt, n_workers, workloads, seed,
                                backend=backend, capacitance_f=capacitance_f,
                                v_max=v_max, active_power_w=active_power_w,
                                kernel=kernel,
                                fleet_placement=fleet_placement,
-                               persist=persist)
+                               persist=persist, interpret=interpret)
     # the rebalance cadence rounds to ticks; run_serve validates it is a
     # multiple of the dispatch cadence
     scheduler = FleetScheduler(pool, workloads, max_batch=max_batch,
@@ -304,7 +330,12 @@ def main(argv: list[str] | None = None) -> dict:
                     help="serve-tick kernel: float64 XLA expression chain "
                          "(xla), the int32-quantized pure-XLA twin (q32), "
                          "or the fused Pallas megakernel over quantized "
-                         "state (pallas; interprets on CPU)")
+                         "state (pallas; compiled for the TPU unless "
+                         "--interpret)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels through the Pallas "
+                         "interpreter (pure XLA; for CPU-only hosts and "
+                         "tests)")
     ap.add_argument("--mesh-fleet", type=int, default=1,
                     help="shard the serve scan K ways over a (fleet,) "
                          "device mesh: per-shard control planes, one "
@@ -317,11 +348,11 @@ def main(argv: list[str] | None = None) -> dict:
                          "shards; must be a multiple of the dispatch "
                          "cadence and needs --mesh-fleet > 1")
     ap.add_argument("--fleet-placement",
-                    choices=("auto", "mesh", "single"), default="auto",
+                    choices=("mesh", "single"), default="mesh",
                     help="where the sharded scan runs: a real K-device "
-                         "mesh (mesh), a single-device vmap of the same "
-                         "K-shard program (single), or mesh iff K "
-                         "devices exist (auto) — bit-identical results")
+                         "mesh (mesh; fails unless K devices exist) or a "
+                         "single-device vmap of the same K-shard program "
+                         "(single) — bit-identical results")
     ap.add_argument("--hetero", action="store_true",
                     help="heterogeneous fleet: per-worker capacitance/v_max")
     ap.add_argument("--hetero-mcu", action="store_true",
@@ -417,6 +448,7 @@ def main(argv: list[str] | None = None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", default="", help="write summary to this path")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     names = args.traces.split(",")
     wl_names = args.workloads.split(",")
@@ -467,7 +499,8 @@ def main(argv: list[str] | None = None) -> dict:
             fleet_placement=args.fleet_placement,
             stream_mode=args.stream, chunk_ticks=args.chunk_ticks,
             refit_every_s=args.refit_every, slo_p95_s=args.slo_p95,
-            persist=args.persist, grace_s=args.grace)
+            persist=args.persist, grace_s=args.grace,
+            interpret=args.interpret)
     if args.scheduler in ("off", "both"):
         out["independent"] = run_independent(
             power, args.dt, args.workers, workloads, mix=mix,

@@ -11,10 +11,18 @@ import jax
 from repro.sharding.context import MeshContext
 
 
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the models place
+    activations with ``with_sharding_constraint``, which jax refuses on
+    the ``Explicit`` axes ``make_mesh`` defaults to."""
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_context(*, multi_pod: bool = False) -> MeshContext:
@@ -28,5 +36,5 @@ def make_host_mesh(n_devices: int | None = None,
     """Small mesh over whatever devices exist (tests/examples)."""
     n = n_devices or len(jax.devices())
     assert n % model == 0
-    mesh = jax.make_mesh((n // model, model), ("data", "model"))
+    mesh = make_mesh((n // model, model), ("data", "model"))
     return MeshContext(mesh=mesh, dp_axes=("data",), tp_axis="model")

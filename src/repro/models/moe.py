@@ -209,7 +209,7 @@ def moe_ffn_distributed(x, p, cfg, *, compute_dtype, topk_override=None):
     local computation otherwise. x: (B, S, D) global."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding import current_mesh_context, shard_map_compat
+    from repro.sharding import current_mesh_context
 
     ctx = current_mesh_context()
     kw = dict(n_experts=cfg.n_experts, topk=cfg.moe_topk,
@@ -251,12 +251,12 @@ def moe_ffn_distributed(x, p, cfg, *, compute_dtype, topk_override=None):
                              ep_size=ctx.tp_size, **kw)
             return y, jax.lax.pmean(aux, ctx.all_axes)
 
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(dp, tp, None), P(None, None),
                       P(tp, None, None), P(tp, None, None), *shared_in),
             out_specs=(P(dp, tp, None), P()),
-            check=False)
+            check_vma=False)
         y, aux = fn(x, p["router"], p["wi"], p["wo"], *shared_args)
         return _with_shared(y), aux
 
@@ -278,10 +278,10 @@ def moe_ffn_distributed(x, p, cfg, *, compute_dtype, topk_override=None):
     # note: in decode mode x is NOT batch-sharded over dp when ep2d is on
     # (every dp rank needs all tokens for its partial contraction)
     x_spec = P(None, None, None) if ep2d else P(dp, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(x_spec, P(None, None), wi_spec, wi_spec, *shared_in),
         out_specs=(x_spec, P()),
-        check=False)
+        check_vma=False)
     y, aux = fn(x, p["router"], p["wi"], p["wo"], *shared_args)
     return _with_shared(y), aux

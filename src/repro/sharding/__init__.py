@@ -1,7 +1,6 @@
 """Mesh axes, partition rules, and the ambient mesh context."""
 from repro.sharding.context import (MeshContext, current_mesh_context,
-                                    mesh_context, shard_hint,
-                                    shard_map_compat)
+                                    mesh_context, shard_hint)
 
 __all__ = ["MeshContext", "current_mesh_context", "mesh_context",
-           "shard_hint", "shard_map_compat"]
+           "shard_hint"]

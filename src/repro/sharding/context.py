@@ -36,31 +36,19 @@ def make_fleet_mesh(k: int) -> Mesh:
     control-plane shard per device. Raises a clear error when the host
     exposes fewer devices (on CPU, force more with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=K``)."""
+    from jax.experimental import mesh_utils
     devs = jax.devices()
     if len(devs) < k:
         raise ValueError(
             f"--mesh-fleet {k} needs {k} devices but jax.device_count() "
             f"== {len(devs)}; on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={k} (before jax "
-            f"imports) or use the single-device vmap placement")
-    import numpy as np
-    return Mesh(np.asarray(devs[:k]), (FLEET_AXIS,))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: the stable name with its
-    ``check_vma`` kwarg when available (jax >= 0.6), otherwise the
-    ``jax.experimental.shard_map`` location with the older ``check_rep``
-    spelling of the same switch."""
-    import inspect
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    kw = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-          else "check_rep")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check})
+            f"imports) or ask for the single-device vmap with "
+            f"--fleet-placement single")
+    # the shard ring's ppermute neighbours should be physical neighbours:
+    # let mesh_utils order the devices by the chip topology
+    return Mesh(mesh_utils.create_device_mesh((k,), devices=devs[:k]),
+                (FLEET_AXIS,))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,0 +1,125 @@
+"""Compile the serve path for a described TPU v5e, with no chip attached.
+
+The TPU compiler is installed wherever libtpu is, and it refuses at
+compile time what interpret mode never sees: Pallas block shapes off the
+(8, 128) tiling, Mosaic ops it cannot legalize, 64-bit ops XLA:TPU does
+not emulate. The serve programs themselves take minutes to compile at
+fleet sizes, so they are covered by ``chip_smoke.py`` on the chip; these
+tests keep the kernel and the control plane's TPU forms, each a few
+seconds to compile.
+"""
+from __future__ import annotations
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it cannot describe a v5e
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compile_for(one_chip):
+    """``compile_for(fn, *shape_dtype_pairs)`` compiles ``fn`` for one v5e
+    chip and returns the compiled program. The persistent compilation
+    cache is off meanwhile: an entry written for a described chip cannot
+    be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def compile_for(fn, *specs):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in specs]
+        with jax.enable_x64(True):
+            return jax.jit(fn).lower(*args).compile()
+
+    yield compile_for
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def test_serve_tick_kernel_compiles_at_131072_workers(compile_for):
+    """The Pallas serve megakernel at the smoke's fleet size: its ledger
+    block is (8, 128)-tiled and its while-loop carries int32 masks, the
+    two forms Mosaic accepts."""
+    from repro.fleet import qtick as Q
+    from repro.kernels import serve_tick as K
+    from repro.launch.fleet import (WORKLOAD_FACTORIES, build_dispatch_pool,
+                                    make_power_matrix)
+    n = 131072
+    wls = [WORKLOAD_FACTORIES[k]() for k in ("har", "harris", "lm")]
+    pool = build_dispatch_pool(make_power_matrix(["RF"], 1, 1.0), 0.01, 8,
+                               wls, kernel="pallas")
+    qp = Q.quantize_fleet(pool.params)
+    w, u = qp.UCQ.shape
+    pad8 = lambda k: -(-k // 8) * 8  # noqa: E731
+    i32 = jnp.int32
+    rw = [((n,), jnp.bool_ if f in K.BOOL_FIELDS else i32)
+          for f in K.RW_FIELDS]
+    specs = (rw + [((n,), i32)] * (len(K.RO_FIELDS) + 4)
+             + [((pad8(w * u), 128), i32), ((pad8(w), 128), i32),
+                ((pad8(w), 128), i32), ((n,), i32), ((), i32)])
+    n_rw, n_ro = len(K.RW_FIELDS), len(K.RO_FIELDS)
+
+    def tick(*a):
+        rw = dict(zip(K.RW_FIELDS, a[:n_rw]))
+        ro = dict(zip(K.RO_FIELDS, a[n_rw:n_rw + n_ro]))
+        consts = dict(zip(("e_on", "e_off", "e_max", "estep"),
+                          a[n_rw + n_ro:n_rw + n_ro + 4]))
+        tables = dict(zip(("uc", "fix", "emitc"),
+                          a[n_rw + n_ro + 4:n_rw + n_ro + 7]))
+        return K.serve_tick(rw, ro, consts, tables, a[-2], a[-1],
+                            u_max=int(u), interpret=False)
+
+    compiled = compile_for(tick, *specs)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_control_plane_forms_compile_inside_a_scan(compile_for):
+    """The sort, prefix sums, gathers, scatters and whole-tick latency sum
+    of the array control plane (``repro.fleet.sched``) in the forms the
+    serve scan uses, fused into one scan body: XLA:TPU refused the float64 bitcast a sort key
+    would otherwise need, and ran out of scoped VMEM on emulated-int64
+    prefix sums fused inside the serve scan."""
+    from repro.fleet import sched as S
+    n, b = 1024, 4
+
+    def body(c, _):
+        key, valid, cnt, slots, ring, lat_sum = c
+        order = S._argsort(-key, valid, jnp)
+        csum = S._cumsum(cnt, jnp)
+        rank = S._cumsum_slots(slots, jnp)
+        phys = rank % ring.shape[0]
+        got = S._take(ring, phys, jnp)
+        ring = S._scatter_set(ring, phys, got + 1.0, jnp)
+        key = key + S._take(key, order, jnp) * 1e-3
+        cnt = (cnt + csum) % 5
+        ticks = jnp.rint(key / 0.01).astype(jnp.int64)
+        lat_sum = lat_sum + jnp.sum(jnp.where(valid, ticks, 0))
+        return (key, valid, cnt, slots, ring, lat_sum), None
+
+    def fn(*c):
+        return lax.scan(body, c, None, length=4)[0]
+
+    compiled = compile_for(fn, ((n,), jnp.float64), ((n,), jnp.bool_),
+                           ((n,), jnp.int64), ((n, b), jnp.int64),
+                           ((4096,), jnp.float64), ((), jnp.int64))
+    assert compiled.memory_analysis() is not None
+

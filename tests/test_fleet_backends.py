@@ -43,7 +43,7 @@ def _local_pair(power, n_workers, policy, *, duration_ticks=None, cap=None,
               active_power_w=active_power_w)
     a = FleetWorkerPool(power, DT, backend="numpy", **kw)
     b = FleetWorkerPool(power, DT, backend="jax", use_pallas=use_pallas,
-                        **kw)
+                        interpret=use_pallas, **kw)
     sa = a.run(duration_ticks)
     sb = b.run(duration_ticks)
     return a, b, sa, sb

@@ -113,8 +113,8 @@ def test_plan_budget_ou_bit_exact_vs_pr3_formula():
 
 @pytest.mark.parametrize("mode", F.FORECASTER_MODES)
 def test_forecaster_numpy_and_jnp_paths_agree(mode):
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     rows = _bank(["SOM", "SIM", "RF", "SIR"], rows=8)
     L = 300
@@ -126,7 +126,7 @@ def test_forecaster_numpy_and_jnp_paths_agree(mode):
         lags = _lags(rows, rf.order, t)
         a = F.usable_energy_rows(rf, usable, lags, L * DT, e_cap=E_CAP,
                                  booster_eff=CAP.booster_eff, xp=np)
-        with enable_x64():
+        with jax.enable_x64(True):
             b = F.usable_energy_rows(
                 rf, jnp.asarray(usable), jnp.asarray(lags), L * DT,
                 e_cap=E_CAP, booster_eff=CAP.booster_eff, xp=jnp)
@@ -135,7 +135,7 @@ def test_forecaster_numpy_and_jnp_paths_agree(mode):
         np.testing.assert_allclose(np.asarray(b), a, rtol=1e-14, atol=0)
         # the regime branch decision itself must be identical
         fa = F.forecast_power_rows(rf, lags, xp=np)
-        with enable_x64():
+        with jax.enable_x64(True):
             fb = F.forecast_power_rows(rf, jnp.asarray(lags), xp=jnp)
         np.testing.assert_allclose(np.asarray(fb), fa, rtol=1e-14, atol=0)
         assert np.all(fa >= 0.0)

@@ -255,7 +255,8 @@ def _serve_counts(n, backend, kernel, duration_s=20.0, seed=0):
     r = run_scheduled(power, DT, n, wls, rate_rps=max(n / 10.0, 0.5),
                       mix=np.array([0.6, 0.4]),
                       n_steps=int(duration_s / DT), seed=seed,
-                      backend=backend, kernel=kernel)
+                      backend=backend, kernel=kernel,
+                      interpret=kernel == "pallas")
     return {k: r[k] for k in COUNT_KEYS}
 
 
@@ -323,7 +324,8 @@ if _HAS_HYPOTHESIS:
                               rate_rps=max(n / 10.0, 0.5),
                               mix=np.array([0.6, 0.4]),
                               n_steps=int(12.0 / DT), seed=seed,
-                              backend=backend, kernel=kernel)
+                              backend=backend, kernel=kernel,
+                              interpret=kernel == "pallas")
             return {k: r[k] for k in COUNT_KEYS}
 
         ref = counts("numpy", "q32")

@@ -1,0 +1,134 @@
+"""The control plane's TPU forms against their NumPy twins, at the bounds
+they claim.
+
+Under jax, ``repro.fleet.sched`` sums bounded counts in int32, divides
+one batch's units in int32, sorts on an int64 image of the float64 rank
+key, and gathers/scatters (N, B) slot arrays one column at a time (see
+the helpers' docstrings for why XLA:TPU needs each). Every form must give
+the NumPy twin's integers exactly.
+"""
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.fleet import sched as S
+
+N_MAX = 1_048_576  # the top of the fleet-size axis
+B_MAX = 4  # --max-batch default
+
+
+def _jit(fn, *args):
+    with jax.enable_x64(True):
+        return jax.tree.map(np.asarray, jax.jit(fn)(*args))
+
+
+@pytest.mark.parametrize("fill", ["bound", "random"])
+def test_batch_cumsum_int32_at_a_million_workers(fill):
+    """Dispatch's per-worker batch prefix sum: N * B stays below 2**31."""
+    rng = np.random.default_rng(0)
+    b = (np.full(N_MAX, B_MAX, np.int64) if fill == "bound"
+         else rng.integers(0, B_MAX + 1, N_MAX).astype(np.int64))
+    got = _jit(lambda x: S._cumsum(x, jnp), b)
+    want = S._cumsum(b, np)
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, want)
+    if fill == "bound":
+        assert got[-1] == N_MAX * B_MAX
+
+
+@pytest.mark.parametrize("fill", ["bound", "random"])
+def test_slot_rank_cumsum_int32_at_a_million_workers(fill):
+    """Requeue's and the quality ledger's (worker, slot)-order ranks."""
+    rng = np.random.default_rng(1)
+    m = (np.ones((N_MAX, B_MAX), np.int64) if fill == "bound"
+         else (rng.random((N_MAX, B_MAX)) < 0.3).astype(np.int64))
+    got = _jit(lambda x: S._cumsum_slots(x, jnp), m)
+    np.testing.assert_array_equal(got, S._cumsum_slots(m, np))
+    np.testing.assert_array_equal(got.reshape(-1),
+                                  np.cumsum(m.reshape(-1)))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_rank_sort_matches_numpy_on_near_ties(seed):
+    """The int64 order key sorts exactly as NumPy's stable float64
+    argsort: one-ulp neighbours, exact ties, 0.0 against -0.0, invalid
+    entries last in index order."""
+    rng = np.random.default_rng(seed)
+    n = N_MAX if seed == 0 else 4096
+    x = rng.uniform(0, 1e-2, n) * 10.0 ** rng.integers(-8, 3, n)
+    k = n // 3
+    x[rng.integers(0, n, k)] = np.nextafter(
+        x[rng.integers(0, n, k)], np.inf * rng.choice([-1, 1], k))
+    x[rng.integers(0, n, 50)] = 0.0
+    x[rng.integers(0, n, 50)] = -0.0
+    x[rng.integers(0, n, 100)] = x[rng.integers(0, n, 100)]
+    x = np.where(rng.random(n) < 0.5, x, -x)
+    valid = rng.random(n) < 0.8
+    got = _jit(lambda a, v: S._argsort(a, v, jnp), x, valid)
+    np.testing.assert_array_equal(got, S._argsort(x, valid, np))
+
+
+def test_slot_gather_scatter_match_numpy():
+    rng = np.random.default_rng(3)
+    n, b, q = 2048, B_MAX, 10000
+    ring = rng.random(q)
+    idx = rng.integers(0, q, (n, b))
+    v = rng.random((n, b))
+    # unique targets, plus a shared dump slot whose write is discarded
+    perm = rng.permutation(q - 1)[:n * b].reshape(n, b)
+    dump = rng.random((n, b)) < 0.3
+    phys = np.where(dump, q - 1, perm)
+    order = rng.permutation(n)
+    hist = rng.integers(0, 33, (n, b))
+    got = _jit(lambda r, i, p, vv, o, h: (
+        S._take(r, i, jnp), S._scatter_set(r, p, vv, jnp)[:-1],
+        S._scatter_set(jnp.zeros((n, b)), o, vv, jnp),
+        S._scatter_add(jnp.zeros(34, jnp.int64), h, 1, jnp)),
+        ring, idx, phys, v, order, hist)
+    want = (S._take(ring, idx, np), S._scatter_set(ring, phys, v, np)[:-1],
+            S._scatter_set(np.zeros((n, b)), order, v, np),
+            S._scatter_add(np.zeros(34, np.int64), hist, 1, np))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_collect_ledger_int32_forms_at_large_counters():
+    """Collect's int32 unit division and the ledger's reduced sample
+    numbering, with run-long completion counters far past 2**31."""
+    from repro.fleet.worker import FleetWorkerPool
+    from repro.fleet.workloads import har_workload, harris_workload, \
+        lm_workload
+    rng = np.random.default_rng(4)
+    wls = [har_workload(), harris_workload(), lm_workload()]
+    n = 512
+    pool = FleetWorkerPool(np.full((1, 100), 1e-3), 0.01,
+                           workloads=[w.costs for w in wls],
+                           mode="dispatch", n_workers=n)
+    sp = S.make_sched_params(pool.params, wls, max_batch=B_MAX)
+    ss = S.make_sched_state(sp)
+    ss.f_n = rng.integers(0, B_MAX + 1, n).astype(np.int64)
+    ss.f_wl = rng.integers(0, sp.W, n).astype(np.int64)
+    ss.f_units = rng.integers(0, int(sp.NU.max()) + 1, n).astype(np.int64)
+    ss.f_arr = rng.uniform(0.0, 5.0, (n, B_MAX))
+    ss.f_retry = rng.integers(0, 3, (n, B_MAX)).astype(np.int64)
+    ss.completed_wl = (np.int64(1) << 40) + rng.integers(0, 1 << 20, sp.W)
+    emit = rng.random(n) < 0.6
+    lost = ~emit & (rng.random(n) < 0.3)
+    units = rng.integers(0, B_MAX * int(sp.NU.max()) + 1, n)
+    args = (emit, lost, units.astype(np.int64), 7.5)
+    want = S._collect_impl(sp, S.SS(*(getattr(ss, f)
+                                      for f in S.SS._fields)), *args, np)
+    got = _jit(lambda s, e, lo, u: S._collect_impl(sp, S.SS(*s), e, lo, u,
+                                                   7.5, jnp),
+               tuple(getattr(ss, f) for f in S.SS._fields), *args[:3])
+    assert int(want.completed) > 0
+    for f, g, w in zip(S.SS._fields, got, want):
+        w = np.asarray(w)
+        if w.dtype.kind == "f":
+            np.testing.assert_allclose(g, w, rtol=1e-12, err_msg=f)
+        else:
+            np.testing.assert_array_equal(g, w, err_msg=f)

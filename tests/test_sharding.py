@@ -109,7 +109,7 @@ import repro.launch.mesh as mesh_mod
 def small_mesh(*, multi_pod=False):
     shape = (2, 2, 2) if multi_pod else (4, 2)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return mesh_mod.make_mesh(shape, axes)
 mesh_mod.make_production_mesh = small_mesh
 from repro.sharding.context import MeshContext
 def small_ctx(*, multi_pod=False):
@@ -361,6 +361,16 @@ def test_mesh_fleet_must_divide_workers():
         _tiny_sharded_run(mesh_fleet=3)  # 8 % 3 != 0
 
 
+def test_sharded_serve_never_falls_back_to_one_device(monkeypatch):
+    # --mesh-fleet K runs on a K-device mesh by default: with fewer
+    # devices it raises; the one-device vmap runs only when asked for
+    import jax
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    with pytest.raises(ValueError, match="needs 2 devices"):
+        _tiny_sharded_run(mesh_fleet=2)
+
+
 def test_sharded_rejects_pallas_kernel():
     with pytest.raises(ValueError, match="Pallas serve megakernel"):
         _tiny_sharded_run(kernel="pallas")
@@ -406,8 +416,7 @@ sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.sharding.context import (FLEET_AXIS, make_fleet_mesh,
-                                    shard_map_compat)
+from repro.sharding.context import FLEET_AXIS, make_fleet_mesh
 K, ns = 8, 32
 state = {{"v": np.arange(K * ns, dtype=np.int64).reshape(K, ns),
          "on": (np.arange(K * ns) % 3 == 0).reshape(K, ns)}}
@@ -428,9 +437,9 @@ def shard_fn(sh):
     return jax.tree.map(lambda x: x[None], (c, ys))
 
 mesh = make_fleet_mesh(K)
-sm = jax.jit(shard_map_compat(shard_fn, mesh=mesh,
-                              in_specs=(P(FLEET_AXIS),),
-                              out_specs=P(FLEET_AXIS)))(state)
+sm = jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                           in_specs=(P(FLEET_AXIS),),
+                           out_specs=P(FLEET_AXIS), check_vma=False))(state)
 vm = jax.vmap(per_shard, axis_name=FLEET_AXIS)(state)
 ok = all(bool((np.asarray(a) == np.asarray(b)).all())
          for a, b in zip(jax.tree.leaves(sm), jax.tree.leaves(vm)))
@@ -470,7 +479,7 @@ fams = trace_family_labels(TRACES, rows)
 out = {{}}
 for reb in (0.0, 1.0):
     blobs = {{}}
-    for name, backend, placement in (("numpy", "numpy", "auto"),
+    for name, backend, placement in (("numpy", "numpy", "mesh"),
                                      ("single", "jax", "single"),
                                      ("mesh", "jax", "mesh")):
         wls = [har_workload(), harris_workload(), lm_workload()]

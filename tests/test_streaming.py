@@ -316,7 +316,7 @@ except ImportError:
 
 
 def _mk_serve(backend, n_workers=16, duration_s=8.0, seed=0, shards=1,
-              kernel="xla", placement="auto", rebalance_every=0,
+              kernel="xla", placement="mesh", rebalance_every=0,
               forecaster="ou", forecaster_fit="full", arrival_seed=1,
               rate_scale=8.0, persist="none", grace_s=20.0):
     """One (pool, scheduler, stream, n_steps) serve fixture. Separate

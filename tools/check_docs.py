@@ -5,7 +5,8 @@ Three checks:
 
 - every ``--flag`` token in README.md and docs/*.md appears in the
   ``--help`` output of the CLIs the docs describe (``repro.launch.fleet``
-  plus the ``benchmarks.fleet_*`` suites — see ``CLIS``) — catches the
+  plus the ``benchmarks.fleet_*`` suites and ``chip_smoke`` — see
+  ``CLIS``) — catches the
   classic drift where a flag is renamed or removed but the prose keeps
   recommending it;
 - every committed ``experiments/*.json`` artifact has a schema entry in
@@ -43,7 +44,8 @@ ROOT = Path(__file__).resolve().parent.parent
 CLIS = ("repro.launch.fleet", "benchmarks.fleet_throughput",
         "benchmarks.fleet_quality", "benchmarks.fleet_observability",
         "benchmarks.fleet_megakernel", "benchmarks.fleet_sharded_scaling",
-        "benchmarks.fleet_streaming", "benchmarks.fleet_exactness")
+        "benchmarks.fleet_streaming", "benchmarks.fleet_exactness",
+        "chip_smoke")
 DOCS = ("README.md", "docs")
 
 # `--flag` with a word boundary before it (skips ---- rules and
