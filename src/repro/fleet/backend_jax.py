@@ -73,11 +73,18 @@ from repro.fleet.state import (STATE_FIELDS, FleetParams, FleetState,
                                sched_state_as_tuple,
                                sched_state_from_tuple, state_as_tuple,
                                state_from_tuple)
+from repro.obs.profile import span
 
 _S = collections.namedtuple("_S", STATE_FIELDS)
 
 # event codes in the fixed-capacity array log
 EV_NONE, EV_EMIT, EV_LOST = 0, 1, 2
+
+
+def _nbytes(tree) -> int:
+    """Bytes held by the arrays of ``tree`` (host or device; reads
+    shapes only, never waits on the device)."""
+    return int(sum(x.nbytes for x in jax.tree.leaves(tree)))
 
 
 class JaxFleetBackend:
@@ -153,6 +160,7 @@ class JaxFleetBackend:
             if kernel != "xla":
                 from repro.fleet import qtick as Q
                 qp_np = Q.quantize_fleet_cached(params)
+                self._qp_host = qp_np
                 self._qp = Q.convert_arrays(qp_np, jnp.asarray)
                 if kernel == "pallas":
                     from repro.kernels.serve_tick import replicate_table
@@ -165,6 +173,7 @@ class JaxFleetBackend:
                         emitc=replicate_table(qp_np.EMITCQ, pad8(w)))
         self._compiled: dict[int, callable] = {}
         self._serve_compiled: dict[tuple, callable] = {}
+        self._serve_builds = 0  # serve programs built (fleet.serve.compile)
         self._serve_sp: SchedParams | None = None
         self._pow_cs = None  # lazy shared power prefix-sum (obs)
 
@@ -246,8 +255,8 @@ class JaxFleetBackend:
 
     def run_serve(self, state: FleetState, sp: SchedParams,
                   sched_state: SchedState, arrivals: np.ndarray, *,
-                  i0: int = 0, dispatch_every: int = 10, obs=None
-                  ) -> tuple[FleetState, SchedState]:
+                  i0: int = 0, dispatch_every: int = 10, obs=None,
+                  chunk: int = 0) -> tuple[FleetState, SchedState]:
         """The whole serve trace — device physics AND the array-native
         control plane (``repro.fleet.sched``) — as one ``lax.scan``: the
         per-tick arrival counts are the scan input, admission/collection
@@ -259,7 +268,14 @@ class JaxFleetBackend:
         event-ring arrays through the scan carry and writes them back
         here — the serve expressions themselves are untouched (the
         zero-perturbation contract), and with ``obs=None`` the compiled
-        program is byte-identical to the uninstrumented build."""
+        program is byte-identical to the uninstrumented build.
+
+        Host spans (``repro.obs.profile.span``, each with the stat
+        ``chunk``): ``fleet.serve.upload`` (stat ``bytes``), then
+        ``fleet.serve.call``, or ``fleet.serve.compile`` on the first
+        call of a program just built (stats ``n_ticks``,
+        ``dispatch_every``, ``builds``), then ``fleet.serve.readback``
+        (stat ``bytes``)."""
         if self.p.mode != "dispatch":
             raise ValueError("run_serve needs a dispatch-mode fleet")
         if obs is not None and self.kernel != "xla":
@@ -269,7 +285,7 @@ class JaxFleetBackend:
         if sp.shards > 1:
             return self._run_serve_sharded(
                 state, sp, sched_state, arrivals, i0=i0,
-                dispatch_every=int(dispatch_every), obs=obs)
+                dispatch_every=int(dispatch_every), obs=obs, chunk=chunk)
         from repro.fleet import sched as S
         arrivals = np.asarray(arrivals, dtype=np.int64)
         n_ticks = arrivals.shape[0]
@@ -281,40 +297,49 @@ class JaxFleetBackend:
         if not S.sched_params_compatible(self._serve_sp, sp):
             self._serve_compiled = {}
         self._serve_sp = sp
+        host = [state_as_tuple(state), sched_state_as_tuple(sched_state)]
+        if op is not None:
+            from repro.obs.state import (ring_as_tuple, ring_from_tuple,
+                                         tele_as_tuple, tele_from_tuple)
+            host += [tele_as_tuple(obs.tele),
+                     None if obs.ring is None else ring_as_tuple(obs.ring)]
+        host += [self._worker_inputs(sp), arrivals, np.int64(i0)]
         with jax.enable_x64(True):
-            fs = tuple(jnp.asarray(x) for x in state_as_tuple(state))
-            ss = tuple(jnp.asarray(x)
-                       for x in sched_state_as_tuple(sched_state))
-            pw = self._worker_inputs(sp)
-            fn = self._serve_compiled.get(key)
-            if fn is None:
-                fn = self._build_serve(sp, n_ticks, int(dispatch_every),
-                                       op=op)
-                self._serve_compiled[key] = fn
-            if op is None:
-                fs, ss = fn(fs, ss, pw, jnp.asarray(arrivals),
-                            jnp.asarray(i0, jnp.int64))
-            else:
-                from repro.obs.state import (ring_as_tuple,
-                                             ring_from_tuple,
-                                             tele_as_tuple,
-                                             tele_from_tuple)
-                tele = tuple(jnp.asarray(x)
-                             for x in tele_as_tuple(obs.tele))
-                ring = (None if obs.ring is None else
-                        tuple(jnp.asarray(x)
-                              for x in ring_as_tuple(obs.ring)))
-                fs, ss, tele, ring = fn(fs, ss, tele, ring, pw,
-                                        jnp.asarray(arrivals),
-                                        jnp.asarray(i0, jnp.int64))
-                obs.tele = tele_from_tuple(
-                    tuple(np.asarray(x) for x in tele))
-                if ring is not None:
-                    obs.ring = ring_from_tuple(
-                        tuple(np.asarray(x) for x in ring))
-            fs = tuple(np.array(x) for x in fs)
-            ss = tuple(np.asarray(x) for x in ss)
+            with span("fleet.serve.upload", chunk=chunk,
+                      bytes=_nbytes(host)):
+                args = jax.tree.map(jnp.asarray, host)
+            fn, launch = self._serve_fn(
+                key, lambda: self._build_serve(sp, n_ticks,
+                                               int(dispatch_every), op=op),
+                chunk)
+            with launch:
+                out = fn(*args)
+            with span("fleet.serve.readback", chunk=chunk,
+                      bytes=_nbytes(out)):
+                # np.array (copy): the host state must stay writable
+                fs = tuple(np.array(x) for x in out[0])
+                ss, *obs_out = jax.tree.map(np.asarray, out[1:])
+        if op is not None:
+            tele, ring = obs_out
+            obs.tele = tele_from_tuple(tele)
+            if ring is not None:
+                obs.ring = ring_from_tuple(ring)
         return state_from_tuple(fs), sched_state_from_tuple(ss)
+
+    def _serve_fn(self, key, build, chunk: int):
+        """The serve program of ``key`` (``build()`` on a miss) and the
+        host span its launch runs in: ``fleet.serve.compile`` on the
+        first call of a program just built (that call traces and compiles
+        it), else ``fleet.serve.call``. ``key`` leads with the chunk's
+        ticks and the dispatch cadence."""
+        fn = self._serve_compiled.get(key)
+        if fn is not None:
+            return fn, span("fleet.serve.call", chunk=chunk)
+        fn = self._serve_compiled[key] = build()
+        self._serve_builds += 1
+        return fn, span("fleet.serve.compile", chunk=chunk,
+                        n_ticks=key[0], dispatch_every=key[1],
+                        builds=self._serve_builds)
 
     def _power_cumsum(self):
         """Shared (R, T+1) power prefix-sum, computed once in NumPy (so
@@ -359,59 +384,68 @@ class JaxFleetBackend:
             fs0 = _S(*fs)
             ssb = ss  # tick-start snapshot (immutable namedtuple view)
             t = i * p.dt
-            ss = S.admit(sp, ss, counts, t, jnp)
+            with jax.named_scope("fleet.admit"):
+                ss = S.admit(sp, ss, counts, t, jnp)
             is_tick = (i % dispatch_every) == 0
 
             def do_dispatch(args):
                 fsn, ss = args
-                ss = S.shed(sp, ss, t, jnp)
-                if quant:
-                    # quanta -> joules: the exact float64 expression the
-                    # NumPy host driver evaluates (backend agreement)
-                    budget_now = (capacitor_usable_q(
-                        fsn.v, view._qp.E_OFF, jnp)
-                        .astype(jnp.float64) * p.quantum_j)
-                else:
-                    budget_now = view._usable(fsn.v)
-                pw_lags = S.power_lags(view.power, view.trace_index, i,
-                                       p.T, sp.fc_order, phase=view.phase,
-                                       xp=jnp)
-                budget_plan = S.plan_budget(sp, budget_now, pw_lags,
-                                            p.eff, jnp)
+                with jax.named_scope("fleet.shed"):
+                    ss = S.shed(sp, ss, t, jnp)
+                with jax.named_scope("fleet.plan"):
+                    if quant:
+                        # quanta -> joules: the exact float64 expression
+                        # the NumPy host path evaluates (agreement)
+                        budget_now = (capacitor_usable_q(
+                            fsn.v, view._qp.E_OFF, jnp)
+                            .astype(jnp.float64) * p.quantum_j)
+                    else:
+                        budget_now = view._usable(fsn.v)
+                    pw_lags = S.power_lags(view.power, view.trace_index,
+                                           i, p.T, sp.fc_order,
+                                           phase=view.phase, xp=jnp)
+                    budget_plan = S.plan_budget(sp, budget_now, pw_lags,
+                                                p.eff, jnp)
                 if rebalance is not None:
-                    ss = lax.cond((i % sp.rebalance_every) == 0,
-                                  lambda s: rebalance(s, budget_plan),
-                                  lambda s: s, ss)
-                dispatchable = fsn.on & ~fsn.has_work & ~fsn.p_pending
-                ss, a = S.dispatch(sp, ss, dispatchable, budget_now,
-                                   budget_plan, t, jnp)
-                cast = ((lambda x: x.astype(jnp.int32)) if quant
-                        else (lambda x: x))
-                fsn = fsn._replace(
-                    p_pending=fsn.p_pending | a.mask,
-                    p_wl=jnp.where(a.mask, cast(a.wl), fsn.p_wl),
-                    p_units=jnp.where(a.mask, cast(a.units),
-                                      fsn.p_units),
-                    p_batch=jnp.where(a.mask,
-                                      cast(jnp.maximum(a.batch, 1)),
-                                      fsn.p_batch),
-                    p_t_assigned=jnp.where(
-                        a.mask, cast(i) if quant else t,
-                        fsn.p_t_assigned))
+                    with jax.named_scope("fleet.rebalance"):
+                        ss = lax.cond((i % sp.rebalance_every) == 0,
+                                      lambda s: rebalance(s, budget_plan),
+                                      lambda s: s, ss)
+                with jax.named_scope("fleet.dispatch"):
+                    dispatchable = fsn.on & ~fsn.has_work & ~fsn.p_pending
+                    ss, a = S.dispatch(sp, ss, dispatchable, budget_now,
+                                       budget_plan, t, jnp)
+                with jax.named_scope("fleet.assign"):
+                    cast = ((lambda x: x.astype(jnp.int32)) if quant
+                            else (lambda x: x))
+                    fsn = fsn._replace(
+                        p_pending=fsn.p_pending | a.mask,
+                        p_wl=jnp.where(a.mask, cast(a.wl), fsn.p_wl),
+                        p_units=jnp.where(a.mask, cast(a.units),
+                                          fsn.p_units),
+                        p_batch=jnp.where(a.mask,
+                                          cast(jnp.maximum(a.batch, 1)),
+                                          fsn.p_batch),
+                        p_t_assigned=jnp.where(
+                            a.mask, cast(i) if quant else t,
+                            fsn.p_t_assigned))
                 return fsn, ss
 
             fsn, ss = lax.cond(is_tick, do_dispatch, lambda x: x,
                                (fs0, ss))
-            if quant:
-                ev0 = tuple(jnp.zeros(n, jnp.int32) for _ in range(4))
-            else:
-                ev0 = (jnp.zeros(n, jnp.int64), jnp.zeros(n, jnp.float64),
-                       jnp.zeros(n, jnp.int64), jnp.zeros(n, jnp.int64))
-            fs2, ev = tick(tuple(fsn), ev0, i)
-            evc, _, _, evu = ev
-            ss = S.collect(sp, ss, evc == EV_EMIT, evc == EV_LOST,
-                           evu.astype(jnp.int64) if quant else evu,
-                           t, jnp)
+            with jax.named_scope("fleet.tick"):
+                if quant:
+                    ev0 = tuple(jnp.zeros(n, jnp.int32) for _ in range(4))
+                else:
+                    ev0 = (jnp.zeros(n, jnp.int64),
+                           jnp.zeros(n, jnp.float64),
+                           jnp.zeros(n, jnp.int64), jnp.zeros(n, jnp.int64))
+                fs2, ev = tick(tuple(fsn), ev0, i)
+            with jax.named_scope("fleet.collect"):
+                evc, _, _, evu = ev
+                ss = S.collect(sp, ss, evc == EV_EMIT, evc == EV_LOST,
+                               evu.astype(jnp.int64) if quant else evu,
+                               t, jnp)
 
             def do_evict(args):
                 fsn, ss = args
@@ -420,8 +454,9 @@ class JaxFleetBackend:
                                     has_work=fsn.has_work & ~evm), ss
 
             fs2s = _S(*fs2)
-            fsn2, ss = lax.cond(is_tick, do_evict, lambda x: x,
-                                (fs2s, ss))
+            with jax.named_scope("fleet.evict"):
+                fsn2, ss = lax.cond(is_tick, do_evict, lambda x: x,
+                                    (fs2s, ss))
             if op is None:
                 return (tuple(fsn2), ss), None
             # observability: pure reads of the before/after snapshots
@@ -486,7 +521,7 @@ class JaxFleetBackend:
 
     def _run_serve_sharded(self, state: FleetState, sp: SchedParams,
                            sched_state: SchedState, arrivals, *, i0,
-                           dispatch_every, obs):
+                           dispatch_every, obs, chunk: int = 0):
         """``run_serve`` for ``sp.shards == K > 1``: the worker axis is
         split into K contiguous row-shards, each with its own control
         plane (per-shard ring queues, ``max_queue // K`` admission),
@@ -495,7 +530,7 @@ class JaxFleetBackend:
         ``fleet_placement="single"`` asks for it, a one-device ``vmap``
         with the same named axis. The two placements (and the NumPy
         host twin) are bit-identical: the shard split is semantic, the
-        placement is not."""
+        placement is not. Host spans as :meth:`run_serve`'s."""
         from repro.fleet import sched as S
         p = self.p
         K = sp.shards
@@ -532,38 +567,37 @@ class JaxFleetBackend:
             a = np.asarray(x)
             return np.ascontiguousarray(a.reshape((K, ns) + a.shape[1:]))
 
+        host = {"fs": tuple(resh(x) for x in state_as_tuple(state)),
+                # the sched state is already stacked (K, ...)
+                "ss": sched_state_as_tuple(sched_state), "arr": arr,
+                **self._worker_inputs(sp, resh)}
+        host = (host, np.int64(i0))
         with jax.enable_x64(True):
-            fs = tuple(jnp.asarray(resh(x))
-                       for x in state_as_tuple(state))
-            ss = tuple(jnp.asarray(x)  # already stacked (K, ...)
-                       for x in sched_state_as_tuple(sched_state))
-            sh = {"fs": fs, "ss": ss, "arr": jnp.asarray(arr),
-                  **self._worker_inputs(sp, resh)}
-            fn = self._serve_compiled.get(key)
-            if fn is None:
-                fn = self._build_serve_sharded(sp, n_ticks,
-                                               int(dispatch_every), op,
-                                               use_mesh)
-                self._serve_compiled[key] = fn
-            out = fn(sh, jnp.asarray(i0, jnp.int64))
+            with span("fleet.serve.upload", chunk=chunk,
+                      bytes=_nbytes(host)):
+                args = jax.tree.map(jnp.asarray, host)
+            fn, launch = self._serve_fn(
+                key, lambda: self._build_serve_sharded(
+                    sp, n_ticks, int(dispatch_every), op, use_mesh),
+                chunk)
+            with launch:
+                out = fn(*args)
             spanned = len(jax.tree.leaves(out)[0].sharding.device_set)
             if use_mesh and spanned != K:
                 raise RuntimeError(
                     f"the {K}-shard mesh serve ran on {spanned} device(s)")
-            if op is None:
-                fs, ss = out
-            else:
-                fs, ss, tele = out
-                from repro.obs.state import tele_as_tuple, tele_from_tuple
-                # per-shard windows summed over K: every channel is a
-                # scatter-add, so the shard sum IS the global counter
-                obs.tele = tele_from_tuple(tuple(
-                    np.asarray(o) + np.asarray(t).sum(axis=0)
-                    for o, t in zip(tele_as_tuple(obs.tele), tele)))
-            fs = tuple(np.array(x).reshape((K * ns,)
-                                           + np.asarray(x).shape[2:])
-                       for x in fs)
-            ss = tuple(np.asarray(x) for x in ss)
+            with span("fleet.serve.readback", chunk=chunk,
+                      bytes=_nbytes(out)):
+                fs = tuple(np.array(x).reshape((K * ns,) + x.shape[2:])
+                           for x in out[0])
+                ss, *tele = jax.tree.map(np.asarray, out[1:])
+        if op is not None:
+            from repro.obs.state import tele_as_tuple, tele_from_tuple
+            # per-shard windows summed over K: every channel is a
+            # scatter-add, so the shard sum IS the global counter
+            obs.tele = tele_from_tuple(tuple(
+                np.asarray(o) + t.sum(axis=0)
+                for o, t in zip(tele_as_tuple(obs.tele), tele[0])))
         return state_from_tuple(fs), sched_state_from_tuple(ss)
 
     def _build_serve_sharded(self, sp: SchedParams, n_ticks: int,
@@ -645,10 +679,10 @@ class JaxFleetBackend:
     _QP_WORKER_FIELDS = ("E_ON", "E_OFF", "E_MAX", "ESTEP")
 
     def _worker_inputs(self, sp: SchedParams, resh=None) -> dict:
-        """The per-worker runtime inputs of a serve program, as device
-        arrays; ``resh`` reshapes each (N, ...) host array first (the
-        sharded build's (K, N/K, ...) split). A ``None`` phase becomes
-        zeros: ``(i + 0) % T == i % T``."""
+        """The per-worker runtime inputs of a serve program, as host
+        arrays (the launch uploads them); ``resh`` reshapes each (N, ...)
+        array (the sharded build's (K, N/K, ...) split). A ``None`` phase
+        becomes zeros: ``(i + 0) % T == i % T``."""
         from repro.fleet import sched as S
         p = self.p
         wk = {"ti": p.trace_index,
@@ -657,10 +691,9 @@ class JaxFleetBackend:
               "C": p.C, "v_max": p.v_max, "AP": p.active_power_w,
               "sp": {f: getattr(sp, f) for f in S.PER_WORKER_FIELDS}}
         if self.kernel != "xla":
-            wk["qp"] = {f: getattr(self._qp, f)
+            wk["qp"] = {f: getattr(self._qp_host, f)
                         for f in self._QP_WORKER_FIELDS}
-        return jax.tree.map(lambda x: jnp.asarray(
-            np.asarray(x) if resh is None else resh(x)), wk)
+        return jax.tree.map(np.asarray if resh is None else resh, wk)
 
     def _view(self, wk: dict, n: int) -> "JaxFleetBackend":
         """A shallow copy of this backend whose per-worker constants are

@@ -595,128 +595,132 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
     whose fixed cost is unfunded today), batch size and greedy knob
     refinement on the *planning* budget (forecast inflow funds in-flight
     units). Queue consumption is a cumulative-sum slice per workload."""
+    from repro.obs.profile import scope
     i64 = xp.int64
-    # rank -> worker id: dispatchable workers richest first
-    order = _argsort(-budget_plan, dispatchable, xp)
-    elig = xp.take(dispatchable, order)
-    bn = xp.take(budget_now, order)
-    bp = xp.take(budget_plan, order)
-    if sp.value_order:
-        # sched="quality": serve queues richest-in-accuracy-per-joule
-        # first (a params constant, so the order is static under tracing)
-        wl_order = xp.asarray(sp.WL_RANK)
-    else:
-        head_t = xp.take_along_axis(ss.q_t, ss.q_head[:, None],
-                                    axis=1)[:, 0]
-        wl_order = _argsort(head_t, ss.q_len > 0, xp)  # empty queues last
-    q_head, q_len = ss.q_head, ss.q_len
-    taken = xp.zeros(sp.n, dtype=bool)
-    a_wl = xp.zeros(sp.n, dtype=i64)
-    a_units = xp.zeros(sp.n, dtype=i64)
-    a_batch = xp.zeros(sp.n, dtype=i64)
-    g_arr = xp.zeros((sp.n, sp.B))
-    g_retry = xp.zeros((sp.n, sp.B), dtype=i64)
-    jB = xp.arange(sp.B)[None, :]
-    for k in range(sp.W):  # static: one pass per workload queue
-        wl = wl_order[k]
-        cu = xp.take(xp.asarray(sp.CU), wl, axis=0)
-        ucum = xp.take(xp.asarray(sp.UCUM), wl, axis=0)
-        nu = xp.take(xp.asarray(sp.NU), wl)
-        overhead = (xp.take(xp.asarray(sp.FIX), wl)
-                    + xp.take(xp.asarray(sp.EMITC), wl))
-        qrem = xp.take(q_len, wl)
-        head = xp.take(q_head, wl)
-        # admission: largest knob the instantaneous budget affords (-1:
-        # even fixed+emit does not fit), SMART floor for floored workloads
-        k_aff = xp.searchsorted(cu, bn, side="right").astype(i64) - 1
-        if sp.persist != "none":
-            # exact disciplines (docs/persistence_plane.md): the knob is
-            # pinned at NU — every unit runs — and admission only needs
-            # the fixed+emit overhead funded now; the persisted request
-            # survives power failure and spans recharge cycles
-            p_req = xp.zeros(sp.n, dtype=i64) + nu
-            afford = k_aff >= 0
+    with scope("fleet.dispatch.rank", xp):
+        # rank -> worker id: dispatchable workers richest first
+        order = _argsort(-budget_plan, dispatchable, xp)
+        elig = xp.take(dispatchable, order)
+        bn = xp.take(budget_now, order)
+        bp = xp.take(budget_plan, order)
+    with scope("fleet.dispatch.queues", xp):
+        if sp.value_order:
+            # sched="quality": serve queues richest-in-accuracy-per-joule
+            # first (a params constant, so the order is static under tracing)
+            wl_order = xp.asarray(sp.WL_RANK)
         else:
-            p_req = xp.where(xp.take(xp.asarray(sp.IS_SMART), wl),
-                             xp.take(xp.asarray(sp.P_REQ), wl),
-                             xp.maximum(k_aff, 0))
-            afford = (k_aff >= p_req) & (k_aff >= 0)
-        # batch sizing on the *planning* budget (forecast inflow lets more
-        # floor-knob requests ride one power cycle, amortizing fixed+emit
-        # overhead); greedy knob refinement on the *instantaneous* budget
-        # (spend expected inflow on throughput, never on slower service).
-        # Quality mode sizes batches at the max-measured-accuracy knob
-        # instead of the floor knob: fewer requests ride one power cycle,
-        # each affording the knob where the oracle says accuracy peaks —
-        # under scarcity the target degrades back to the floor (b_want
-        # clips to >= 1 and refinement still bounds at p_req).
-        spend_plan = bp - overhead
-        spend_now = bn - overhead
-        cpr = xp.take(ucum, xp.clip(p_req, 0, ucum.shape[0] - 1))
-        if sp.value_order and sp.persist == "none":
-            # quality mode also CAPS refinement at the target knob:
-            # measured tables are non-monotonic, so units past the peak
-            # cost strictly more joules for no more (often less)
-            # measured accuracy
-            u_cap = xp.maximum(xp.take(xp.asarray(sp.QTARGET), wl), p_req)
-            cpq = xp.take(ucum, xp.clip(u_cap, 0, ucum.shape[0] - 1))
-            cpb = xp.maximum(cpq, cpr)  # never below the admission knob
-        else:
-            u_cap = nu
-            cpb = cpr
-        b_want = xp.where(
-            cpb > 0,
-            xp.floor_divide(spend_plan, xp.maximum(cpb, 1e-300)), sp.B)
-        b_want = xp.clip(b_want, 1, sp.B).astype(i64)
-        u_want = xp.clip(
-            xp.searchsorted(ucum, spend_now / xp.maximum(b_want, 1),
-                            side="right").astype(i64) - 1,
-            p_req, u_cap)
-        ok = elig & ~taken & afford & (u_want > 0)
-        b = xp.where(ok, b_want, 0)
-        c = _cumsum(b, xp)  # b <= B per worker
-        start = c - b
-        actual = xp.clip(qrem - start, 0, b)
-        got = ok & (actual > 0)
-        u = xp.clip(
-            xp.searchsorted(ucum, spend_now / xp.maximum(actual, 1),
-                            side="right").astype(i64) - 1,
-            p_req, u_cap)
-        # consume the queue front: gather each worker's request slice
-        phys = (head + start[:, None] + jB) % sp.Q
-        row_t = xp.take(ss.q_t, wl, axis=0)
-        row_r = xp.take(ss.q_r, wl, axis=0)
-        take_mask = got[:, None] & (jB < actual[:, None])
-        g_arr = xp.where(take_mask, _take(row_t, phys, xp), g_arr)
-        g_retry = xp.where(take_mask, _take(row_r, phys, xp), g_retry)
-        consumed = xp.sum(actual)
-        onehot = xp.arange(sp.W) == wl
-        q_head = xp.where(onehot, (q_head + consumed) % sp.Q, q_head)
-        q_len = xp.where(onehot, q_len - consumed, q_len)
-        taken = taken | got
-        a_wl = xp.where(got, wl, a_wl)
-        a_units = xp.where(got, u, a_units)
-        a_batch = xp.where(got, actual, a_batch)
-    # rank space -> worker space (order is a permutation)
-    z = lambda dt=i64: xp.zeros(sp.n, dtype=dt)  # noqa: E731
-    batch_w = _scatter_set(z(), order, a_batch, xp)
-    mask_w = batch_w > 0
-    wl_w = _scatter_set(z(), order, a_wl, xp)
-    units_w = _scatter_set(z(), order, a_units, xp)
-    arr_w = _scatter_set(xp.zeros((sp.n, sp.B)), order, g_arr, xp)
-    retry_w = _scatter_set(xp.zeros((sp.n, sp.B), dtype=i64), order,
-                           g_retry, xp)
-    ss = ss._replace(
-        q_head=q_head, q_len=q_len,
-        f_n=xp.where(mask_w, batch_w, ss.f_n),
-        f_wl=xp.where(mask_w, wl_w, ss.f_wl),
-        f_units=xp.where(mask_w, units_w, ss.f_units),
-        f_t0=xp.where(mask_w, t, ss.f_t0),
-        f_arr=xp.where(mask_w[:, None], arr_w, ss.f_arr),
-        f_retry=xp.where(mask_w[:, None], retry_w, ss.f_retry),
-        batch_hist=ss.batch_hist + xp.sum(
-            (batch_w[:, None] == xp.arange(sp.B + 1)[None, :])
-            & mask_w[:, None], axis=0))
+            head_t = xp.take_along_axis(ss.q_t, ss.q_head[:, None],
+                                        axis=1)[:, 0]
+            wl_order = _argsort(head_t, ss.q_len > 0, xp)  # empty queues last
+        q_head, q_len = ss.q_head, ss.q_len
+        taken = xp.zeros(sp.n, dtype=bool)
+        a_wl = xp.zeros(sp.n, dtype=i64)
+        a_units = xp.zeros(sp.n, dtype=i64)
+        a_batch = xp.zeros(sp.n, dtype=i64)
+        g_arr = xp.zeros((sp.n, sp.B))
+        g_retry = xp.zeros((sp.n, sp.B), dtype=i64)
+        jB = xp.arange(sp.B)[None, :]
+        for k in range(sp.W):  # static: one pass per workload queue
+            wl = wl_order[k]
+            cu = xp.take(xp.asarray(sp.CU), wl, axis=0)
+            ucum = xp.take(xp.asarray(sp.UCUM), wl, axis=0)
+            nu = xp.take(xp.asarray(sp.NU), wl)
+            overhead = (xp.take(xp.asarray(sp.FIX), wl)
+                        + xp.take(xp.asarray(sp.EMITC), wl))
+            qrem = xp.take(q_len, wl)
+            head = xp.take(q_head, wl)
+            # admission: largest knob the instantaneous budget affords (-1:
+            # even fixed+emit does not fit), SMART floor for floored workloads
+            k_aff = xp.searchsorted(cu, bn, side="right").astype(i64) - 1
+            if sp.persist != "none":
+                # exact disciplines (docs/persistence_plane.md): the knob is
+                # pinned at NU — every unit runs — and admission only needs
+                # the fixed+emit overhead funded now; the persisted request
+                # survives power failure and spans recharge cycles
+                p_req = xp.zeros(sp.n, dtype=i64) + nu
+                afford = k_aff >= 0
+            else:
+                p_req = xp.where(xp.take(xp.asarray(sp.IS_SMART), wl),
+                                 xp.take(xp.asarray(sp.P_REQ), wl),
+                                 xp.maximum(k_aff, 0))
+                afford = (k_aff >= p_req) & (k_aff >= 0)
+            # batch sizing on the *planning* budget (forecast inflow lets more
+            # floor-knob requests ride one power cycle, amortizing fixed+emit
+            # overhead); greedy knob refinement on the *instantaneous* budget
+            # (spend expected inflow on throughput, never on slower service).
+            # Quality mode sizes batches at the max-measured-accuracy knob
+            # instead of the floor knob: fewer requests ride one power cycle,
+            # each affording the knob where the oracle says accuracy peaks —
+            # under scarcity the target degrades back to the floor (b_want
+            # clips to >= 1 and refinement still bounds at p_req).
+            spend_plan = bp - overhead
+            spend_now = bn - overhead
+            cpr = xp.take(ucum, xp.clip(p_req, 0, ucum.shape[0] - 1))
+            if sp.value_order and sp.persist == "none":
+                # quality mode also CAPS refinement at the target knob:
+                # measured tables are non-monotonic, so units past the peak
+                # cost strictly more joules for no more (often less)
+                # measured accuracy
+                u_cap = xp.maximum(xp.take(xp.asarray(sp.QTARGET), wl), p_req)
+                cpq = xp.take(ucum, xp.clip(u_cap, 0, ucum.shape[0] - 1))
+                cpb = xp.maximum(cpq, cpr)  # never below the admission knob
+            else:
+                u_cap = nu
+                cpb = cpr
+            b_want = xp.where(
+                cpb > 0,
+                xp.floor_divide(spend_plan, xp.maximum(cpb, 1e-300)), sp.B)
+            b_want = xp.clip(b_want, 1, sp.B).astype(i64)
+            u_want = xp.clip(
+                xp.searchsorted(ucum, spend_now / xp.maximum(b_want, 1),
+                                side="right").astype(i64) - 1,
+                p_req, u_cap)
+            ok = elig & ~taken & afford & (u_want > 0)
+            b = xp.where(ok, b_want, 0)
+            c = _cumsum(b, xp)  # b <= B per worker
+            start = c - b
+            actual = xp.clip(qrem - start, 0, b)
+            got = ok & (actual > 0)
+            u = xp.clip(
+                xp.searchsorted(ucum, spend_now / xp.maximum(actual, 1),
+                                side="right").astype(i64) - 1,
+                p_req, u_cap)
+            # consume the queue front: gather each worker's request slice
+            phys = (head + start[:, None] + jB) % sp.Q
+            row_t = xp.take(ss.q_t, wl, axis=0)
+            row_r = xp.take(ss.q_r, wl, axis=0)
+            take_mask = got[:, None] & (jB < actual[:, None])
+            g_arr = xp.where(take_mask, _take(row_t, phys, xp), g_arr)
+            g_retry = xp.where(take_mask, _take(row_r, phys, xp), g_retry)
+            consumed = xp.sum(actual)
+            onehot = xp.arange(sp.W) == wl
+            q_head = xp.where(onehot, (q_head + consumed) % sp.Q, q_head)
+            q_len = xp.where(onehot, q_len - consumed, q_len)
+            taken = taken | got
+            a_wl = xp.where(got, wl, a_wl)
+            a_units = xp.where(got, u, a_units)
+            a_batch = xp.where(got, actual, a_batch)
+    with scope("fleet.dispatch.scatter", xp):
+        # rank space -> worker space (order is a permutation)
+        z = lambda dt=i64: xp.zeros(sp.n, dtype=dt)  # noqa: E731
+        batch_w = _scatter_set(z(), order, a_batch, xp)
+        mask_w = batch_w > 0
+        wl_w = _scatter_set(z(), order, a_wl, xp)
+        units_w = _scatter_set(z(), order, a_units, xp)
+        arr_w = _scatter_set(xp.zeros((sp.n, sp.B)), order, g_arr, xp)
+        retry_w = _scatter_set(xp.zeros((sp.n, sp.B), dtype=i64), order,
+                               g_retry, xp)
+        ss = ss._replace(
+            q_head=q_head, q_len=q_len,
+            f_n=xp.where(mask_w, batch_w, ss.f_n),
+            f_wl=xp.where(mask_w, wl_w, ss.f_wl),
+            f_units=xp.where(mask_w, units_w, ss.f_units),
+            f_t0=xp.where(mask_w, t, ss.f_t0),
+            f_arr=xp.where(mask_w[:, None], arr_w, ss.f_arr),
+            f_retry=xp.where(mask_w[:, None], retry_w, ss.f_retry),
+            batch_hist=ss.batch_hist + xp.sum(
+                (batch_w[:, None] == xp.arange(sp.B + 1)[None, :])
+                & mask_w[:, None], axis=0))
     return ss, Assignment(mask_w, wl_w, units_w, batch_w)
 
 

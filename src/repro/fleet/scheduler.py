@@ -613,8 +613,19 @@ def run_fleet_stream(pool: FleetWorkerPool, sched: FleetScheduler,
     delta), refit count, and — when ``slo_p95_s`` > 0 — a per-chunk
     p95 SLO verdict and total violation count. Wall-clock fields are
     nondeterministic; equality checks strip the block.
+
+    Host spans (``repro.obs.profile``; each with the stat ``chunk``, the
+    chunk's index): ``fleet.stream.chunk`` around each chunk (a
+    ``jax.profiler.StepTraceAnnotation``, ``step_num`` the index),
+    holding ``fleet.stream.take``, two ``fleet.stream.snapshot``, the
+    launch's ``fleet.serve.*`` spans, ``fleet.stream.record`` and, when a
+    refit runs, ``fleet.stream.refit``.
     """
     import time
+
+    import jax
+
+    from repro.obs.profile import span
     if chunk_ticks <= 0:
         raise ValueError(f"chunk_ticks={chunk_ticks} must be positive")
     dt = pool.dt
@@ -633,45 +644,55 @@ def run_fleet_stream(pool: FleetWorkerPool, sched: FleetScheduler,
     refits = 0
     violations = 0
     while done < n_steps:
-        k = min(int(chunk_ticks), n_steps - done)
-        counts = (source.take(k) if counts_all is None
-                  else counts_all[done:done + k])
-        before = _chunk_snapshot(sched.state)
-        t0 = time.perf_counter()
-        if is_jax:
-            pool.run_serve(sched, counts, dispatch_every=dispatch_every,
-                           obs=obs)
-        elif sharded:
-            host_serve.window(counts, done)
-        else:
-            _run_fleet_numpy_window(pool, sched, counts, done,
-                                    dispatch_every, obs)
-        wall = time.perf_counter() - t0
-        after = _chunk_snapshot(sched.state)
-        hist = after["lat_hist"] - before["lat_hist"]
-        completed = after["completed"] - before["completed"]
-        lat_sum = after["lat_sum"] - before["lat_sum"]
-        rec = {"tick0": done, "ticks": k,
-               "wall_s": wall,
-               "throughput_rps": completed / (k * dt),
-               "mean_latency_s": (lat_sum * dt / completed
-                                  if completed else 0.0),
-               "p50_s": _hist_percentile(hist, sp.lat_max_s, 0.50),
-               "p95_s": _hist_percentile(hist, sp.lat_max_s, 0.95),
-               "p99_s": _hist_percentile(hist, sp.lat_max_s, 0.99)}
-        for f in _CHUNK_COUNTERS:
-            if f != "lat_sum":
-                rec[f] = after[f] - before[f]
-        if slo_p95_s > 0.0:
-            rec["slo_ok"] = bool(rec["p95_s"] <= slo_p95_s)
-            violations += not rec["slo_ok"]
-        chunks.append(rec)
-        done += k
-        if (refit_every and done < n_steps
-                and done - last_refit >= refit_every):
-            if sched.refit_forecast(done):
-                refits += 1
-            last_refit = done
+        c = len(chunks)
+        with jax.profiler.StepTraceAnnotation("fleet.stream.chunk",
+                                              step_num=c, chunk=c):
+            k = min(int(chunk_ticks), n_steps - done)
+            with span("fleet.stream.take", chunk=c):
+                counts = (source.take(k) if counts_all is None
+                          else counts_all[done:done + k])
+            with span("fleet.stream.snapshot", chunk=c):
+                before = _chunk_snapshot(sched.state)
+            t0 = time.perf_counter()
+            if is_jax:
+                pool.run_serve(sched, counts,
+                               dispatch_every=dispatch_every, obs=obs,
+                               chunk=c)
+            elif sharded:
+                host_serve.window(counts, done)
+            else:
+                _run_fleet_numpy_window(pool, sched, counts, done,
+                                        dispatch_every, obs)
+            wall = time.perf_counter() - t0
+            with span("fleet.stream.snapshot", chunk=c):
+                after = _chunk_snapshot(sched.state)
+            with span("fleet.stream.record", chunk=c):
+                hist = after["lat_hist"] - before["lat_hist"]
+                completed = after["completed"] - before["completed"]
+                lat_sum = after["lat_sum"] - before["lat_sum"]
+                rec = {"tick0": done, "ticks": k,
+                       "wall_s": wall,
+                       "throughput_rps": completed / (k * dt),
+                       "mean_latency_s": (lat_sum * dt / completed
+                                          if completed else 0.0),
+                       "p50_s": _hist_percentile(hist, sp.lat_max_s, 0.50),
+                       "p95_s": _hist_percentile(hist, sp.lat_max_s, 0.95),
+                       "p99_s": _hist_percentile(hist, sp.lat_max_s,
+                                                 0.99)}
+                for f in _CHUNK_COUNTERS:
+                    if f != "lat_sum":
+                        rec[f] = after[f] - before[f]
+                if slo_p95_s > 0.0:
+                    rec["slo_ok"] = bool(rec["p95_s"] <= slo_p95_s)
+                    violations += not rec["slo_ok"]
+                chunks.append(rec)
+            done += k
+            if (refit_every and done < n_steps
+                    and done - last_refit >= refit_every):
+                with span("fleet.stream.refit", chunk=c):
+                    if sched.refit_forecast(done):
+                        refits += 1
+                last_refit = done
     summary = sched.summary(n_steps * dt)
     summary["stream"] = {"chunk_ticks": int(chunk_ticks),
                          "refit_every": int(refit_every),

@@ -329,14 +329,17 @@ class FleetWorkerPool:
                 self.step(i)
 
     def run_serve(self, sched, arrivals: np.ndarray, *,
-                  dispatch_every: int = 10, obs=None) -> None:
+                  dispatch_every: int = 10, obs=None,
+                  chunk: int = 0) -> None:
         """Fused serve: device physics AND the array-native scheduler as
         one ``lax.scan`` launch (JAX backend only; the NumPy reference
         drives the same control-plane expressions tick-by-tick through
         ``repro.fleet.scheduler.run_fleet``). ``sched`` is a
         ``FleetScheduler``; its state is advanced in place. ``obs`` (a
         ``repro.obs.FleetObs``) rides the scan carry and is updated in
-        place — the serve results are bit-identical with or without it."""
+        place — the serve results are bit-identical with or without it.
+        ``chunk`` tags the launch's host spans (the stream's chunk
+        index)."""
         if self.backend != "jax":
             raise ValueError("run_serve is the fused jax path; use "
                              "run_fleet's per-tick driver for numpy pools")
@@ -349,7 +352,8 @@ class FleetWorkerPool:
                 interpret=self.interpret)
         self.state, sched.state = self._jax.run_serve(
             self.state, sched.params, sched.state, arrivals,
-            i0=self.steps_done, dispatch_every=dispatch_every, obs=obs)
+            i0=self.steps_done, dispatch_every=dispatch_every, obs=obs,
+            chunk=chunk)
         self.steps_done += int(np.asarray(arrivals).shape[0])
 
     # -- driving + accounting ------------------------------------------------

@@ -174,7 +174,7 @@ def run_scheduled(power: np.ndarray, dt: float, n_workers: int,
                   slo_p95_s: float = 0.0,
                   persist: str = "none",
                   grace_s: float = 20.0,
-                  interpret: bool = False) -> dict:
+                  interpret: bool = False, profile_dir: str = "") -> dict:
     pool = build_dispatch_pool(power, dt, n_workers, workloads, seed,
                                backend=backend, capacitance_f=capacitance_f,
                                v_max=v_max, active_power_w=active_power_w,
@@ -202,21 +202,23 @@ def run_scheduled(power: np.ndarray, dt: float, n_workers: int,
                              window=max(int(round(obs_window_s / dt)), 1),
                              ring=obs_ring)
     stream = RequestStream(rate_rps, mix, n_steps, dt, seed=seed + 1)
-    if stream_mode:
-        # streaming online serve: a live client thread feeds arrival
-        # rows into the chunked steady-state loop (chunk boundaries are
-        # where causal refits and per-chunk SLO records happen)
-        from repro.fleet.scheduler import StreamClient, run_fleet_stream
-        client = StreamClient(stream, scheduler.params.W, n_steps)
-        summary = run_fleet_stream(
-            pool, scheduler, client, n_steps,
-            chunk_ticks=chunk_ticks or max(n_steps // 8, 1),
-            dispatch_every=dispatch_every,
-            refit_every=int(round(refit_every_s / dt)), obs=obs,
-            slo_p95_s=slo_p95_s)
-    else:
-        summary = run_fleet(pool, scheduler, stream, n_steps,
-                            dispatch_every=dispatch_every, obs=obs)
+    from repro.obs.profile import profiled
+    with profiled(profile_dir):
+        if stream_mode:
+            # streaming online serve: a live client thread feeds arrival
+            # rows into the chunked steady-state loop (chunk boundaries
+            # are where causal refits and per-chunk SLO records happen)
+            from repro.fleet.scheduler import StreamClient, run_fleet_stream
+            client = StreamClient(stream, scheduler.params.W, n_steps)
+            summary = run_fleet_stream(
+                pool, scheduler, client, n_steps,
+                chunk_ticks=chunk_ticks or max(n_steps // 8, 1),
+                dispatch_every=dispatch_every,
+                refit_every=int(round(refit_every_s / dt)), obs=obs,
+                slo_p95_s=slo_p95_s)
+        else:
+            summary = run_fleet(pool, scheduler, stream, n_steps,
+                                dispatch_every=dispatch_every, obs=obs)
     summary["mode"] = "scheduled"
     summary["sched"] = sched
     summary["persist"] = persist
@@ -415,6 +417,13 @@ def main(argv: list[str] | None = None) -> dict:
                          "(--stream; 0: off): each chunk record gets a "
                          "verdict and the stream block counts "
                          "violations")
+    ap.add_argument("--profile-dir", default="",
+                    help="record the serve (--scheduler on) under "
+                         "jax.profiler into this directory: device "
+                         "operations named by their fleet.* scopes and "
+                         "the host's fleet.stream.*/fleet.serve.* spans "
+                         "on one clock (docs/observability.md, 'Program "
+                         "spans'; open in TensorBoard or Perfetto)")
     ap.add_argument("--persist", choices=("none", "ckpt", "undolog"),
                     default="none",
                     help="execution discipline (docs/persistence_plane."
@@ -500,7 +509,7 @@ def main(argv: list[str] | None = None) -> dict:
             stream_mode=args.stream, chunk_ticks=args.chunk_ticks,
             refit_every_s=args.refit_every, slo_p95_s=args.slo_p95,
             persist=args.persist, grace_s=args.grace,
-            interpret=args.interpret)
+            interpret=args.interpret, profile_dir=args.profile_dir)
     if args.scheduler in ("off", "both"):
         out["independent"] = run_independent(
             power, args.dt, args.workers, workloads, mix=mix,

@@ -10,12 +10,13 @@ Four pieces (see docs/observability.md):
   the :class:`FleetObs` host recorder.
 - ``repro.obs.export`` — Chrome trace-event / Perfetto JSON export and
   terminal summaries of the drained rings.
-- ``repro.obs.profile`` — ``jax.profiler`` wrapping + the uniform
-  cold/warm timing split the benchmarks report.
+- ``repro.obs.profile`` — program tracing on the ``jax.profiler``
+  clock: the ``profiled`` recorder, host ``span``s and device
+  ``scope``s.
 """
 from repro.obs.export import (format_ring_summary, format_tele_summary,
                               perfetto_trace, write_trace)
-from repro.obs.profile import profiled, time_compiled
+from repro.obs.profile import profiled, scope, span
 from repro.obs.state import (EVENT_NAMES, OBS_MODES, RING_FIELDS,
                              TELE_FIELDS, ObsParams, RingState,
                              TeleState, init_ring, init_tele,
@@ -27,5 +28,5 @@ __all__ = [
     "ObsParams", "RingState", "TeleState", "FleetObs", "init_ring",
     "init_tele", "make_fleet_obs", "make_obs_params", "obs_tick",
     "perfetto_trace", "write_trace", "format_ring_summary",
-    "format_tele_summary", "profiled", "time_compiled",
+    "format_tele_summary", "profiled", "scope", "span",
 ]

@@ -1,28 +1,35 @@
-"""Profiler wiring: jax.profiler traces + uniform cold/warm timing.
+"""Program tracing on the ``jax.profiler`` clock.
 
-Two small tools the benchmarks and CLIs share:
+Three small tools, all writing into the one profiler trace (host spans
+and device operations share its nanosecond clock):
 
-- :func:`profiled` — context manager around a scan launch. Given a
-  directory it records a ``jax.profiler`` trace there (viewable in
-  Perfetto / TensorBoard); with no directory, or when jax is absent,
-  it is a no-op — callers wrap launches unconditionally.
-- :func:`time_compiled` — the cold/warm wall-clock split every
-  benchmark reports the same way: first call (compile + run) timed as
-  ``cold_s``, then ``iters`` warm calls timed individually for a median
-  and spread. Results are blocked on (``block_until_ready``) when they
-  are jax arrays, so device asynchrony cannot hide work.
+- :func:`profiled` — context manager recording a ``jax.profiler`` trace
+  of the enclosed block into a directory (viewable in Perfetto /
+  TensorBoard); with no directory, or when jax is absent, a no-op, so
+  callers wrap launches unconditionally (``--profile-dir``).
+- :func:`span` — a host span (``jax.profiler.TraceAnnotation``); its
+  keyword values become stats on the span's event, so a counter rides
+  on the span that bounds its work. Near free while no trace records.
+- :func:`scope` — a device scope (``jax.named_scope``) for the
+  xp-generic passes: under ``jax.numpy`` it names the operations traced
+  inside it (HLO ``op_name`` metadata only; the optimized program is
+  otherwise the same), under NumPy it does nothing.
+
+Every program span and scope is named ``fleet.*`` (docs/observability.md,
+"Program spans").
 """
 from __future__ import annotations
 
 import contextlib
-import statistics
-import time
+
+import numpy as np
 
 
 @contextlib.contextmanager
 def profiled(trace_dir: str | None = None):
     """Record a ``jax.profiler`` trace of the enclosed block into
-    ``trace_dir`` (no-op when ``trace_dir`` is falsy or jax is
+    ``trace_dir``: host spans and device operations, without the Python
+    call tracer (no-op when ``trace_dir`` is falsy or jax is
     unavailable)."""
     if not trace_dir:
         yield
@@ -32,35 +39,23 @@ def profiled(trace_dir: str | None = None):
     except ImportError:  # profiler requested but no jax: still run
         yield
         return
-    with jax.profiler.trace(trace_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # spans and device ops, no Python calls
+    with jax.profiler.trace(trace_dir, profiler_options=opts):
         yield
 
 
-def _block(x):
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except (ImportError, TypeError):
-        pass
-    return x
+def span(name: str, **counts):
+    """A host span ``name`` whose keyword values (ints) are recorded as
+    stats of its trace event."""
+    import jax
+    return jax.profiler.TraceAnnotation(name, **counts)
 
 
-def time_compiled(fn, *args, iters: int = 5) -> dict:
-    """Cold/warm wall-clock split of ``fn(*args)``.
-
-    Returns ``{"cold_s", "warm_s", "warm_s_std", "iters"}`` — cold is
-    the first call (compile included), warm is the median of ``iters``
-    subsequent calls, std-dev over those same calls (0.0 when
-    ``iters < 2``)."""
-    t0 = time.perf_counter()
-    _block(fn(*args))
-    cold = time.perf_counter() - t0
-    warm: list[float] = []
-    for _ in range(max(iters, 1)):
-        t0 = time.perf_counter()
-        _block(fn(*args))
-        warm.append(time.perf_counter() - t0)
-    return {"cold_s": cold, "warm_s": statistics.median(warm),
-            "warm_s_std": (statistics.pstdev(warm)
-                           if len(warm) > 1 else 0.0),
-            "iters": len(warm)}
+def scope(name: str, xp):
+    """``jax.named_scope(name)`` when ``xp`` is ``jax.numpy``; a null
+    context under NumPy."""
+    if xp is np:
+        return contextlib.nullcontext()
+    import jax
+    return jax.named_scope(name)
