@@ -397,6 +397,24 @@ def _cumsum(x, xp):
     return xp.cumsum(x.astype(xp.int32), axis=0).astype(x.dtype)
 
 
+def _searchsorted_right(table, v, xp):
+    """``np.searchsorted(table, v, side="right")`` for a short 1-D
+    non-decreasing ``table`` (a knob row of ``CU``/``UCUM``, ``+inf``
+    padded) and an (N,) query ``v``.
+
+    Under jax it counts the table entries at or below each query
+    (``method="compare_all"``): one fused (K, N) compare and reduce. The
+    default binary search is a ``while`` loop of ceil(log2(K + 1)) levels,
+    each a gather of N indices from the table, and on XLA:TPU each such
+    gather costs about a millisecond at N = 131,072, where the whole
+    compare costs microseconds for K in the hundreds. Both compare in
+    jax's same total order on the same stored values, so the counts are
+    the insertion indices exactly, ties included."""
+    if xp is np:
+        return np.searchsorted(table, v, side="right")
+    return xp.searchsorted(table, v, side="right", method="compare_all")
+
+
 # (N, B) per-slot arrays under jax: XLA:TPU lays out the narrow slot axis
 # B minor, and a gather or scatter indexed by such an array, or a reshape
 # between (N, B) and the flat (N*B,) order, costs it about a minute of
@@ -631,7 +649,7 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
             head = xp.take(q_head, wl)
             # admission: largest knob the instantaneous budget affords (-1:
             # even fixed+emit does not fit), SMART floor for floored workloads
-            k_aff = xp.searchsorted(cu, bn, side="right").astype(i64) - 1
+            k_aff = _searchsorted_right(cu, bn, xp).astype(i64) - 1
             if sp.persist != "none":
                 # exact disciplines (docs/persistence_plane.md): the knob is
                 # pinned at NU — every unit runs — and admission only needs
@@ -672,8 +690,8 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
                 xp.floor_divide(spend_plan, xp.maximum(cpb, 1e-300)), sp.B)
             b_want = xp.clip(b_want, 1, sp.B).astype(i64)
             u_want = xp.clip(
-                xp.searchsorted(ucum, spend_now / xp.maximum(b_want, 1),
-                                side="right").astype(i64) - 1,
+                _searchsorted_right(ucum, spend_now / xp.maximum(b_want, 1),
+                                    xp).astype(i64) - 1,
                 p_req, u_cap)
             ok = elig & ~taken & afford & (u_want > 0)
             b = xp.where(ok, b_want, 0)
@@ -682,8 +700,8 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
             actual = xp.clip(qrem - start, 0, b)
             got = ok & (actual > 0)
             u = xp.clip(
-                xp.searchsorted(ucum, spend_now / xp.maximum(actual, 1),
-                                side="right").astype(i64) - 1,
+                _searchsorted_right(ucum, spend_now / xp.maximum(actual, 1),
+                                    xp).astype(i64) - 1,
                 p_req, u_cap)
             # consume the queue front: gather each worker's request slice
             phys = (head + start[:, None] + jB) % sp.Q

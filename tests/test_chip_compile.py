@@ -93,18 +93,21 @@ def test_serve_tick_kernel_compiles_at_131072_workers(compile_for):
 
 
 def test_control_plane_forms_compile_inside_a_scan(compile_for):
-    """The sort, prefix sums, gathers, scatters and whole-tick latency sum
-    of the array control plane (``repro.fleet.sched``) in the forms the
-    serve scan uses, fused into one scan body: XLA:TPU refused the float64 bitcast a sort key
-    would otherwise need, and ran out of scoped VMEM on emulated-int64
-    prefix sums fused inside the serve scan."""
+    """The sort, prefix sums, knob lookup, gathers, scatters and whole-tick
+    latency sum of the array control plane (``repro.fleet.sched``) in the
+    forms the serve scan uses, fused into one scan body: XLA:TPU refused
+    the float64 bitcast a sort key would otherwise need, and ran out of
+    scoped VMEM on emulated-int64 prefix sums fused inside the serve
+    scan."""
     from repro.fleet import sched as S
     n, b = 1024, 4
 
     def body(c, _):
         key, valid, cnt, slots, ring, lat_sum = c
         order = S._argsort(-key, valid, jnp)
-        csum = S._cumsum(cnt, jnp)
+        # a knob table's width; the compile needs only its shape
+        csum = S._cumsum(cnt, jnp) + S._searchsorted_right(ring[:142], key,
+                                                            jnp)
         rank = S._cumsum_slots(slots, jnp)
         phys = rank % ring.shape[0]
         got = S._take(ring, phys, jnp)

@@ -3,7 +3,8 @@ they claim.
 
 Under jax, ``repro.fleet.sched`` sums bounded counts in int32, divides
 one batch's units in int32, sorts on an int64 image of the float64 rank
-key, and gathers/scatters (N, B) slot arrays one column at a time (see
+key, looks knobs up by counting table entries instead of a binary
+search, and gathers/scatters (N, B) slot arrays one column at a time (see
 the helpers' docstrings for why XLA:TPU needs each). Every form must give
 the NumPy twin's integers exactly.
 """
@@ -70,6 +71,56 @@ def test_rank_sort_matches_numpy_on_near_ties(seed):
     valid = rng.random(n) < 0.8
     got = _jit(lambda a, v: S._argsort(a, v, jnp), x, valid)
     np.testing.assert_array_equal(got, S._argsort(x, valid, np))
+
+
+def test_knob_lookup_counts_match_numpy_binary_search():
+    """Dispatch's knob lookup (a count of the table entries at or below
+    each query) against NumPy's right-side binary search, on a
+    ``+inf``-padded non-decreasing float64 table with repeated entries:
+    queries on every entry, one ulp either side of it, below the first
+    entry and above the last finite one."""
+    rng = np.random.default_rng(5)
+    n, k, finite = 131_072, 142, 121
+    table = np.full(k, np.inf)
+    steps = rng.uniform(0, 1e-4, finite - 1) * (rng.random(finite - 1) < 0.8)
+    table[:finite] = np.concatenate([[0.0], np.cumsum(steps)]) + 1e-6
+    ent = table[:finite]
+    edge = np.concatenate([ent, np.nextafter(ent, np.inf),
+                           np.nextafter(ent, -np.inf),
+                           [-1.0, 0.0, -np.inf, ent[0] / 2,
+                            ent[-1] * 2, 1e300]])
+    v = np.concatenate([edge, rng.choice(edge, n - edge.size - n // 4),
+                        rng.uniform(-1e-5, ent[-1] * 1.1, n // 4)])
+    assert v.size == n
+    assert np.unique(ent).size < finite  # repeated entries
+    got = _jit(lambda a, q: S._searchsorted_right(a, q, jnp), table, v)
+    want = S._searchsorted_right(table, v, np)
+    np.testing.assert_array_equal(got, want)
+    assert want.min() == 0 and want.max() == finite
+
+
+def test_dispatch_lowers_without_a_while_loop():
+    """The knob lookups of ``dispatch`` stay loop-free: a binary search
+    would bring back a ``while`` of per-level gathers."""
+    from repro.fleet.worker import FleetWorkerPool
+    from repro.fleet.workloads import har_workload, harris_workload, \
+        lm_workload
+    wls = [har_workload(), harris_workload(), lm_workload()]
+    n = 256
+    pool = FleetWorkerPool(np.full((1, 100), 1e-3), 0.01,
+                           workloads=[w.costs for w in wls],
+                           mode="dispatch", n_workers=n)
+    sp = S.make_sched_params(pool.params, wls, max_batch=B_MAX)
+    ss = tuple(np.asarray(getattr(S.make_sched_state(sp), f))
+               for f in S.SS._fields)
+    rng = np.random.default_rng(6)
+    budget = rng.uniform(0, 1e-2, n)
+    with jax.enable_x64(True):
+        text = jax.jit(lambda s, d, bn, bp: S.dispatch(
+            sp, S.SS(*s), d, bn, bp, 1.0, jnp)).lower(
+            ss, np.ones(n, bool), budget, budget).as_text()
+    assert "stablehlo.while" not in text
+    assert "stablehlo.sort" in text  # the rank sort, so it is dispatch
 
 
 def test_slot_gather_scatter_match_numpy():
