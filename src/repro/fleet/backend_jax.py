@@ -394,12 +394,13 @@ class JaxFleetBackend:
                     ss = S.shed(sp, ss, t, jnp)
                 with jax.named_scope("fleet.plan"):
                     if quant:
-                        # quanta -> joules: the exact float64 expression
-                        # the NumPy host path evaluates (agreement)
-                        budget_now = (capacitor_usable_q(
-                            fsn.v, view._qp.E_OFF, jnp)
-                            .astype(jnp.float64) * p.quantum_j)
+                        # dispatch decides on the usable quanta; the
+                        # joules (the exact float64 expression of the
+                        # NumPy host path) feed forecast planning
+                        us = capacitor_usable_q(fsn.v, view._qp.E_OFF, jnp)
+                        budget_now = us.astype(jnp.float64) * p.quantum_j
                     else:
+                        us = None
                         budget_now = view._usable(fsn.v)
                     pw_lags = S.power_lags(view.power, view.trace_index,
                                            i, p.T, sp.fc_order,
@@ -414,7 +415,7 @@ class JaxFleetBackend:
                 with jax.named_scope("fleet.dispatch"):
                     dispatchable = fsn.on & ~fsn.has_work & ~fsn.p_pending
                     ss, a = S.dispatch(sp, ss, dispatchable, budget_now,
-                                       budget_plan, t, jnp)
+                                       budget_plan, t, jnp, us=us)
                 with jax.named_scope("fleet.assign"):
                     cast = ((lambda x: x.astype(jnp.int32)) if quant
                             else (lambda x: x))

@@ -33,19 +33,27 @@ EMIT = "emit"
 LOST = "lost"
 
 
+def usable_quanta(p: FleetParams, s: FleetState) -> np.ndarray | None:
+    """Per-worker usable energy quanta above brown-out of a quantized
+    fleet (``p.quantum_j`` set; ``None`` for a float fleet): what its
+    dispatch decides on (``sched.dispatch``'s ``us``)."""
+    if p.quantum_j is None:
+        return None
+    from repro.core.energy import capacitor_usable_q
+    from repro.fleet.qtick import quantize_fleet_cached
+    return capacitor_usable_q(s.v, quantize_fleet_cached(p).E_OFF, np)
+
+
 def usable_energy(p: FleetParams, s: FleetState) -> np.ndarray:
     """Per-worker usable joules — the budget the host scheduler reads.
 
     Quantized states (``p.quantum_j`` set) hold energy quanta in ``v``;
     the quanta -> joules conversion here is the exact float64 expression
-    the fused jax serve build uses, so dispatch decisions agree
+    the fused jax serve build uses, so forecast planning agrees
     bit-for-bit across backends in both precisions."""
-    if p.quantum_j is not None:
-        from repro.fleet.qtick import quantize_fleet_cached
-        qp = quantize_fleet_cached(p)
-        from repro.core.energy import capacitor_usable_q
-        return (capacitor_usable_q(s.v, qp.E_OFF, np)
-                .astype(np.float64) * p.quantum_j)
+    us = usable_quanta(p, s)
+    if us is not None:
+        return us.astype(np.float64) * p.quantum_j
     return capacitor_usable_energy(s.v, capacitance_f=p.C, v_off=p.v_off)
 
 

@@ -55,7 +55,7 @@ Mechanisms (array formulation of the PR-1 semantics):
   without a second drop mechanism. Reactive and forecast modes are
   untouched (the rank key is the only difference, guarded by
   ``value_order``).
-- **Quality ledger** — on every completion, ``collect`` gathers the
+- **Quality ledger** — on every completion, ``collect`` reads the
   request's *measured* quality from the precomputed
   ``(workload, sample, units)`` oracle table (``repro.quality.oracles``)
   and its table-priced spend in integer nanojoules, accumulating both
@@ -69,7 +69,10 @@ knob units, shed/evict counts) is integer arithmetic or elementwise IEEE
 float ops evaluated identically by numpy and jax.numpy under
 ``enable_x64``, with stable sorts on both sides — the NumPy and fused-JAX
 control planes agree exactly on emitted/skipped/power-cycle/completion
-counts (pinned by tests/test_fleet_backends.py). Float *metric*
+counts (pinned by tests/test_fleet_backends.py). A quantized fleet's
+dispatch decisions are integer counts of thresholds on its usable
+quanta (:func:`quanta_thresholds`), equal to the IEEE float64 ones on a
+device whose float64 is not IEEE too. Float *metric*
 accumulators (latency sums, accuracy sums) may differ by reduction order
 ulps and are compared with tolerances.
 """
@@ -249,6 +252,13 @@ def make_sched_params(p: FleetParams, workloads: Sequence, *,
         QVALUE[w] = ((ACC[w, u_eff] - ACC[w, 0])
                      / max(CU[w, u_eff], 1e-300))
         QTARGET[w] = int(np.argmax(wk.accuracy))  # first knob at the max
+    thr = {}
+    if p.quantum_j is not None:
+        from repro.obs.profile import span
+        with span("fleet.sched.thresholds",
+                  entries=W * (u_max + 2) * (1 + 2 * int(max_batch))):
+            thr = quanta_thresholds(CU, UCUM, FIX + EMITC, int(max_batch),
+                                    p.quantum_j)
     L = max(int(round(lookahead_s / p.dt)), 1)
     if sched == "forecast" and forecaster_fit == "causal":
         # honest start: nothing observed yet, forecast nothing. The
@@ -294,7 +304,75 @@ def make_sched_params(p: FleetParams, workloads: Sequence, *,
         forecaster_fit=str(forecaster_fit),
         persist=str(persist),
         fram_write_j_per_byte=float(fram_write_j_per_byte),
-        fram_read_j_per_byte=float(fram_read_j_per_byte))
+        fram_read_j_per_byte=float(fram_read_j_per_byte), **thr)
+
+
+_I32_MAX = np.iinfo(np.int32).max
+
+
+def _first_true(pred, shape) -> np.ndarray:
+    """Elementwise over a table of ``shape``: the smallest ``us`` in
+    ``[0, INT32_MAX)`` at which the monotone (false, then true) ``pred``
+    is true, or ``INT32_MAX`` where it never is. Integer bisection, 31
+    rounds of ``pred`` on the whole table at once."""
+    lo = np.zeros(shape, np.int64)
+    hi = np.full(shape, _I32_MAX, np.int64)
+    while np.any(lo < hi):
+        mid = (lo + hi) // 2
+        t = pred(mid)
+        hi, lo = (np.where((lo < hi) & t, mid, hi),
+                  np.where((lo < hi) & ~t, mid + 1, lo))
+    return lo.astype(np.int32)
+
+
+def quanta_thresholds(CU, UCUM, overhead, B: int, quantum_j: float) -> dict:
+    """Dispatch's float64 decisions as int32 thresholds on the usable
+    quanta ``us`` of a quantized fleet.
+
+    Each decision ``dispatch`` takes from the budget ``fl(us * q)`` is a
+    comparison of IEEE operations that are each monotone in ``us``
+    (multiply, subtract, divide, ``floor_divide``), so it turns true at
+    one smallest ``us``, found here by bisection in NumPy float64, and
+    a lookup becomes a count of thresholds at or below ``us``. Budgets
+    are whole quanta and costs whole nanojoules, so exact ties are
+    common; an integer count decides them as IEEE float64 does on any
+    device. Padded ``+inf`` costs are never affordable (``INT32_MAX``).
+
+    Args:
+        CU / UCUM: (W, K) cumulative cost tables, J (``SchedParams``).
+        overhead: (W,) fixed plus emission cost, J.
+        B: the batch cap.
+        quantum_j: joules per quantum.
+    Returns:
+        ``THR_AFF`` (W, K): ``fl(us*q) >= CU[w, k]`` (the affordable
+        knob); ``THR_B`` (W, K, B): the batch of ``b + 1`` requests
+        priced at knob ``j`` fits, ``floor_divide(fl(us*q) - overhead,
+        UCUM[w, j]) >= b + 1``; ``THR_U`` (W, B, K): knob ``k`` fits one
+        request of a batch of ``a + 1``, ``(fl(us*q) - overhead) / (a + 1)
+        >= UCUM[w, k]``. Each row is non-decreasing along its last axis,
+        and ``THR_B`` also along ``j``.
+    """
+    ovh = np.asarray(overhead, np.float64)[:, None]
+    bsz = np.arange(1, B + 1)
+
+    def spend(us):
+        return us.astype(np.float64) * quantum_j - ovh[..., None]
+
+    def fits(us):  # us: (W, K, B)
+        cpb = UCUM[:, :, None]
+        with np.errstate(invalid="ignore"):
+            b = np.where(cpb > 0, np.floor_divide(
+                spend(us), np.maximum(cpb, 1e-300)), B)
+        return b >= bsz
+
+    W, K = CU.shape
+    return dict(
+        THR_AFF=_first_true(
+            lambda us: CU <= us.astype(np.float64) * quantum_j, (W, K)),
+        THR_B=_first_true(fits, (W, K, B)),
+        THR_U=_first_true(
+            lambda us: UCUM[:, None, :] <= spend(us) / bsz[:, None],
+            (W, B, K)))
 
 
 def make_sched_state(sp: SchedParams) -> SchedState:
@@ -343,12 +421,17 @@ def _argsort(key, valid, xp):
     float64 key: XLA:TPU emulates float64, and a sort on an emulated
     float64 comparator takes minutes to compile at fleet sizes (as does a
     sort with several key operands); one int64 key compiles in well under
-    a minute. ``key`` must be finite where ``valid``."""
+    a minute. An integer key (negated usable quanta) sorts as it is.
+    ``key`` must be finite where ``valid``, and an integer key below its
+    dtype's maximum."""
     if xp is np:
         return np.argsort(np.where(valid, key, np.inf), kind="stable")
     from jax import lax
-    k = xp.where(valid, _order_key(xp.where(valid, key, 0.0), xp),
-                 np.iinfo(np.int64).max)
+    if np.issubdtype(key.dtype, np.integer):
+        k = xp.where(valid, key, np.iinfo(key.dtype).max)
+    else:
+        k = xp.where(valid, _order_key(xp.where(valid, key, 0.0), xp),
+                     np.iinfo(np.int64).max)
     idx = lax.iota(xp.int32, k.shape[0])
     return lax.sort((k, idx), num_keys=1, is_stable=True)[1]
 
@@ -435,18 +518,41 @@ def _take(a, idx, xp):
     return xp.stack([xp.take(a, c) for c in _cols(idx)], axis=1)
 
 
+def _rows(a, idx, xp):
+    """``a[idx]`` for an (N,) or (N, B) ``a`` and an (N,) ``idx``: a row
+    gather, one slot column at a time under jax."""
+    if xp is np:
+        return a[idx]
+    if a.ndim == 2:
+        return xp.stack([xp.take(c, idx) for c in _cols(a)], axis=1)
+    return xp.take(a, idx)
+
+
+def _inverse_permutation(perm, xp):
+    """``inv`` with ``inv[perm[r]] == r``, for a permutation ``perm`` of
+    ``0..N-1``. Under jax a sort of ``perm`` carrying the ranks: on
+    XLA:TPU a scatter of N = 131,072 indices takes about 10 ms, more as
+    the permutation scrambles, where the sort takes about 2 ms and each
+    array it lets :func:`_rows` gather about 2 ms."""
+    if xp is np:
+        inv = np.empty_like(perm)
+        inv[perm] = np.arange(perm.shape[0])
+        return inv
+    from jax import lax
+    n = perm.shape[0]
+    return lax.sort((perm.astype(xp.int32), lax.iota(xp.int32, n)),
+                    num_keys=1)[1]
+
+
 def _scatter_set(a, idx, v, xp):
-    """``a[idx] = v`` on a copy. ``idx`` is (N,) or (N, B) with ``v`` of
-    its shape; a 2-D ``a`` with (N,) ``idx`` scatters whole rows. Where
-    indices repeat, the NumPy order decides (the last write wins), and the
-    callers send only discarded writes to a repeated index."""
+    """``a[idx] = v`` on a copy, for a 1-D ``a`` and an (N,) or (N, B)
+    ``idx`` with ``v`` of its shape. Where indices repeat, the NumPy order
+    decides (the last write wins), and the callers send only discarded
+    writes to a repeated index."""
     if xp is np:
         out = a.copy()
         out[idx] = v
         return out
-    if a.ndim == 2:  # row scatter, one slot column at a time
-        return xp.stack([c.at[idx].set(vc)
-                         for c, vc in zip(_cols(a), _cols(v))], axis=1)
     if idx.ndim == 2:
         for c, vc in zip(_cols(idx), _cols(v)):
             a = a.at[c].set(vc)
@@ -454,16 +560,45 @@ def _scatter_set(a, idx, v, xp):
     return a.at[idx].set(v)
 
 
-def _scatter_add(a, idx, v, xp):
-    """``np.add.at(a, idx, v)`` on a copy, for a scalar ``v`` and an (N,)
-    or (N, B) ``idx``."""
+def _bincount(idx, n: int, xp):
+    """How many entries of the int array ``idx`` hold each value
+    ``0..n-1`` (other values are not counted), as (n,) int64. Under jax a
+    compare with each value and a reduce, no scatter: at N = 131,072
+    XLA:TPU takes about 10 ms to scatter N indices and 1-2 ms to gather
+    them, where a compare and reduce over a few hundred values takes
+    microseconds."""
     if xp is np:
-        out = a.copy()
-        np.add.at(out, idx, v)
-        return out
-    for c in (_cols(idx) if idx.ndim == 2 else [idx]):
-        a = a.at[c].add(v)
-    return a
+        v = idx.ravel()
+        return np.bincount(v[(v >= 0) & (v < n)], minlength=n)
+    hit = idx[..., None] == xp.arange(n)
+    return xp.sum(hit.astype(xp.int32),
+                  axis=tuple(range(idx.ndim))).astype(xp.int64)
+
+
+def _lookup(table, idx, xp):
+    """``table[idx]`` for a short 1-D host ``table`` and an index array
+    in its range. Under jax each index is compared with every entry's and
+    the one match kept by a max reduce, no gather (see
+    :func:`_bincount`)."""
+    table = np.asarray(table)
+    if xp is np:
+        return table[idx]
+    low = (-np.inf if table.dtype.kind == "f"
+           else np.iinfo(table.dtype).min)
+    hit = idx[..., None] == xp.arange(table.shape[0])
+    return xp.max(xp.where(hit, table, low), axis=-1)
+
+
+def _sample_words(q) -> np.ndarray:
+    """A (samples, K) 0/1 table packed along samples: (K, ceil(S / 32))
+    int32 words, bit ``s % 32`` of word ``s // 32`` holding ``q[s]``."""
+    q = np.asarray(q, np.int64)
+    n_words = max(-(-q.shape[0] // 32), 1)
+    bits = np.zeros((n_words * 32, q.shape[1]), np.int64)
+    bits[:q.shape[0]] = q
+    words = (bits.reshape(n_words, 32, -1)
+             << np.arange(32)[None, :, None]).sum(axis=1)
+    return words.astype(np.uint32).view(np.int32).T
 
 
 def _cumsum_slots(m, xp):
@@ -592,7 +727,7 @@ def plan_budget(sp: SchedParams, budget_now, pw_lags, eff, xp=np):
 
 
 def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
-             t, xp=np):
+             t, xp=np, us=None):
     """Route queued requests to capable workers.
 
     Args:
@@ -600,6 +735,9 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
         budget_now: (N,) instantaneous usable energy, J.
         budget_plan: (N,) planning budget from :func:`plan_budget`, J.
         t: assignment time, seconds.
+        us: (N,) int usable quanta above brown-out, of which
+            ``budget_now`` is ``fl(us * quantum_j)``; required for a
+            quantized fleet (``sp.THR_AFF`` set), unused otherwise.
     Returns:
         ``(ss, a)`` — the updated state and an :class:`Assignment` of
         per-worker arrays (mask, workload id, per-request knob units,
@@ -612,14 +750,30 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
     the workload floor on the *instantaneous* budget (never start work
     whose fixed cost is unfunded today), batch size and greedy knob
     refinement on the *planning* budget (forecast inflow funds in-flight
-    units). Queue consumption is a cumulative-sum slice per workload."""
+    units). Queue consumption is a cumulative-sum slice per workload.
+
+    A quantized fleet takes every decision that reads the instantaneous
+    budget (the rank, the affordable knob, the batch size, the knob per
+    request) as a count of :func:`quanta_thresholds` at or below ``us``:
+    the IEEE float64 decisions, in integers on any device. Forecast mode
+    still ranks and sizes batches on its float planning budget, which is
+    no whole number of quanta."""
     from repro.obs.profile import scope
     i64 = xp.int64
+    quant = sp.THR_AFF is not None
+    if quant and us is None:
+        raise ValueError("a quantized fleet dispatches on its usable "
+                         "quanta: pass us")
     with scope("fleet.dispatch.rank", xp):
-        # rank -> worker id: dispatchable workers richest first
-        order = _argsort(-budget_plan, dispatchable, xp)
+        # rank -> worker id: dispatchable workers richest first (fl(us * q)
+        # rises strictly with us, so us ranks as the reactive budget does)
+        key = us if quant and not sp.forecast else budget_plan
+        order = _argsort(-key, dispatchable, xp)
         elig = xp.take(dispatchable, order)
-        bn = xp.take(budget_now, order)
+        if quant:
+            us_r = xp.take(us, order)
+        else:
+            bn = xp.take(budget_now, order)
         bp = xp.take(budget_plan, order)
     with scope("fleet.dispatch.queues", xp):
         if sp.value_order:
@@ -640,7 +794,6 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
         jB = xp.arange(sp.B)[None, :]
         for k in range(sp.W):  # static: one pass per workload queue
             wl = wl_order[k]
-            cu = xp.take(xp.asarray(sp.CU), wl, axis=0)
             ucum = xp.take(xp.asarray(sp.UCUM), wl, axis=0)
             nu = xp.take(xp.asarray(sp.NU), wl)
             overhead = (xp.take(xp.asarray(sp.FIX), wl)
@@ -649,7 +802,26 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
             head = xp.take(q_head, wl)
             # admission: largest knob the instantaneous budget affords (-1:
             # even fixed+emit does not fit), SMART floor for floored workloads
-            k_aff = _searchsorted_right(cu, bn, xp).astype(i64) - 1
+            if quant:
+                with scope("fleet.dispatch.afford", xp):
+                    def count(thr):
+                        return _searchsorted_right(thr, us_r,
+                                                   xp).astype(i64)
+                    k_aff = count(xp.take(xp.asarray(sp.THR_AFF), wl,
+                                          axis=0)) - 1
+                    # b_fit[b]: the knobs below it price b + 1 requests
+                    # within budget (THR_B rises along the knob axis)
+                    thr_b = xp.take(xp.asarray(sp.THR_B), wl, axis=0)
+                    b_fit = ([] if sp.forecast else
+                             [count(thr_b[:, b]) for b in range(sp.B)])
+                    # k_fit[a]: the largest knob per request of a + 1
+                    thr_u = xp.take(xp.asarray(sp.THR_U), wl, axis=0)
+                    k_fit = [count(thr_u[a]) - 1 for a in range(sp.B)]
+            else:
+                k_aff = _searchsorted_right(
+                    xp.take(xp.asarray(sp.CU), wl, axis=0), bn,
+                    xp).astype(i64) - 1
+                b_fit = []
             if sp.persist != "none":
                 # exact disciplines (docs/persistence_plane.md): the knob is
                 # pinned at NU — every unit runs — and admission only needs
@@ -671,38 +843,48 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
             # each affording the knob where the oracle says accuracy peaks —
             # under scarcity the target degrades back to the floor (b_want
             # clips to >= 1 and refinement still bounds at p_req).
-            spend_plan = bp - overhead
-            spend_now = bn - overhead
-            cpr = xp.take(ucum, xp.clip(p_req, 0, ucum.shape[0] - 1))
             if sp.value_order and sp.persist == "none":
                 # quality mode also CAPS refinement at the target knob:
                 # measured tables are non-monotonic, so units past the peak
                 # cost strictly more joules for no more (often less)
                 # measured accuracy
                 u_cap = xp.maximum(xp.take(xp.asarray(sp.QTARGET), wl), p_req)
-                cpq = xp.take(ucum, xp.clip(u_cap, 0, ucum.shape[0] - 1))
-                cpb = xp.maximum(cpq, cpr)  # never below the admission knob
+                j_b = u_cap  # never below the admission knob
             else:
                 u_cap = nu
-                cpb = cpr
-            b_want = xp.where(
-                cpb > 0,
-                xp.floor_divide(spend_plan, xp.maximum(cpb, 1e-300)), sp.B)
+                j_b = p_req
+            j_b = xp.clip(j_b, 0, ucum.shape[0] - 1)  # the knob pricing b
+            if b_fit:
+                b_want = xp.zeros(sp.n, dtype=i64)
+                for f in b_fit:
+                    b_want = b_want + (j_b < f)
+            else:
+                cpb = xp.take(ucum, j_b)
+                b_want = xp.where(
+                    cpb > 0, xp.floor_divide(bp - overhead,
+                                             xp.maximum(cpb, 1e-300)), sp.B)
             b_want = xp.clip(b_want, 1, sp.B).astype(i64)
-            u_want = xp.clip(
-                _searchsorted_right(ucum, spend_now / xp.maximum(b_want, 1),
-                                    xp).astype(i64) - 1,
-                p_req, u_cap)
+
+            def knob(batch):
+                """The largest knob one request of a ``batch`` (1..B)
+                affords on the instantaneous budget, within
+                ``[p_req, u_cap]``."""
+                if quant:
+                    kb = k_fit[0]
+                    for a in range(1, sp.B):
+                        kb = xp.where(batch == a + 1, k_fit[a], kb)
+                else:
+                    kb = _searchsorted_right(ucum, (bn - overhead) / batch,
+                                             xp).astype(i64) - 1
+                return xp.clip(kb, p_req, u_cap)
+            u_want = knob(b_want)
             ok = elig & ~taken & afford & (u_want > 0)
             b = xp.where(ok, b_want, 0)
             c = _cumsum(b, xp)  # b <= B per worker
             start = c - b
             actual = xp.clip(qrem - start, 0, b)
             got = ok & (actual > 0)
-            u = xp.clip(
-                _searchsorted_right(ucum, spend_now / xp.maximum(actual, 1),
-                                    xp).astype(i64) - 1,
-                p_req, u_cap)
+            u = knob(xp.maximum(actual, 1))
             # consume the queue front: gather each worker's request slice
             phys = (head + start[:, None] + jB) % sp.Q
             row_t = xp.take(ss.q_t, wl, axis=0)
@@ -719,15 +901,15 @@ def dispatch(sp: SchedParams, ss, dispatchable, budget_now, budget_plan,
             a_units = xp.where(got, u, a_units)
             a_batch = xp.where(got, actual, a_batch)
     with scope("fleet.dispatch.scatter", xp):
-        # rank space -> worker space (order is a permutation)
-        z = lambda dt=i64: xp.zeros(sp.n, dtype=dt)  # noqa: E731
-        batch_w = _scatter_set(z(), order, a_batch, xp)
+        # rank space -> worker space: order is a permutation, so each
+        # worker's entry sits at its rank
+        rank = _inverse_permutation(order, xp)
+        batch_w = _rows(a_batch, rank, xp)
         mask_w = batch_w > 0
-        wl_w = _scatter_set(z(), order, a_wl, xp)
-        units_w = _scatter_set(z(), order, a_units, xp)
-        arr_w = _scatter_set(xp.zeros((sp.n, sp.B)), order, g_arr, xp)
-        retry_w = _scatter_set(xp.zeros((sp.n, sp.B), dtype=i64), order,
-                               g_retry, xp)
+        wl_w = _rows(a_wl, rank, xp)
+        units_w = _rows(a_units, rank, xp)
+        arr_w = _rows(g_arr, rank, xp)
+        retry_w = _rows(g_retry, rank, xp)
         ss = ss._replace(
             q_head=q_head, q_len=q_len,
             f_n=xp.where(mask_w, batch_w, ss.f_n),
@@ -840,29 +1022,30 @@ def _collect_impl(sp: SchedParams, ss, emit, lost, units_done, t, xp):
     units_slot = xp.where(compfull, u[:, None],
                           xp.where(comppart, part[:, None], 0))
     lat = t - ss.f_arr
-    # fixed-bin latency histogram: integer scatter-adds agree exactly
-    # across backends; percentiles come from the bins (metrics.py)
+    # fixed-bin latency histogram: integer counts agree exactly across
+    # backends; percentiles come from the bins (metrics.py)
     binw = sp.lat_max_s / sp.lat_bins
     idx = xp.clip((lat / binw).astype(xp.int64), 0, sp.lat_bins - 1)
-    idx = xp.where(comp, idx, sp.lat_bins)  # non-completions -> dump bin
-    hist_ext = _scatter_add(xp.zeros(sp.lat_bins + 1, dtype=xp.int64),
-                            idx, 1, xp)
+    hist = _bincount(xp.where(comp, idx, sp.lat_bins), sp.lat_bins, xp)
     # per-workload aggregates, one static pass per workload over the
-    # (N, B) slots (no (N, B, W) one-hot tensors)
-    wl = ss.f_wl[:, None]
-    Uw = sp.ACC.shape[1]
-    accv = _take(xp.asarray(sp.ACC).reshape(-1),
-                 wl * Uw + xp.clip(units_slot, 0, Uw - 1), xp)
+    # (N, B) slots (no (N, B, W) one-hot tensors). A worker's completions
+    # are its first slots: full ones at its knob u, then at most one
+    # partial, so each table is read at two knobs per worker.
+    K = sp.ACC.shape[1]
+    uq, pq = xp.clip(u, 0, K - 1), xp.clip(part, 0, K - 1)
+
+    def at(row):
+        """``row[knob]`` of each slot's completion, (N, B)."""
+        return xp.where(comppart, _lookup(row, pq, xp)[:, None],
+                        _lookup(row, uq, xp)[:, None])
     # quality ledger: each completion is scored against a deterministic
     # oracle sample — per workload, this tick's completions are numbered
     # in flat (worker, slot) order continuing the run-long completed_wl
     # counter, cycling mod the oracle set size — then measured
-    # correctness (0/1) and the table-priced spend (integer nanojoules)
-    # are gathered from the precomputed (workload, sample, units)
-    # tables. Integer arithmetic only: both backends ledger bit-exactly.
-    Smax, Uq = sp.QTAB.shape[1], sp.QTAB.shape[2]
-    uq = xp.clip(units_slot, 0, Uq - 1)
-    jnj = _take(xp.asarray(sp.QJ_NJ).reshape(-1), wl * Uq + uq, xp)
+    # correctness (0/1, a bit of the packed (sample, units) table) and
+    # the table-priced spend (integer nanojoules) are read at the slot's
+    # knob. Integer arithmetic only: both backends ledger bit-exactly.
+    wl = ss.f_wl[:, None]
     agg = {k: [] for k in ("n", "units", "acc", "meas", "nj")}
     for w in range(sp.W):  # static: one pass per workload
         m = comp & (wl == w)
@@ -872,13 +1055,16 @@ def _collect_impl(sp: SchedParams, ss, emit, lost, units_done, t, xp):
         s_q = sp.S_Q[w].astype(i32)
         sample = ((ss.completed_wl[w] % sp.S_Q[w]).astype(i32)
                   + (_cumsum_slots(mi, xp) - mi).astype(i32)) % s_q
-        qv = _take(xp.asarray(sp.QTAB).reshape(-1),
-                   (w * Smax + sample) * Uq + uq, xp)
+        words = _sample_words(sp.QTAB[w, :sp.S_Q[w]])
+        word = at(words[:, 0])
+        for c in range(1, words.shape[1]):
+            word = xp.where(sample // 32 == c, at(words[:, c]), word)
+        qv = (word >> (sample % 32)) & 1
         agg["n"].append(xp.sum(mi))
         agg["units"].append(xp.sum(xp.where(m, units_slot, 0)))
-        agg["acc"].append(xp.sum(xp.where(m, accv, 0.0)))
-        agg["meas"].append(xp.sum(xp.where(m, qv, 0)))
-        agg["nj"].append(xp.sum(xp.where(m, jnj, 0)))
+        agg["acc"].append(xp.sum(xp.where(m, at(sp.ACC[w]), 0.0)))
+        agg["meas"].append(xp.sum(xp.where(m, qv, 0).astype(xp.int64)))
+        agg["nj"].append(xp.sum(xp.where(m, at(sp.QJ_NJ[w]), 0)))
     agg = {k: xp.stack(v) for k, v in agg.items()}
     # the latency sum counts whole ticks (arrivals and completions are
     # stamped on the tick grid): an integer is the same in every
@@ -892,7 +1078,7 @@ def _collect_impl(sp: SchedParams, ss, emit, lost, units_done, t, xp):
         meas_wl=ss.meas_wl + agg["meas"],
         joules_nj_wl=ss.joules_nj_wl + agg["nj"],
         lat_sum=ss.lat_sum + xp.sum(xp.where(comp, lat_ticks, 0)),
-        lat_hist=ss.lat_hist + hist_ext[:sp.lat_bins])
+        lat_hist=ss.lat_hist + hist)
     ss = _requeue(sp, ss, unfinished, xp)
     return ss._replace(f_n=xp.where(em | lo, 0, ss.f_n))
 

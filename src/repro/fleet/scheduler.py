@@ -206,7 +206,8 @@ class FleetScheduler:
                                          np)
         dispatchable = s.on & ~s.has_work & ~s.p_pending
         ss, a = _sched.dispatch(sp, ss, dispatchable, budget_now,
-                                budget_plan, float(t), np)
+                                budget_plan, float(t), np,
+                                us=backend_numpy.usable_quanta(p, s))
         s.p_pending = s.p_pending | a.mask
         s.p_wl = np.where(a.mask, a.wl, s.p_wl)
         s.p_units = np.where(a.mask, a.units, s.p_units)
@@ -389,6 +390,7 @@ class _ShardedHostServe:
                                       np)
             if is_tick:
                 budget_now = backend_numpy.usable_energy(p, dev)
+                us = backend_numpy.usable_quanta(p, dev)
                 plans = []
                 for s, sl in enumerate(sls):
                     sss[s] = _sched.shed(sps[s], sss[s], t, np)
@@ -409,7 +411,7 @@ class _ShardedHostServe:
                                     & ~dev.p_pending)[sl]
                     sss[s], a = _sched.dispatch(
                         sps[s], sss[s], dispatchable, budget_now[sl],
-                        plans[s], t, np)
+                        plans[s], t, np, us=None if us is None else us[sl])
                     mask_f[sl] = a.mask
                     wl_f[sl] = a.wl
                     units_f[sl] = a.units

@@ -265,6 +265,14 @@ class SchedParams:
     persist: str = "none"  # "none" | "ckpt" | "undolog"
     fram_write_j_per_byte: float = 18e-9
     fram_read_j_per_byte: float = 7e-9
+    # quantized fleets (FleetParams.quantum_j set): dispatch decides on
+    # the integer usable quanta ``us``, each table entry being the
+    # smallest ``us`` at which its float64 comparison turns true
+    # (sched.quanta_thresholds; INT32_MAX: never). None for float fleets.
+    THR_AFF: np.ndarray | None = None  # (W, U+2) int32 knob k affordable
+    THR_B: np.ndarray | None = None  # (W, U+2, B) int32 batch b+1 at knob j
+    THR_U: np.ndarray | None = None  # (W, B, U+2) int32 knob k per request
+    # of a batch of a+1
 
 
 @dataclasses.dataclass

@@ -181,29 +181,31 @@ def run_scheduled(power: np.ndarray, dt: float, n_workers: int,
                                kernel=kernel,
                                fleet_placement=fleet_placement,
                                persist=persist, interpret=interpret)
-    # the rebalance cadence rounds to ticks; run_serve validates it is a
-    # multiple of the dispatch cadence
-    scheduler = FleetScheduler(pool, workloads, max_batch=max_batch,
-                               grace_s=grace_s,
-                               shed_after_s=shed_after_s, sched=sched,
-                               lookahead_s=lookahead_s,
-                               forecaster=forecaster,
-                               trace_families=trace_families,
-                               forecaster_fit=forecaster_fit,
-                               shards=mesh_fleet,
-                               rebalance_every=int(round(
-                                   rebalance_every_s / dt)),
-                               rebalance_max=rebalance_max)
-    obs = None
-    if obs_mode != "off":
-        from repro.obs import make_fleet_obs
-        obs = make_fleet_obs(obs_mode, pool.params, scheduler.params,
-                             n_steps,
-                             window=max(int(round(obs_window_s / dt)), 1),
-                             ring=obs_ring)
-    stream = RequestStream(rate_rps, mix, n_steps, dt, seed=seed + 1)
+    # the trace covers building the scheduler (its dispatch thresholds)
+    # and the serve
     from repro.obs.profile import profiled
     with profiled(profile_dir):
+        # the rebalance cadence rounds to ticks; run_serve validates it is a
+        # multiple of the dispatch cadence
+        scheduler = FleetScheduler(pool, workloads, max_batch=max_batch,
+                                   grace_s=grace_s,
+                                   shed_after_s=shed_after_s, sched=sched,
+                                   lookahead_s=lookahead_s,
+                                   forecaster=forecaster,
+                                   trace_families=trace_families,
+                                   forecaster_fit=forecaster_fit,
+                                   shards=mesh_fleet,
+                                   rebalance_every=int(round(
+                                       rebalance_every_s / dt)),
+                                   rebalance_max=rebalance_max)
+        obs = None
+        if obs_mode != "off":
+            from repro.obs import make_fleet_obs
+            obs = make_fleet_obs(obs_mode, pool.params, scheduler.params,
+                                 n_steps,
+                                 window=max(int(round(obs_window_s / dt)), 1),
+                                 ring=obs_ring)
+        stream = RequestStream(rate_rps, mix, n_steps, dt, seed=seed + 1)
         if stream_mode:
             # streaming online serve: a live client thread feeds arrival
             # rows into the chunked steady-state loop (chunk boundaries

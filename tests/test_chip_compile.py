@@ -10,6 +10,7 @@ seconds to compile.
 """
 from __future__ import annotations
 
+import numpy as np
 import pytest
 
 import jax
@@ -93,8 +94,10 @@ def test_serve_tick_kernel_compiles_at_131072_workers(compile_for):
 
 
 def test_control_plane_forms_compile_inside_a_scan(compile_for):
-    """The sort, prefix sums, knob lookup, gathers, scatters and whole-tick
-    latency sum of the array control plane (``repro.fleet.sched``) in the
+    """The sorts, prefix sums, knob lookups (float64 costs and int32
+    quanta thresholds), table reads, bin counts, the inverse permutation,
+    gathers, scatters and whole-tick latency sum of the array control
+    plane (``repro.fleet.sched``) in the
     forms the serve scan uses, fused into one scan body: XLA:TPU refused
     the float64 bitcast a sort key would otherwise need, and ran out of
     scoped VMEM on emulated-int64 prefix sums fused inside the serve
@@ -103,26 +106,39 @@ def test_control_plane_forms_compile_inside_a_scan(compile_for):
     n, b = 1024, 4
 
     def body(c, _):
-        key, valid, cnt, slots, ring, lat_sum = c
+        key, valid, cnt, slots, ring, lat_sum, us, thr = c
         order = S._argsort(-key, valid, jnp)
         # a knob table's width; the compile needs only its shape
         csum = S._cumsum(cnt, jnp) + S._searchsorted_right(ring[:142], key,
                                                             jnp)
+        # quantized dispatch: the rank on usable quanta and a count of a
+        # row of int32 thresholds (sched.quanta_thresholds)
+        q_order = S._argsort(-us, valid, jnp)
+        csum = csum + S._searchsorted_right(thr[1], S._take(us, q_order,
+                                                            jnp), jnp)
+        us = (us + csum.astype(jnp.int32)) % 7_000_000
+        # collect's knob-row read and latency-bin count (no gather, no
+        # scatter)
+        csum = csum + S._lookup(np.arange(142) * 3, us % 142, jnp)
+        lat_sum = lat_sum + jnp.sum(S._bincount(slots % 65, 64, jnp))
         rank = S._cumsum_slots(slots, jnp)
         phys = rank % ring.shape[0]
         got = S._take(ring, phys, jnp)
         ring = S._scatter_set(ring, phys, got + 1.0, jnp)
         key = key + S._take(key, order, jnp) * 1e-3
+        slots = slots + S._rows(slots, S._inverse_permutation(order, jnp),
+                                jnp) % 3
         cnt = (cnt + csum) % 5
         ticks = jnp.rint(key / 0.01).astype(jnp.int64)
         lat_sum = lat_sum + jnp.sum(jnp.where(valid, ticks, 0))
-        return (key, valid, cnt, slots, ring, lat_sum), None
+        return (key, valid, cnt, slots, ring, lat_sum, us, thr), None
 
     def fn(*c):
         return lax.scan(body, c, None, length=4)[0]
 
     compiled = compile_for(fn, ((n,), jnp.float64), ((n,), jnp.bool_),
                            ((n,), jnp.int64), ((n, b), jnp.int64),
-                           ((4096,), jnp.float64), ((), jnp.int64))
+                           ((4096,), jnp.float64), ((), jnp.int64),
+                           ((n,), jnp.int32), ((b, 142), jnp.int32))
     assert compiled.memory_analysis() is not None
 

@@ -39,7 +39,7 @@ CHUNK_CHILDREN = ("fleet.stream.take", "fleet.stream.snapshot",
                   "fleet.stream.record")
 SCOPES = ("fleet.admit", "fleet.shed", "fleet.plan", "fleet.dispatch",
           "fleet.dispatch.rank", "fleet.dispatch.queues",
-          "fleet.dispatch.scatter", "fleet.assign", "fleet.tick",
+          "fleet.dispatch.afford", "fleet.dispatch.scatter", "fleet.assign", "fleet.tick",
           "fleet.collect", "fleet.evict")
 
 Ev = collections.namedtuple("Ev", "name start end stats")
@@ -195,7 +195,12 @@ def test_launcher_profile_dir_records_the_serve(tmp_path):
     main(["--workers", "16", "--duration", "2", "--backend", "jax",
           "--kernel", "q32", "--scheduler", "on", "--stream",
           "--chunk-ticks", "100", "--profile-dir", str(tmp_path)])
-    names = collections.Counter(s.name for s in _host_spans(str(tmp_path)))
+    spans = _host_spans(str(tmp_path))
+    names = collections.Counter(s.name for s in spans)
+    # the scheduler's dispatch thresholds: (1 + 2 B) (W, K) tables, for
+    # the three workloads' 142-wide knob tables at the default batch of 4
+    thr, = [s for s in spans if s.name == "fleet.sched.thresholds"]
+    assert thr.stats["entries"] == 9 * 3 * 142
     assert names["fleet.stream.chunk"] == 2
     assert names["fleet.serve.compile"] == 1
     assert names["fleet.serve.call"] == 1

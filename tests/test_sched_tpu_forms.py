@@ -137,14 +137,39 @@ def test_slot_gather_scatter_match_numpy():
     hist = rng.integers(0, 33, (n, b))
     got = _jit(lambda r, i, p, vv, o, h: (
         S._take(r, i, jnp), S._scatter_set(r, p, vv, jnp)[:-1],
-        S._scatter_set(jnp.zeros((n, b)), o, vv, jnp),
-        S._scatter_add(jnp.zeros(34, jnp.int64), h, 1, jnp)),
+        S._rows(vv, S._inverse_permutation(o, jnp), jnp),
+        S._bincount(h, 32, jnp)),
         ring, idx, phys, v, order, hist)
+    rank = S._inverse_permutation(order, np)
     want = (S._take(ring, idx, np), S._scatter_set(ring, phys, v, np)[:-1],
-            S._scatter_set(np.zeros((n, b)), order, v, np),
-            S._scatter_add(np.zeros(34, np.int64), hist, 1, np))
+            S._rows(v, rank, np), S._bincount(hist, 32, np))
+    np.testing.assert_array_equal(rank, np.argsort(order))
+    moved = np.zeros((n, b))
+    moved[order] = v  # rank order back to worker order
+    np.testing.assert_array_equal(want[2], moved)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.int64, np.int32])
+def test_table_lookup_and_packed_samples_match_numpy(dtype):
+    """Collect's per-worker reads of a knob row (a select chain under jax,
+    no gather) and of the packed (sample, units) quality table."""
+    rng = np.random.default_rng(7)
+    k, n = 142, 131_072
+    table = (rng.uniform(0, 1, k) if dtype is np.float64
+             else rng.integers(-2**40 if dtype is np.int64 else -2**30,
+                               2**30, k)).astype(dtype)
+    idx = rng.integers(0, k, n)
+    got = _jit(lambda i: S._lookup(table, i, jnp), idx)
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(got, S._lookup(table, idx, np))
+    np.testing.assert_array_equal(got, table[idx])
+    q = rng.integers(0, 2, (144, k))  # HAR's oracle samples, 4.5 words
+    words = S._sample_words(q)
+    s_ = rng.integers(0, q.shape[0], n)
+    bit = (words[idx, s_ // 32] >> (s_ % 32)) & 1
+    np.testing.assert_array_equal(bit, q[s_, idx])
 
 
 def test_collect_ledger_int32_forms_at_large_counters():
