@@ -10,6 +10,8 @@ seconds to compile.
 """
 from __future__ import annotations
 
+import re
+
 import numpy as np
 import pytest
 
@@ -142,3 +144,35 @@ def test_control_plane_forms_compile_inside_a_scan(compile_for):
                            ((n,), jnp.int32), ((b, 142), jnp.int32))
     assert compiled.memory_analysis() is not None
 
+
+def test_shed_compiles_without_a_gather_at_131072_workers(compile_for):
+    """Shed over the 131,072-worker queue rings (3 queues of 4,096 +
+    131,072 * 4 slots of float64 arrival times) reads them in physical
+    order: the optimized program holds no gather, which XLA:TPU would
+    run as two 32-bit gathers of 1.59 M indices per dispatch tick."""
+    import dataclasses
+    from repro.fleet import sched as S
+    from repro.fleet.worker import FleetWorkerPool
+    from repro.fleet.workloads import har_workload, harris_workload, \
+        lm_workload
+    wls = [har_workload(), harris_workload(), lm_workload()]
+    pool = FleetWorkerPool(np.full((1, 100), 1e-3), 0.01,
+                           workloads=[w.costs for w in wls],
+                           mode="dispatch", n_workers=8)
+    sp = S.make_sched_params(pool.params, wls, max_batch=4)
+    ss = S.make_sched_state(sp)
+    ss = S.SS(*(getattr(ss, f) for f in S.SS._fields))
+    q = 4096 + 131_072 * 4
+    sp = dataclasses.replace(sp, Q=q)
+
+    def fn(q_t, q_head, q_len, t):
+        out = S.shed(sp, ss._replace(q_t=q_t, q_head=q_head, q_len=q_len),
+                     t, jnp)
+        return out.q_head, out.q_len, out.shed
+
+    compiled = compile_for(fn, ((sp.W, q), jnp.float64),
+                           ((sp.W,), jnp.int64), ((sp.W,), jnp.int64),
+                           ((), jnp.float64))
+    text = compiled.as_text()
+    assert re.search(r"\breduce\(", text)  # the row min, so it is shed
+    assert not re.search(r"\bgather\(", text)

@@ -4,11 +4,15 @@ they claim.
 Under jax, ``repro.fleet.sched`` sums bounded counts in int32, divides
 one batch's units in int32, sorts on an int64 image of the float64 rank
 key, looks knobs up by counting table entries instead of a binary
-search, and gathers/scatters (N, B) slot arrays one column at a time (see
-the helpers' docstrings for why XLA:TPU needs each). Every form must give
-the NumPy twin's integers exactly.
+search, gathers/scatters (N, B) slot arrays one column at a time, and
+sheds by reading the queue rings in physical order (see the docstrings
+for why XLA:TPU needs each). Every form must give the NumPy twin's
+integers exactly.
 """
 from __future__ import annotations
+
+import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -25,6 +29,19 @@ B_MAX = 4  # --max-batch default
 def _jit(fn, *args):
     with jax.enable_x64(True):
         return jax.tree.map(np.asarray, jax.jit(fn)(*args))
+
+
+def _sched_params(n):
+    """The control plane's parameters for ``n`` workers running the three
+    paper workloads on a constant 1 mW harvest."""
+    from repro.fleet.worker import FleetWorkerPool
+    from repro.fleet.workloads import har_workload, harris_workload, \
+        lm_workload
+    wls = [har_workload(), harris_workload(), lm_workload()]
+    pool = FleetWorkerPool(np.full((1, 100), 1e-3), 0.01,
+                           workloads=[w.costs for w in wls],
+                           mode="dispatch", n_workers=n)
+    return S.make_sched_params(pool.params, wls, max_batch=B_MAX)
 
 
 @pytest.mark.parametrize("fill", ["bound", "random"])
@@ -102,15 +119,8 @@ def test_knob_lookup_counts_match_numpy_binary_search():
 def test_dispatch_lowers_without_a_while_loop():
     """The knob lookups of ``dispatch`` stay loop-free: a binary search
     would bring back a ``while`` of per-level gathers."""
-    from repro.fleet.worker import FleetWorkerPool
-    from repro.fleet.workloads import har_workload, harris_workload, \
-        lm_workload
-    wls = [har_workload(), harris_workload(), lm_workload()]
     n = 256
-    pool = FleetWorkerPool(np.full((1, 100), 1e-3), 0.01,
-                           workloads=[w.costs for w in wls],
-                           mode="dispatch", n_workers=n)
-    sp = S.make_sched_params(pool.params, wls, max_batch=B_MAX)
+    sp = _sched_params(n)
     ss = tuple(np.asarray(getattr(S.make_sched_state(sp), f))
                for f in S.SS._fields)
     rng = np.random.default_rng(6)
@@ -175,16 +185,9 @@ def test_table_lookup_and_packed_samples_match_numpy(dtype):
 def test_collect_ledger_int32_forms_at_large_counters():
     """Collect's int32 unit division and the ledger's reduced sample
     numbering, with run-long completion counters far past 2**31."""
-    from repro.fleet.worker import FleetWorkerPool
-    from repro.fleet.workloads import har_workload, harris_workload, \
-        lm_workload
     rng = np.random.default_rng(4)
-    wls = [har_workload(), harris_workload(), lm_workload()]
     n = 512
-    pool = FleetWorkerPool(np.full((1, 100), 1e-3), 0.01,
-                           workloads=[w.costs for w in wls],
-                           mode="dispatch", n_workers=n)
-    sp = S.make_sched_params(pool.params, wls, max_batch=B_MAX)
+    sp = _sched_params(n)
     ss = S.make_sched_state(sp)
     ss.f_n = rng.integers(0, B_MAX + 1, n).astype(np.int64)
     ss.f_wl = rng.integers(0, sp.W, n).astype(np.int64)
@@ -208,3 +211,96 @@ def test_collect_ledger_int32_forms_at_large_counters():
             np.testing.assert_allclose(g, w, rtol=1e-12, err_msg=f)
         else:
             np.testing.assert_array_equal(g, w, err_msg=f)
+
+
+def _shed_by_gather(q_t, q_head, q_len, t, after):
+    """Shed's first form: gather each queue into logical order and take
+    the first position that is not stale."""
+    q = q_t.shape[1]
+    j = np.arange(q)[None, :]
+    log_t = np.take_along_axis(q_t, (q_head[:, None] + j) % q, axis=1)
+    stale = (j < q_len[:, None]) & (t - log_t > after)
+    n = np.min(np.where(stale, q, j), axis=1)
+    return (q_head + n) % q, q_len - n, n
+
+
+SHED_Q, SHED_T, SHED_AFTER = 64, 100.0, 30.0
+OLD, NEW, EDGE = 10.0, 99.0, SHED_T - SHED_AFTER  # stale, fresh, age == 30
+
+
+# each case: per queue (head, arrival times in logical order, n_shed)
+SHED_CASES = {
+    "wraps_past_the_last_slot": [
+        (SHED_Q - 5, [OLD] * 8 + [NEW] * 4, 8),
+        (SHED_Q - 1, [OLD] * 3, 3),
+        (SHED_Q - 2, [NEW] + [OLD] * 6, 0)],
+    "full_ring_all_stale": [
+        (17, [OLD] * SHED_Q, SHED_Q), (0, [OLD] * SHED_Q, SHED_Q),
+        (SHED_Q - 1, [OLD] * SHED_Q, SHED_Q)],
+    "empty_queues": [(0, [], 0), (31, [], 0), (SHED_Q - 1, [], 0)],
+    "fresh_head_before_stale_retries": [
+        (3, [NEW] + [OLD] * 9, 0), (SHED_Q - 2, [NEW, NEW] + [OLD] * 5, 0),
+        (40, [EDGE] + [OLD] * 4, 0)],
+    "stale_run_ends_mid_queue": [
+        (40, [OLD] * 7 + [NEW] * 13, 7),
+        (SHED_Q - 3, [OLD] * 5 + [NEW] + [OLD] * 4, 5),
+        (9, [OLD] * 30 + [NEW], 30)],
+    "age_equal_to_the_limit_is_fresh": [
+        (0, [OLD] * 3 + [EDGE] + [OLD] * 2, 3), (50, [EDGE] * 4, 0),
+        (SHED_Q - 2, [np.nextafter(EDGE, 0.0), EDGE, OLD], 1)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHED_CASES))
+def test_shed_physical_order_matches_logical_gather(case):
+    """Shed's masked min over physical slots under jit equals NumPy's
+    and the logical-order gather's, integer for integer. Retries re-enter
+    at a queue's front with their original arrival times, so a fresh head
+    may stand before stale entries; then nothing is shed."""
+    rows = SHED_CASES[case]
+    sp = dataclasses.replace(_sched_params(8), Q=SHED_Q,
+                             shed_after_s=SHED_AFTER)
+    q_t = np.zeros((sp.W, SHED_Q))  # unqueued slots read stale
+    q_head = np.array([h for h, _, _ in rows], np.int64)
+    q_len = np.array([len(a) for _, a, _ in rows], np.int64)
+    for w, (h, arr, _) in enumerate(rows):
+        q_t[w, (h + np.arange(len(arr))) % SHED_Q] = arr
+    ss = S.make_sched_state(sp)
+    ss = S.SS(*(getattr(ss, f) for f in S.SS._fields))._replace(
+        q_t=q_t, q_head=q_head, q_len=q_len, shed=np.int64(5))
+    head, length, n = _shed_by_gather(q_t, q_head, q_len, SHED_T,
+                                      SHED_AFTER)
+    np.testing.assert_array_equal(n, [k for _, _, k in rows])
+    want = (head, length, 5 + n.sum())
+    host = S.shed(sp, ss, SHED_T, np)
+    dev = _jit(lambda s, t: S.shed(sp, s, t, jnp), ss, SHED_T)
+    for got in (host, dev):
+        for f, w in zip(("q_head", "q_len", "shed"), want):
+            g = np.asarray(getattr(got, f))
+            assert g.dtype == np.int64, f
+            np.testing.assert_array_equal(g, w, err_msg=f)
+
+
+def test_shed_lowers_without_a_gather():
+    """At the 131,072-worker ring (3 queues of 4,096 + 131,072 * 4
+    slots) shed reads the ring in place: no gather of the float64
+    arrival times comes back."""
+    q = 4096 + 131_072 * B_MAX
+    sp = _sched_params(8)
+    ss = S.make_sched_state(sp)
+    ss = S.SS(*(getattr(ss, f) for f in S.SS._fields))
+    sp = dataclasses.replace(sp, Q=q)
+    args = (jax.ShapeDtypeStruct((sp.W, q), jnp.float64),
+            jax.ShapeDtypeStruct((sp.W,), jnp.int64),
+            jax.ShapeDtypeStruct((sp.W,), jnp.int64),
+            jax.ShapeDtypeStruct((), jnp.float64))
+
+    def fn(q_t, q_head, q_len, t):
+        out = S.shed(sp, ss._replace(q_t=q_t, q_head=q_head, q_len=q_len),
+                     t, jnp)
+        return out.q_head, out.q_len, out.shed
+
+    with jax.enable_x64(True):
+        text = jax.jit(fn).lower(*args).as_text()
+    assert not re.search(r"stablehlo\.(dynamic_)?gather", text)
+    assert "stablehlo.reduce" in text  # the row min, so it is shed
