@@ -76,29 +76,10 @@ def main() -> dict:
     us = timeit(ssd, x3, dt3, A3, Bm)
     emit("kernels.ssd_scan_jax_512", us, "chunk=64")
 
-    # fleet harvest kernel (interpret) vs float reference + jnp twin
-    from repro.core.energy import capacitor_harvest
-    from repro.kernels.fleet_step import harvest_step
-    nw = 8192
-    rng = np.random.default_rng(0)
-    vv = jnp.asarray(rng.uniform(0.0, 5.0, nw))
-    pw = jnp.asarray(rng.uniform(0.0, 5e-3, nw))
-    cc = jnp.asarray(rng.uniform(50e-6, 200e-6, nw))
-    vmx = jnp.full((nw,), 5.5)
-    got = harvest_step(vv, pw, cc, vmx, eff=0.7, dt=0.01, interpret=True)
-    want = capacitor_harvest(vv, pw, 0.01, capacitance_f=cc,
-                             booster_eff=0.7, v_max=vmx, xp=jnp)
-    emit("kernels.fleet_step_allclose", 0.0,
-         str(bool(np.allclose(got, want, rtol=1e-6))))
-    hv = jax.jit(lambda v: capacitor_harvest(v, pw, 0.01, capacitance_f=cc,
-                                             booster_eff=0.7, v_max=vmx,
-                                             xp=jnp))
-    emit("kernels.fleet_harvest_jax_8k", timeit(hv, vv), "jnp twin")
-
     # serve-tick megakernel (interpret) vs the quantized reference tick,
     # timed as the jitted q32 twin (the same integer numerics as XLA)
     from benchmarks.fleet_megakernel import _serve_tick_fixture
-    tick_pallas, tick_q32, agree = _serve_tick_fixture(nw, interpret=True)
+    tick_pallas, tick_q32, agree = _serve_tick_fixture(8192, interpret=True)
     emit("kernels.serve_tick_agrees_q32", 0.0, str(agree))
     emit("kernels.serve_tick_q32_twin_8k", timeit(tick_q32), "one tick")
     emit("kernels.serve_tick_interpret_8k", timeit(tick_pallas),

@@ -16,10 +16,8 @@ Claims checked:
 - the array-native control plane (``--control-plane``): a full
   1024-worker / 600 s serve trace with ``--backend jax`` runs workers AND
   scheduler as one compiled launch, agrees with the NumPy per-tick
-  reference on all request/emission counts, forecast routing beats
-  reactive routing on completed requests for the solar trace families,
-  and the fused launch beats the PR-1-style host-interleaved cadence on
-  wall clock (the before/after scaling table);
+  reference on all request/emission counts, and forecast routing beats
+  reactive routing on completed requests for the solar trace families;
 - pluggable forecasters (``--forecasters``): the forecaster-vs-family
   completed-requests matrix at 1024 workers / 600 s — regime-aware
   models (occlusion for mobile solar, burst for RF) complete at least as
@@ -364,97 +362,6 @@ def control_plane_comparison(n_workers: int = 1024,
     return out
 
 
-def _run_interleaved_jax(pool, sched, stream, n_steps: int,
-                         dispatch_every: int = 10) -> dict:
-    """The *before* cadence (PR 2): device physics as 10-tick
-    ``step_macro`` scans with the scheduler on the host between them —
-    every macro-step pays a device launch plus a full state round-trip.
-    Collection lands at macro boundaries, so counts are close to (not
-    bit-equal with) the per-tick cadences; this driver exists only to
-    price the host interleaving the fused launch removes."""
-    dt = pool.dt
-    for i0 in range(0, n_steps, dispatch_every):
-        k = min(dispatch_every, n_steps - i0)
-        t = i0 * dt
-        sched.submit(t, stream.arrivals(i0))
-        sched.dispatch(t, i0)
-        for i in range(i0 + 1, i0 + k):
-            wls = stream.arrivals(i)
-            if wls.size:
-                sched.submit(i * dt, wls)
-        pool.step_macro(i0, k)
-        sched.collect((i0 + k - 1) * dt, evict=True)
-    return sched.summary(n_steps * dt)
-
-
-def control_plane_scaling(sizes=(256, 1024), duration_s: float = 120.0,
-                          seed: int = 3) -> dict:
-    """Before/after table for the serve hot path. Before: the PR-2-style
-    host-interleaved cadence (JAX macro-step scans with the scheduler on
-    the host between launches). After: the fused single launch, timed
-    cold (includes the one-off serve-scan compile) and warm (fresh
-    states, same compiled launch). The NumPy host-tick driver rides along
-    as the CPU reference point."""
-    from repro.fleet.sched import make_sched_state
-    from repro.fleet.scheduler import FleetScheduler, RequestStream, \
-        run_fleet
-    from repro.launch.fleet import build_dispatch_pool
-
-    n_steps = int(duration_s / DT)
-    out = {}
-    for n in sizes:
-        power = make_power_matrix(TRACES, min(32, n), duration_s, DT, seed)
-        wls = _workloads()
-        stream = RequestStream(n / PERIOD_S, MIX, n_steps, DT,
-                               seed=seed + 1)
-
-        t0 = time.perf_counter()
-        np_res = run_scheduled(power, DT, n, wls, rate_rps=n / PERIOD_S,
-                               mix=MIX, n_steps=n_steps, seed=seed,
-                               backend="numpy", sched="forecast")
-        np_s = time.perf_counter() - t0
-
-        # before: host-interleaved macro-stepping (warm = re-run on the
-        # already-compiled 10-tick scan, fresh states)
-        pool = build_dispatch_pool(power, DT, n, wls, seed, backend="jax")
-        sched = FleetScheduler(pool, wls, sched="forecast")
-        t0 = time.perf_counter()
-        _run_interleaved_jax(pool, sched, stream, n_steps)
-        inter_cold = time.perf_counter() - t0
-        pool.reset()
-        sched.state = make_sched_state(sched.params)
-        t0 = time.perf_counter()
-        inter_res = _run_interleaved_jax(pool, sched, stream, n_steps)
-        inter_warm = time.perf_counter() - t0
-
-        # after: the whole serve trace as one launch
-        pool = build_dispatch_pool(power, DT, n, wls, seed, backend="jax")
-        sched = FleetScheduler(pool, wls, sched="forecast")
-        t0 = time.perf_counter()
-        jax_res = run_fleet(pool, sched, stream, n_steps)
-        cold = time.perf_counter() - t0
-        pool.reset()
-        sched.state = make_sched_state(sched.params)
-        t0 = time.perf_counter()
-        jax_res = run_fleet(pool, sched, stream, n_steps)
-        warm = time.perf_counter() - t0
-        out[str(n)] = {
-            "completed": {"numpy": np_res["completed"],
-                          "jax_fused": jax_res["completed"],
-                          "jax_interleaved": inter_res["completed"]},
-            "counts_agree_numpy_vs_fused": all(
-                np_res[k] == jax_res[k] for k in _COUNT_KEYS),
-            "wall_s": {"numpy_host_ticks": np_s,
-                       "jax_interleaved_cold": inter_cold,
-                       "jax_interleaved_warm": inter_warm,
-                       "jax_fused_cold": cold,
-                       "jax_fused_warm": warm},
-            "speedup_fused_over_interleaved_warm":
-                inter_warm / max(warm, 1e-9),
-        }
-    return out
-
-
 # ---------------------------------------------------------------------------
 # pluggable forecasters: model x trace-family completed-requests matrix
 # ---------------------------------------------------------------------------
@@ -548,11 +455,10 @@ def run_control_plane_suite(n_workers: int = 1024,
                              obs_window_s=obs_window_s,
                              trace_out=trace_out)
     comp = control_plane_comparison(n_workers, duration_s)
-    scaling = control_plane_scaling()
     total = time.perf_counter() - t0
     res = {"agreement": agree, "forecast_vs_reactive": comp,
-           "host_vs_fused_scaling": scaling, "host": host_metadata()}
-    us = total * 1e6 / 3
+           "host": host_metadata()}
+    us = total * 1e6 / 2
     emit("fleet.sched_counts_agree", us, str(agree["counts_agree"]))
     if obs_mode != "off":
         emit("fleet.obs_channels_agree", us,
@@ -560,9 +466,6 @@ def run_control_plane_suite(n_workers: int = 1024,
     for fam, per in comp.items():
         emit(f"fleet.forecast_over_reactive_{fam}", us,
              f"{per['forecast_over_reactive']:.3f}x")
-    top = str(max(int(k) for k in scaling))
-    emit(f"fleet.fused_over_interleaved_warm_at_{top}", us,
-         f"{scaling[top]['speedup_fused_over_interleaved_warm']:.2f}x")
     out = Path("experiments")
     out.mkdir(exist_ok=True)
     (out / "fleet_control_plane.json").write_text(

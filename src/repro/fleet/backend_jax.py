@@ -22,22 +22,16 @@ count equality). XLA:TPU emulates float64 and does not round as IEEE
 does, so on the chip that agreement is measured (``chip_smoke.py``), not
 promised; the int32 ``q32``/``pallas`` ticks do not depend on it.
 
-Events (dispatch mode) are materialized as fixed-capacity (N,) arrays —
-code / time / ticket / units per worker — instead of Python tuple lists.
-Capacity one-per-worker-per-macro-step is an invariant, not a truncation:
-a worker's assignment can terminate (emit or loss) at most once per
-tick, and new assignments only arrive between device steps.
-
-``run_serve`` goes further: the array-native control plane
+``run`` is that scan for local-mode fleets. A dispatch-mode fleet
+serves through ``run_serve``: the array-native control plane
 (``repro.fleet.sched``) is traced *into* the scan — admission and event
 collection every tick, shed/dispatch/evict under a ``lax.cond`` at the
 dispatch cadence — so an entire serve trace (workers AND scheduler) is a
-single compiled launch; events are consumed by the in-scan collect the
-same tick they occur and never reach the host at all.
-
-Optionally the harvest stage runs through the Pallas capacitor-bank
-kernel (``repro.kernels.fleet_step``) — the TPU fast path; interpret mode
-(``interpret=True``) keeps it testable on CPU-only environments.
+single compiled launch. Each tick's events are fixed-capacity (N,)
+arrays — code / time / ticket / units per worker — that the in-scan
+collect consumes the same tick; they never reach the host. Capacity
+one per worker per tick is an invariant, not a truncation: a worker's
+assignment can terminate (emit or loss) at most once per tick.
 
 ``kernel`` selects the device-tick numerics/implementation:
 
@@ -90,11 +84,9 @@ def _nbytes(tree) -> int:
 class JaxFleetBackend:
     """Compiled scan runner for one ``FleetParams`` configuration."""
 
-    def __init__(self, params: FleetParams, *, use_pallas: bool = False,
-                 kernel: str = "xla", fleet_placement: str = "mesh",
-                 interpret: bool = False):
+    def __init__(self, params: FleetParams, *, kernel: str = "xla",
+                 fleet_placement: str = "mesh", interpret: bool = False):
         self.p = params
-        self.use_pallas = use_pallas
         self.kernel = kernel
         self.fleet_placement = fleet_placement
         # Pallas kernels run interpreted only when the caller asks (CPU
@@ -180,53 +172,26 @@ class JaxFleetBackend:
     # -- public API ----------------------------------------------------------
 
     def run(self, state: FleetState, i0: int,
-            n_ticks: int) -> tuple[FleetState, list[tuple]]:
-        """Advance ``n_ticks`` from trace index ``i0``; returns the updated
-        host-side state and decoded dispatch events (empty in local mode).
-        """
-        p = self.p
+            n_ticks: int) -> FleetState:
+        """Advance a local-mode fleet ``n_ticks`` from trace index ``i0``
+        as one float64 ``lax.scan``; returns the updated host-side state.
+        A dispatch-mode fleet advances only with its scheduler, through
+        :meth:`run_serve`."""
+        if self.p.mode != "local":
+            raise ValueError(
+                "a dispatch-mode fleet serves through run_serve "
+                "(run_fleet / run_fleet_stream), with its scheduler in "
+                "the scan")
         with jax.enable_x64(True):
             st = tuple(jnp.asarray(x) for x in state_as_tuple(state))
-            n = p.n
-            if self.kernel == "xla":
-                ev0 = (jnp.zeros(n, jnp.int64), jnp.zeros(n, jnp.float64),
-                       jnp.zeros(n, jnp.int64), jnp.zeros(n, jnp.int64))
-            else:  # quantized log: int32 codes, integer tick times
-                ev0 = tuple(jnp.zeros(n, jnp.int32) for _ in range(4))
             fn = self._compiled.get(n_ticks)
             if fn is None:
                 fn = self._build(n_ticks)
                 self._compiled[n_ticks] = fn
-            st_out, ev_out = fn(st, ev0, jnp.asarray(i0, jnp.int64))
-            # np.array (copy): the host state must stay writable for the
-            # scheduler's assign/evict mutations between macro-steps
+            st_out = fn(st, jnp.asarray(i0, jnp.int64))
+            # np.array (copy): the host state must stay writable
             st_out = tuple(np.array(x) for x in st_out)
-            ev_out = tuple(np.asarray(x) for x in ev_out)
-        new_state = state_from_tuple(st_out)
-        events = (self._decode_events(new_state, ev_out)
-                  if p.mode == "dispatch" else [])
-        return new_state, events
-
-    # -- event decoding ------------------------------------------------------
-
-    def _decode_events(self, s: FleetState, ev: tuple) -> list[tuple]:
-        from repro.fleet.backend_numpy import EMIT, LOST
-        code, ev_t, ev_ticket, ev_units = ev
-        # quantized logs stamp integer tick indices, not seconds
-        scale = 1.0 if self.kernel == "xla" else self.p.dt
-        hit = np.nonzero(code != EV_NONE)[0]
-        out: list[tuple] = []
-        for w in hit[np.lexsort((hit, ev_t[hit]))]:  # temporal order
-            w = int(w)
-            if code[w] == EV_EMIT:
-                out.append((EMIT, float(ev_t[w]) * scale, w,
-                            int(ev_ticket[w]),
-                            int(ev_units[w]), int(s.w_tile[w]),
-                            int(s.w_batch[w])))
-            else:
-                out.append((LOST, float(ev_t[w]) * scale, w,
-                            int(ev_ticket[w])))
-        return out
+        return state_from_tuple(st_out)
 
     # -- compiled scan -------------------------------------------------------
 
@@ -239,15 +204,14 @@ class JaxFleetBackend:
         return self._tick
 
     def _build(self, n_ticks: int):
-        tick = self._pick_tick()
+        def scan_fn(st, i0):
+            def body(st, j):
+                # local mode records no events: the log is empty (None)
+                return self._tick(st, None, i0 + j)[0], None
 
-        def scan_fn(st, ev, i0):
-            def body(carry, j):
-                return tick(carry[0], carry[1], i0 + j), None
-
-            (st, ev), _ = lax.scan(body, (st, ev),
-                                   jnp.arange(n_ticks, dtype=jnp.int64))
-            return st, ev
+            st, _ = lax.scan(body, st,
+                             jnp.arange(n_ticks, dtype=jnp.int64))
+            return st
 
         return jax.jit(scan_fn)
 
@@ -262,7 +226,7 @@ class JaxFleetBackend:
         per-tick arrival counts are the scan input, admission/collection
         run every tick, the shed/dispatch/evict passes fire under a
         ``lax.cond`` at the dispatch cadence, and only the two final
-        states come back to the host. No per-macro-step transfers.
+        states come back to the host. No per-tick transfers.
 
         ``obs`` (a ``repro.obs.FleetObs``) threads the telemetry /
         event-ring arrays through the scan carry and writes them back
@@ -721,17 +685,13 @@ class JaxFleetBackend:
 
     def _harvest(self, v, pw):
         p = self.p
-        if self.use_pallas:
-            from repro.kernels.fleet_step import harvest_step
-            return harvest_step(v, pw, self.C, self.v_max, eff=p.eff,
-                                dt=p.dt, interpret=self.interpret)
         return capacitor_harvest(v, pw, p.dt, capacitance_f=self.C,
                                  booster_eff=p.eff, v_max=self.v_max,
                                  xp=jnp)
 
     def _rec(self, ev, mask, code, t, ticket, units):
         """Record events for ``mask`` lanes into the fixed-capacity log
-        (first event per worker per macro-step wins; see module docstring
+        (first event per worker per tick wins; see module docstring
         for why a second cannot occur)."""
         evc, evt, evtk, evu = ev
         new = mask & (evc == EV_NONE)
@@ -753,9 +713,8 @@ class JaxFleetBackend:
         """Quantized tick as one fused Pallas pass per tick
         (``repro.kernels.serve_tick``): compiled for the TPU, or
         interpret-mode when the backend was built with ``interpret``.
-        The kernel emits a fresh event log; it is merged into the carried
-        log first-event-wins so macro-step runs keep the
-        one-event-per-worker invariant."""
+        The kernel emits a fresh event log; it is merged into the passed
+        log first-event-wins (the one-event-per-worker invariant)."""
         from repro.fleet import qtick as Q
         from repro.kernels import serve_tick as K
         p = self.p

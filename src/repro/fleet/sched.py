@@ -8,8 +8,8 @@ module re-expresses every control-plane mechanism as pure, xp-parametric
 (``xp`` is numpy or jax.numpy) functions over the struct-of-arrays
 ``SchedState`` so the same expressions serve two evaluation modes:
 
-- the NumPy reference (``FleetScheduler`` in ``repro.fleet.scheduler``
-  drives them tick-by-tick on the host), and
+- the NumPy reference (the host window ``_HostServe`` in
+  ``repro.fleet.scheduler`` drives them tick-by-tick on the host), and
 - the fused JAX path (``backend_jax.run_serve`` traces them *inside* the
   worker scan, so an entire serve trace — workers and scheduler — runs as
   one device launch with no host interleaving).

@@ -7,14 +7,16 @@ should run this request, at which knob setting, so the result is emitted
 within the worker's current power cycle?* Since PR 3 the answer is
 computed by pure struct-of-arrays ops (queue ring-buffers, cumulative-sum
 batching, stable-sort routing) instead of a per-request Python object
-loop, so the same expressions run in two modes:
+loop, so the same expressions run in two modes, both driven chunk by
+chunk by :func:`run_fleet_stream` (:func:`run_fleet` is its one-chunk
+form):
 
-- ``backend="numpy"`` pools: :class:`FleetScheduler` drives the array ops
-  tick-by-tick on the host — the bit-exact reference cadence;
-- ``backend="jax"`` pools: :func:`run_fleet` hands the whole serve trace
-  to ``backend_jax.run_serve`` — workers **and** scheduler fused into a
-  single ``lax.scan`` device launch with no per-macro-step host
-  round-trips.
+- ``backend="numpy"`` pools: the host window (:class:`_HostServe`)
+  evaluates the array ops tick by tick on the host, for one shard or K —
+  the bit-exact reference cadence;
+- ``backend="jax"`` pools: each chunk is one ``backend_jax.run_serve``
+  launch — workers **and** scheduler fused into a single ``lax.scan``
+  with no per-tick host round-trips.
 
 Routing is *forecast-aware* (``sched="forecast"``): workers are ranked —
 and batches sized — by the conditional expectation of usable energy over
@@ -49,11 +51,6 @@ class RequestStream:
         total = int(self.counts.sum())
         mix = np.asarray(mix, dtype=np.float64)
         self.wl = rng.choice(mix.shape[0], size=total, p=mix / mix.sum())
-        self.offsets = np.concatenate([[0], np.cumsum(self.counts)])
-
-    def arrivals(self, i: int) -> np.ndarray:
-        """Workload indices of the requests arriving at step ``i``."""
-        return self.wl[self.offsets[i]:self.offsets[i + 1]]
 
     def counts_matrix(self, n_workloads: int) -> np.ndarray:
         """(n_steps, W) per-tick arrival counts — the array-native form
@@ -69,11 +66,10 @@ class FleetScheduler:
     """Host handle over (``SchedParams``, ``SchedState``) for one pool.
 
     Construction compiles the workload tables into stacked arrays and
-    fits the per-trace-row harvest forecaster; ``submit`` / ``dispatch``
-    / ``collect`` evaluate the shared control-plane expressions with
-    ``xp=numpy`` against the pool's live state (the reference path). The
-    fused JAX path bypasses these methods and runs the identical
-    expressions inside the device scan.
+    fits the per-trace-row harvest forecaster. The two serve paths
+    (:class:`_HostServe` on the host, ``backend_jax.run_serve`` on the
+    device) advance ``state`` with the shared control-plane expressions
+    of ``repro.fleet.sched``.
     """
 
     def __init__(self, pool: FleetWorkerPool,
@@ -179,164 +175,68 @@ class FleetScheduler:
                              duration_s, self.pool,
                              [w.name for w in self.workloads])
 
-    # -- intake --------------------------------------------------------------
-
-    def submit(self, t: float, workload_ids: np.ndarray) -> None:
-        """Admit arrivals; reject beyond the global queue bound."""
-        counts = np.bincount(np.asarray(workload_ids, dtype=np.int64),
-                             minlength=self.params.W).astype(np.int64)
-        self._store(_sched.admit(self.params, self._ss(), counts,
-                                 float(t), np))
-
-    # -- dispatch ------------------------------------------------------------
-
-    def dispatch(self, t: float, i: int | None = None) -> int:
-        """Shed stale work, then route queued requests to capable workers
-        (richest planning budget first). Returns requests assigned."""
-        sp = self.params
-        p = self.pool.params
-        s = self.pool.state
-        if i is None:
-            i = int(round(t / p.dt))
-        ss = _sched.shed(sp, self._ss(), float(t), np)
-        budget_now = backend_numpy.usable_energy(p, s)
-        pw_lags = _sched.power_lags(p.power, p.trace_index, i, p.T,
-                                    sp.fc_order, phase=p.phase, xp=np)
-        budget_plan = _sched.plan_budget(sp, budget_now, pw_lags, p.eff,
-                                         np)
-        dispatchable = s.on & ~s.has_work & ~s.p_pending
-        ss, a = _sched.dispatch(sp, ss, dispatchable, budget_now,
-                                budget_plan, float(t), np,
-                                us=backend_numpy.usable_quanta(p, s))
-        s.p_pending = s.p_pending | a.mask
-        s.p_wl = np.where(a.mask, a.wl, s.p_wl)
-        s.p_units = np.where(a.mask, a.units, s.p_units)
-        s.p_batch = np.where(a.mask, np.maximum(a.batch, 1), s.p_batch)
-        s.p_t_assigned = np.where(a.mask, float(t), s.p_t_assigned)
-        self._store(ss)
-        return int(a.batch.sum())
-
-    # -- harvest results / losses -------------------------------------------
-
-    def collect(self, t: float, evict: bool = False) -> None:
-        """Retire the pool's emit/loss events through the array control
-        plane; optionally run the straggler-eviction pass."""
-        n = self.params.n
-        emit = np.zeros(n, dtype=bool)
-        lost = np.zeros(n, dtype=bool)
-        units = np.zeros(n, dtype=np.int64)
-        for ev in self.pool.pop_events():
-            w = int(ev[2])
-            if ev[0] == EMIT:
-                emit[w] = True
-                units[w] = int(ev[4])
-            else:
-                lost[w] = True
-        ss = _sched.collect(self.params, self._ss(), emit, lost, units,
-                            float(t), np)
-        if evict:
-            ss, evm = _sched.evict(self.params, ss, float(t), np)
-            s = self.pool.state
-            s.p_pending = s.p_pending & ~evm
-            s.has_work = s.has_work & ~evm
-        self._store(ss)
-
 
 def run_fleet(pool: FleetWorkerPool, sched: FleetScheduler,
               stream: RequestStream, n_steps: int, *,
               dispatch_every: int = 10, obs=None) -> dict:
-    """Drive arrivals -> control plane -> device physics -> collection.
+    """Drive arrivals -> control plane -> device physics -> collection
+    over ``n_steps`` ticks: :func:`run_fleet_stream` as one chunk, with
+    its ``"stream"`` block removed from the summary.
 
-    With a NumPy pool the loop advances tick-by-tick on the host (the
-    reference cadence). With a JAX pool the *entire* serve trace —
-    arrivals, admission, routing, batching, shedding, eviction, and the
-    device physics — runs as one fused ``lax.scan`` launch
-    (``backend_jax.run_serve``): the arrival counts matrix is the scan
-    input, the dispatch/evict passes fire under a ``lax.cond`` every
-    ``dispatch_every`` ticks, and only the final states return to the
-    host. Both paths evaluate the same control-plane expressions and
-    agree exactly on all discrete counts.
-
-    ``obs`` (a ``repro.obs.FleetObs``, or None) instruments the run:
-    the NumPy loop calls its snapshot hooks around each tick, the JAX
-    path threads its arrays through the scan carry — both fill the same
-    int64 channels bit-exactly, and neither perturbs the serve results.
+    With a JAX pool that chunk is one fused ``backend_jax.run_serve``
+    launch over the whole trace; with a NumPy pool it is one
+    :class:`_HostServe` window, the per-tick reference. Both evaluate the
+    same control-plane expressions and agree exactly on all discrete
+    counts. ``obs`` (a ``repro.obs.FleetObs``, or None) instruments the
+    run without perturbing its results.
     """
-    dt = pool.dt
-    if getattr(pool, "backend", "numpy") == "jax":
-        arrivals = stream.counts_matrix(sched.params.W)[:n_steps]
-        pool.run_serve(sched, arrivals, dispatch_every=dispatch_every,
-                       obs=obs)
-        return sched.summary(n_steps * dt)
-    if sched.params.shards > 1:
-        return _run_fleet_numpy_sharded(pool, sched, stream, n_steps,
-                                        dispatch_every, obs)
-    for i in range(n_steps):
-        t = i * dt
-        if obs is not None:
-            obs.host_begin(pool.state, sched.state)
-        wls = stream.arrivals(i)
-        if wls.size:
-            sched.submit(t, wls)
-        tick = i % dispatch_every == 0
-        if tick:
-            sched.dispatch(t, i)
-            if obs is not None:
-                obs.host_after_dispatch(pool.state)
-        pool.step(i)
-        if obs is not None:
-            obs.host_before_evict(pool.state)
-        sched.collect(t, evict=tick)
-        if obs is not None:
-            obs.host_end(i, tick, pool.state, sched.state)
-    return sched.summary(n_steps * dt)
+    summary = run_fleet_stream(pool, sched, stream, n_steps,
+                               chunk_ticks=n_steps,
+                               dispatch_every=dispatch_every, obs=obs)
+    del summary["stream"]
+    return summary
 
 
-_FS = collections.namedtuple("_FS", STATE_FIELDS)
+class _HostServe:
+    """The NumPy reference serve, the one host serve loop of a NumPy pool:
+    :meth:`window` serves a chunk of ticks, mutating pool and scheduler
+    state in place, so the streaming loop carries full state across
+    chunk boundaries. It is the reference the fused scan (and, for K > 1,
+    the traced ``shard_map``/``vmap`` path) is gated against bit for bit.
 
+    The control plane loops over ``K = sched.params.shards`` contiguous
+    worker slices. At K = 1 the one slice is the whole fleet under
+    ``sched.params`` itself (the unsharded ring, the unstacked state) and
+    no rebalance runs. At K > 1 (``--mesh-fleet K``) each shard gets
+    its own admission (the deterministic ``split_counts`` arrival split:
+    elementwise, so splitting each chunk equals slicing the full split),
+    shed/plan/dispatch/collect/evict against its params view, and the
+    all-integer work-stealing exchange via
+    :func:`repro.fleet.sched.rebalance_host`; the per-shard scheduler
+    states are restacked into the (K, ...) form the fused scan uses. The
+    device physics stays full-fleet: the tick is embarrassingly parallel
+    over workers, so one ``pool.step`` per tick is bit-identical to K
+    shard-local ticks.
 
-def _slice_state(s, sl: slice) -> _FS:
-    """One shard's view of the (N,) struct-of-arrays device state."""
-    return _FS(*(getattr(s, f)[sl] for f in STATE_FIELDS))
-
-
-class _ShardedHostServe:
-    """NumPy host twin of the sharded serve scan (``--mesh-fleet K``),
-    restructured around :meth:`window` so the streaming loop can drive
-    it chunk-by-chunk with full state carried across chunk boundaries.
-
-    The device physics stays full-fleet — the tick is embarrassingly
-    parallel over workers, so one ``pool.step`` per tick is already
-    bit-identical to K shard-local ticks. Only the control plane loops
-    the K contiguous shard slices: per-shard admission (deterministic
-    ``split_counts`` arrival split — elementwise, so splitting each
-    chunk equals slicing the full split), shed/plan/dispatch/collect/
-    evict against each shard's params view, the all-integer
-    work-stealing exchange via :func:`repro.fleet.sched.rebalance_host`,
-    and (in tele mode) K per-shard telemetry states summed into
-    ``obs.tele`` at each window end — every channel is an int64
-    scatter-add, so the per-window shard sums accumulate to exactly the
-    whole-trace counters. This is the reference the traced
-    ``shard_map``/``vmap`` path is gated against bit-for-bit.
-
-    Each :meth:`window` call re-reads ``sched.params`` (a causal refit
-    between chunks swaps the ``FC_*`` tables) and restacks the
-    per-shard scheduler states into ``sched.state`` on exit, so the
-    carried state is exactly the (K, ...) stacked form the fused scan
-    uses.
+    With ``obs``, every tick evaluates the shared
+    :func:`repro.obs.telemetry.obs_tick` per shard. Each window's shard
+    telemetry starts at zero and is summed into ``obs.tele`` at its end:
+    every channel is an int64 scatter-add, so the sums equal the
+    whole-trace counters. The event ring (``--obs trace``, K = 1 only)
+    is carried in place.
     """
 
     def __init__(self, pool: FleetWorkerPool, sched: FleetScheduler,
                  dispatch_every: int, obs):
         sp = sched.params
-        p = pool.params
-        if sp.rebalance_every and (sp.rebalance_every % dispatch_every):
+        self.K = sp.shards
+        if self.K > 1 and sp.rebalance_every % dispatch_every:
             raise ValueError(
                 f"rebalance_every={sp.rebalance_every} ticks must be a "
                 f"positive multiple of dispatch_every={dispatch_every}:"
                 " the work-stealing exchange runs inside the dispatch "
                 "pass")
-        if obs is not None and obs.op.mode != "tele":
+        if self.K > 1 and obs is not None and obs.op.mode != "tele":
             raise ValueError(
                 "--obs trace keeps a global per-worker event ring and "
                 "is not supported under --mesh-fleet > 1; use --obs "
@@ -345,49 +245,48 @@ class _ShardedHostServe:
         self.sched = sched
         self.dispatch_every = dispatch_every
         self.obs = obs
-        self.K = sp.shards
-        self.ns = p.n // self.K
-        self.sls = [slice(s * self.ns, (s + 1) * self.ns)
-                    for s in range(self.K)]
+        ns = pool.params.n // self.K
+        self.sls = [slice(s * ns, (s + 1) * ns) for s in range(self.K)]
 
     def window(self, counts: np.ndarray, i0: int) -> None:
         """Serve ticks ``[i0, i0 + counts.shape[0])`` with per-tick
-        arrival counts ``counts`` ((k, W) int64), mutating pool and
-        scheduler state in place."""
+        arrival counts ``counts`` ((k, W) int64). The tick index is
+        global, so harvest columns, dispatch/evict phase and shed
+        deadlines are chunk-invariant."""
         pool, sched, obs = self.pool, self.sched, self.obs
-        K, ns, sls = self.K, self.ns, self.sls
-        dispatch_every = self.dispatch_every
+        K, sls = self.K, self.sls
         sp = sched.params  # re-read: causal refits swap the FC_* tables
         p = pool.params
-        dt = pool.dt
-        sps = [_sched.shard_sched_params(sp, s) for s in range(K)]
-        split = _sched.split_counts(np.asarray(counts, np.int64), K)
-        st = sched.state
-        sss = [_sched.SS(*(np.asarray(getattr(st, f))[s]
-                           for f in _sched.SCHED_FIELDS))
-               for s in range(K)]
         dev = pool.state
+        if K == 1:
+            sps = [sp]
+            sss = [sched._ss()]
+        else:
+            sps = [_sched.shard_sched_params(sp, s) for s in range(K)]
+            sss = [_sched.SS(*(np.asarray(getattr(sched.state, f))[s]
+                               for f in _sched.SCHED_FIELDS))
+                   for s in range(K)]
+        split = _sched.split_counts(np.asarray(counts, np.int64), K)
         if obs is not None:
             from repro.obs import telemetry as O
-            from repro.obs.state import (init_tele, tele_as_tuple,
+            from repro.obs.state import (init_tele, ring_as_tuple,
+                                         ring_from_tuple, tele_as_tuple,
                                          tele_from_tuple)
             base = tele_as_tuple(init_tele(obs.op))
             teles = [tuple(np.zeros_like(np.asarray(x)) for x in base)
                      for _ in range(K)]
+            ring = None if obs.ring is None else ring_as_tuple(obs.ring)
         for j in range(split.shape[1]):
             i = i0 + j
-            t = i * dt
-            is_tick = i % dispatch_every == 0
+            t = i * p.dt
+            is_tick = i % self.dispatch_every == 0
             if obs is not None:
-                begins = [(O.dev_snap(_slice_state(dev, sl), copy=True),
-                           O.sched_snap(sss[s], np))
-                          for s, sl in enumerate(sls)]
-                assigns = [np.zeros(ns, dtype=bool) for _ in range(K)]
-                assign_wls = [np.zeros(ns, dtype=np.int64)
-                              for _ in range(K)]
+                b = O.dev_snap(dev, copy=True)
+                sbs = [O.sched_snap(ss, np) for ss in sss]
+                assign = np.zeros(p.n, dtype=bool)
+                assign_wl = np.zeros(p.n, dtype=np.int64)
             for s in range(K):
-                sss[s] = _sched.admit(sps[s], sss[s], split[s, j], t,
-                                      np)
+                sss[s] = _sched.admit(sps[s], sss[s], split[s, j], t, np)
             if is_tick:
                 budget_now = backend_numpy.usable_energy(p, dev)
                 us = backend_numpy.usable_quanta(p, dev)
@@ -400,132 +299,94 @@ class _ShardedHostServe:
                         xp=np)
                     plans.append(_sched.plan_budget(
                         sps[s], budget_now[sl], pw_lags, p.eff, np))
-                if sp.rebalance_every and i % sp.rebalance_every == 0:
+                if K > 1 and sp.rebalance_every and (
+                        i % sp.rebalance_every == 0):
                     sss = _sched.rebalance_host(sps, sss, plans)
-                mask_f = np.zeros(p.n, dtype=bool)
-                wl_f = np.zeros(p.n, dtype=np.int64)
-                units_f = np.zeros(p.n, dtype=np.int64)
-                batch_f = np.zeros(p.n, dtype=np.int64)
+                dispatchable = dev.on & ~dev.has_work & ~dev.p_pending
+                mask = np.zeros(p.n, dtype=bool)
+                wl = np.zeros(p.n, dtype=np.int64)
+                units = np.zeros(p.n, dtype=np.int64)
+                batch = np.zeros(p.n, dtype=np.int64)
                 for s, sl in enumerate(sls):
-                    dispatchable = (dev.on & ~dev.has_work
-                                    & ~dev.p_pending)[sl]
                     sss[s], a = _sched.dispatch(
-                        sps[s], sss[s], dispatchable, budget_now[sl],
-                        plans[s], t, np, us=None if us is None else us[sl])
-                    mask_f[sl] = a.mask
-                    wl_f[sl] = a.wl
-                    units_f[sl] = a.units
-                    batch_f[sl] = a.batch
-                # one full-width write round, the exact expressions (and
-                # dtype promotions) of FleetScheduler.dispatch
-                dev.p_pending = dev.p_pending | mask_f
-                dev.p_wl = np.where(mask_f, wl_f, dev.p_wl)
-                dev.p_units = np.where(mask_f, units_f, dev.p_units)
-                dev.p_batch = np.where(mask_f, np.maximum(batch_f, 1),
+                        sps[s], sss[s], dispatchable[sl], budget_now[sl],
+                        plans[s], t, np,
+                        us=None if us is None else us[sl])
+                    mask[sl] = a.mask
+                    wl[sl] = a.wl
+                    units[sl] = a.units
+                    batch[sl] = a.batch
+                # the one full-width write round of the assignments
+                dev.p_pending = dev.p_pending | mask
+                dev.p_wl = np.where(mask, wl, dev.p_wl)
+                dev.p_units = np.where(mask, units, dev.p_units)
+                dev.p_batch = np.where(mask, np.maximum(batch, 1),
                                        dev.p_batch)
-                dev.p_t_assigned = np.where(mask_f, float(t),
+                dev.p_t_assigned = np.where(mask, float(t),
                                             dev.p_t_assigned)
                 if obs is not None:
-                    for s, sl in enumerate(sls):
-                        assigns[s] = (dev.p_pending[sl]
-                                      & ~begins[s][0].p_pending)
-                        assign_wls[s] = dev.p_wl[sl].copy()
+                    assign = dev.p_pending & ~b.p_pending
+                    assign_wl = dev.p_wl.copy()
             pool.step(i)
             if obs is not None:
-                pre_evict = dev.p_pending | dev.has_work
+                # who holds an assignment before the evict pass
+                held = dev.p_pending | dev.has_work
             emit = np.zeros(p.n, dtype=bool)
             lost = np.zeros(p.n, dtype=bool)
-            units = np.zeros(p.n, dtype=np.int64)
+            done_units = np.zeros(p.n, dtype=np.int64)
             for ev in pool.pop_events():
                 w = int(ev[2])
                 if ev[0] == EMIT:
                     emit[w] = True
-                    units[w] = int(ev[4])
+                    done_units[w] = int(ev[4])
                 else:
                     lost[w] = True
             for s, sl in enumerate(sls):
                 sss[s] = _sched.collect(sps[s], sss[s], emit[sl],
-                                        lost[sl], units[sl], t, np)
+                                        lost[sl], done_units[sl], t, np)
             if is_tick:
-                evm_f = np.zeros(p.n, dtype=bool)
+                evm = np.zeros(p.n, dtype=bool)
                 for s, sl in enumerate(sls):
-                    sss[s], evm = _sched.evict(sps[s], sss[s], t, np)
-                    evm_f[sl] = evm
-                dev.p_pending = dev.p_pending & ~evm_f
-                dev.has_work = dev.has_work & ~evm_f
+                    sss[s], evm[sl] = _sched.evict(sps[s], sss[s], t, np)
+                dev.p_pending = dev.p_pending & ~evm
+                dev.has_work = dev.has_work & ~evm
             if obs is not None:
+                col = ((i % p.T) if p.phase is None
+                       else (i + p.phase) % p.T)
+                pw = p.power[p.trace_index, col]
+                evict_mask = held & ~(dev.p_pending | dev.has_work)
                 for s, sl in enumerate(sls):
-                    col = ((i % p.T) if p.phase is None
-                           else (i + p.phase[sl]) % p.T)
-                    pw = p.power[p.trace_index[sl], col]
-                    evict_mask = (pre_evict[sl]
-                                  & ~(dev.p_pending[sl]
-                                      | dev.has_work[sl]))
-                    teles[s], _ = O.obs_tick(
-                        obs.op, sps[s], teles[s], None, i=i, j=i,
-                        is_tick=is_tick, pw=pw, eff=p.eff, dt=p.dt,
-                        b=begins[s][0], sb=begins[s][1],
-                        assign_mask=assigns[s],
-                        assign_wl=assign_wls[s],
-                        evict_mask=evict_mask,
+                    teles[s], ring = O.obs_tick(
+                        obs.op, sps[s], teles[s], ring, i=i, j=i,
+                        is_tick=is_tick, pw=pw[sl], eff=p.eff, dt=p.dt,
+                        b=O.DevSnap(*(x[sl] for x in b)), sb=sbs[s],
+                        assign_mask=assign[sl], assign_wl=assign_wl[sl],
+                        evict_mask=evict_mask[sl],
                         fs=_slice_state(dev, sl), ss=sss[s],
                         power=p.power, cs=obs.cs,
                         trace_index=p.trace_index[sl],
                         phase=None if p.phase is None else p.phase[sl],
                         T=p.T, xp=np)
-        sched.state = sched_state_from_tuple(tuple(
-            np.stack([np.asarray(getattr(ss_, f)) for ss_ in sss])
-            for f in _sched.SCHED_FIELDS))
+        if K == 1:
+            sched._store(sss[0])
+        else:
+            sched.state = sched_state_from_tuple(tuple(
+                np.stack([np.asarray(getattr(ss, f)) for ss in sss])
+                for f in _sched.SCHED_FIELDS))
         if obs is not None:
             obs.tele = tele_from_tuple(tuple(
                 np.asarray(o) + sum(np.asarray(tl[k]) for tl in teles)
                 for k, o in enumerate(tele_as_tuple(obs.tele))))
+            if ring is not None:
+                obs.ring = ring_from_tuple(ring)
 
 
-def _run_fleet_numpy_sharded(pool: FleetWorkerPool,
-                             sched: FleetScheduler,
-                             stream: RequestStream, n_steps: int,
-                             dispatch_every: int, obs) -> dict:
-    """Whole-trace entry over :class:`_ShardedHostServe` — one window
-    covering the full serve trace (the offline reference cadence)."""
-    serve = _ShardedHostServe(pool, sched, dispatch_every, obs)
-    serve.window(stream.counts_matrix(sched.params.W)[:n_steps], 0)
-    return sched.summary(n_steps * pool.dt)
+_FS = collections.namedtuple("_FS", STATE_FIELDS)
 
 
-def _run_fleet_numpy_window(pool: FleetWorkerPool,
-                            sched: FleetScheduler, counts: np.ndarray,
-                            i0: int, dispatch_every: int, obs) -> None:
-    """One chunk of the unsharded NumPy reference loop: serve ticks
-    ``[i0, i0 + counts.shape[0])`` with per-tick arrival counts
-    ``counts`` ((k, W) int64). Identical per-tick cadence to
-    :func:`run_fleet`'s host loop — admission takes the count row
-    directly (``submit`` reduces workload ids to exactly this bincount,
-    and an all-zero row is the same no-op as an empty arrival slice),
-    and the tick index stays GLOBAL so harvest columns, dispatch/evict
-    phase, and shed deadlines are chunk-invariant."""
-    counts = np.asarray(counts, dtype=np.int64)
-    dt = pool.dt
-    for j in range(counts.shape[0]):
-        i = i0 + j
-        t = i * dt
-        if obs is not None:
-            obs.host_begin(pool.state, sched.state)
-        c = counts[j]
-        if c.any():
-            sched._store(_sched.admit(sched.params, sched._ss(), c,
-                                      float(t), np))
-        tick = i % dispatch_every == 0
-        if tick:
-            sched.dispatch(t, i)
-            if obs is not None:
-                obs.host_after_dispatch(pool.state)
-        pool.step(i)
-        if obs is not None:
-            obs.host_before_evict(pool.state)
-        sched.collect(t, evict=tick)
-        if obs is not None:
-            obs.host_end(i, tick, pool.state, sched.state)
+def _slice_state(s, sl: slice) -> _FS:
+    """One shard's view of the (N,) struct-of-arrays device state."""
+    return _FS(*(getattr(s, f)[sl] for f in STATE_FIELDS))
 
 
 class StreamClient:
@@ -597,12 +458,12 @@ def run_fleet_stream(pool: FleetWorkerPool, sched: FleetScheduler,
     (``i0 = pool.steps_done`` keeps harvest columns and obs indices
     global); equal-size chunks reuse a single compiled function, and a
     causal refit between chunks swaps only the runtime ``FC_*``
-    tables — no re-trace. With a NumPy pool the chunk runs through the
-    per-tick reference loop (sharded pools through the
-    :class:`_ShardedHostServe` window driver). When the arrival rows
-    are identical and ``refit_every`` is 0, the chunked run is
-    **bit-exact** with the whole-trace launch on every summary field —
-    the differential suite in tests/test_streaming.py pins this.
+    tables — no re-trace. With a NumPy pool each chunk is one
+    :class:`_HostServe` window, the per-tick reference for one or K
+    shards. When the arrival rows are identical and ``refit_every`` is
+    0, the chunked run is **bit-exact** with the one-chunk run
+    (:func:`run_fleet`) on every summary field — the differential suite
+    in tests/test_streaming.py pins this.
 
     ``refit_every`` (ticks; 0 = off) triggers
     :meth:`FleetScheduler.refit_forecast` at the first chunk boundary
@@ -632,11 +493,8 @@ def run_fleet_stream(pool: FleetWorkerPool, sched: FleetScheduler,
         raise ValueError(f"chunk_ticks={chunk_ticks} must be positive")
     dt = pool.dt
     sp = sched.params
-    is_jax = getattr(pool, "backend", "numpy") == "jax"
-    sharded = sched.params.shards > 1
-    host_serve = None
-    if not is_jax and sharded:
-        host_serve = _ShardedHostServe(pool, sched, dispatch_every, obs)
+    host = (None if pool.backend == "jax"
+            else _HostServe(pool, sched, dispatch_every, obs))
     counts_all = None
     if not hasattr(source, "take"):
         counts_all = source.counts_matrix(sp.W)[:n_steps]
@@ -656,15 +514,12 @@ def run_fleet_stream(pool: FleetWorkerPool, sched: FleetScheduler,
             with span("fleet.stream.snapshot", chunk=c):
                 before = _chunk_snapshot(sched.state)
             t0 = time.perf_counter()
-            if is_jax:
+            if host is None:
                 pool.run_serve(sched, counts,
                                dispatch_every=dispatch_every, obs=obs,
                                chunk=c)
-            elif sharded:
-                host_serve.window(counts, done)
             else:
-                _run_fleet_numpy_window(pool, sched, counts, done,
-                                        dispatch_every, obs)
+                host.window(counts, done)
             wall = time.perf_counter() - t0
             with span("fleet.stream.snapshot", chunk=c):
                 after = _chunk_snapshot(sched.state)

@@ -12,11 +12,13 @@ loop. The per-tick transition itself lives in pluggable backends:
   expression, so a 1-worker pool reproduces the scalar results exactly
   (pinned by tests/test_fleet.py).
 - ``backend="jax"``: ``repro.fleet.backend_jax``, the same transition as
-  a single ``jax.lax.scan`` over the whole trace (float64), built for
-  >=100k-worker fleets in one accelerator launch. Counts agree exactly
-  with the NumPy reference (pinned by tests/test_fleet_backends.py);
-  per-result ``results[w]`` records are a NumPy-backend-only feature —
-  the JAX path reports the aggregate emission counters instead.
+  a single ``jax.lax.scan`` over the whole trace, built for
+  >=100k-worker fleets in one accelerator launch: :meth:`step_macro` in
+  local mode, :meth:`run_serve` (with the scheduler in the scan) in
+  dispatch mode. Counts agree exactly with the NumPy reference (pinned
+  by tests/test_fleet_backends.py); per-result ``results[w]`` records
+  are a NumPy-backend-only feature — the JAX path reports the aggregate
+  emission counters instead.
 
 Two request modes:
 
@@ -25,9 +27,8 @@ Two request modes:
   workers baseline, and the mode the scalar-agreement test uses.
 - ``dispatch``: workers are idle until a scheduler assigns them a request
   (or a batch of requests) via :meth:`assign`; emissions and losses are
-  reported as events the scheduler consumes via :meth:`pop_events`
-  (the JAX backend materializes them as fixed-capacity arrays per
-  macro-step and decodes them here).
+  reported as events the NumPy host serve consumes via
+  :meth:`pop_events` (the JAX serve scan consumes them in-scan).
 
 Heterogeneous fleets: pass per-worker ``capacitance_f`` / ``v_max``
 arrays to mix capacitor sizes across the fleet (both backends support it;
@@ -126,7 +127,6 @@ class FleetWorkerPool:
                  v_max: np.ndarray | float | None = None,
                  active_power_w: np.ndarray | float | None = None,
                  backend: str = "numpy",
-                 use_pallas: bool = False,
                  kernel: str = "xla",
                  fleet_placement: str = "mesh",
                  persist: str = "none",
@@ -200,7 +200,6 @@ class FleetWorkerPool:
             COMMIT_J=COMMIT_J)
         self.state = init_state(n, quantized=kernel != "xla")
         self.backend = backend
-        self.use_pallas = use_pallas
         self.kernel = kernel
         # sharded-serve evaluation: "mesh" (shard_map over a real fleet
         # mesh; raises without K devices) or "single" (one-device vmap,
@@ -303,54 +302,49 @@ class FleetWorkerPool:
     def step(self, i: int) -> None:
         """Advance all N workers by one dt (trace index ``i``) through the
         NumPy reference transition (single-tick stepping is host-side by
-        definition; the JAX backend accelerates :meth:`step_macro`)."""
+        definition)."""
         backend_numpy.tick(self.params, self.state, i, self.results,
                            self.events)
         self.steps_done = i + 1
 
     def step_macro(self, i0: int, n_ticks: int) -> None:
-        """Advance ``n_ticks`` ticks starting at trace index ``i0`` as one
-        device macro-step: the JAX backend runs them as a single fused
-        ``lax.scan`` launch and materializes dispatch events into the
-        ``events`` list; the NumPy backend loops :meth:`step`."""
-        if self.backend == "jax":
-            if self._jax is None:
-                from repro.fleet.backend_jax import JaxFleetBackend
-                self._jax = JaxFleetBackend(
-                    self.params, use_pallas=self.use_pallas,
-                    kernel=self.kernel,
-                    fleet_placement=self.fleet_placement,
-                    interpret=self.interpret)
-            self.state, events = self._jax.run(self.state, i0, n_ticks)
-            self.events.extend(events)
-            self.steps_done = i0 + n_ticks
-        else:
+        """Advance ``n_ticks`` ticks starting at trace index ``i0``: a
+        JAX local-mode pool runs them as one ``lax.scan`` launch; the
+        NumPy backend loops :meth:`step`. A JAX dispatch-mode pool
+        advances only with its scheduler, through :meth:`run_serve`."""
+        if self.backend == "numpy":
             for i in range(i0, i0 + n_ticks):
                 self.step(i)
+            return
+        self.state = self._jax_backend().run(self.state, i0, n_ticks)
+        self.steps_done = i0 + n_ticks
+
+    def _jax_backend(self):
+        """The lazily-built ``JaxFleetBackend`` of a JAX pool."""
+        if self._jax is None:
+            from repro.fleet.backend_jax import JaxFleetBackend
+            self._jax = JaxFleetBackend(
+                self.params, kernel=self.kernel,
+                fleet_placement=self.fleet_placement,
+                interpret=self.interpret)
+        return self._jax
 
     def run_serve(self, sched, arrivals: np.ndarray, *,
                   dispatch_every: int = 10, obs=None,
                   chunk: int = 0) -> None:
         """Fused serve: device physics AND the array-native scheduler as
         one ``lax.scan`` launch (JAX backend only; the NumPy reference
-        drives the same control-plane expressions tick-by-tick through
-        ``repro.fleet.scheduler.run_fleet``). ``sched`` is a
+        drives the same control-plane expressions tick by tick in
+        ``repro.fleet.scheduler``'s host window). ``sched`` is a
         ``FleetScheduler``; its state is advanced in place. ``obs`` (a
         ``repro.obs.FleetObs``) rides the scan carry and is updated in
         place — the serve results are bit-identical with or without it.
         ``chunk`` tags the launch's host spans (the stream's chunk
         index)."""
         if self.backend != "jax":
-            raise ValueError("run_serve is the fused jax path; use "
-                             "run_fleet's per-tick driver for numpy pools")
-        if self._jax is None:
-            from repro.fleet.backend_jax import JaxFleetBackend
-            self._jax = JaxFleetBackend(
-                self.params, use_pallas=self.use_pallas,
-                kernel=self.kernel,
-                fleet_placement=self.fleet_placement,
-                interpret=self.interpret)
-        self.state, sched.state = self._jax.run_serve(
+            raise ValueError("run_serve is the fused jax path; numpy "
+                             "pools serve through run_fleet's host window")
+        self.state, sched.state = self._jax_backend().run_serve(
             self.state, sched.params, sched.state, arrivals,
             i0=self.steps_done, dispatch_every=dispatch_every, obs=obs,
             chunk=chunk)

@@ -8,7 +8,6 @@
 # documenting one that does not exist) fails CI.
 KERNELS: tuple[str, ...] = (
     "anytime_svm",
-    "fleet_step",
     "harris",
     "perforated_attention",
     "rwkv6_wkv",

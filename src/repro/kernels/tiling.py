@@ -3,10 +3,9 @@
 Every fleet kernel views the flat (N,) worker axis as a (rows, LANES)
 matrix and tiles it (block_rows, LANES) per grid step. N is rarely a
 whole number of tiles, so each kernel pads up to the tile grid on the
-way in and slices the pad lanes off on the way out. That pad/reshape
-arithmetic used to live copy-pasted inside ``fleet_step``; it is lifted
-here so ``serve_tick`` (and any future fleet kernel) reuses one
-implementation.
+way in and slices the pad lanes off on the way out; ``serve_tick``
+(and any future fleet kernel) reuses this one implementation of that
+pad/reshape arithmetic.
 
 Pad lanes must stay *inert* through a kernel — callers choose the fill
 value per array so padded workers never wake, never hold work, and never

@@ -328,7 +328,8 @@ def main(argv: list[str] | None = None) -> dict:
                     default="both")
     ap.add_argument("--backend", choices=("numpy", "jax"), default="numpy",
                     help="worker-pool backend: numpy reference lockstep or "
-                         "jax lax.scan macro-steps")
+                         "one jax lax.scan launch (the fused serve scan "
+                         "with the scheduler on)")
     ap.add_argument("--kernel", choices=("xla", "q32", "pallas"),
                     default="xla",
                     help="serve-tick kernel: float64 XLA expression chain "

@@ -6,7 +6,7 @@ Four pieces (see docs/observability.md):
   telemetry channels (:class:`TeleState`), per-worker event rings
   (:class:`RingState`), and the frozen :class:`ObsParams` config.
 - ``repro.obs.telemetry`` — the shared xp-generic tick update both
-  backends evaluate (NumPy host hooks / traced into the JAX scan) and
+  backends evaluate (the NumPy host window / traced into the JAX scan) and
   the :class:`FleetObs` host recorder.
 - ``repro.obs.export`` — Chrome trace-event / Perfetto JSON export and
   terminal summaries of the drained rings.

@@ -1,8 +1,8 @@
 """Shared xp-generic telemetry expressions + the host-side recorder.
 
 One tick-update function (:func:`obs_tick`) evaluated by both serve
-paths: the NumPy reference driver calls it with ``xp=numpy`` from the
-``run_fleet`` host loop (via :class:`FleetObs`), and
+paths: the NumPy host window (``repro.fleet.scheduler._HostServe``)
+calls it with ``xp=numpy`` every tick, and
 ``backend_jax._build_serve`` traces the identical expressions inside the
 fused ``lax.scan`` (telemetry and ring arrays ride the scan carry).
 Everything it accumulates is an int64 sum of per-worker integer
@@ -29,8 +29,7 @@ from repro.obs.state import (EV_ACQUIRE, EV_ADMIT, EV_ASSIGN, EV_BROWN,
                              EV_COMPLETE, EV_EMIT, EV_EVICT, EV_LOST,
                              EV_REJECT, EV_REQUEUE, EV_SHED, EV_WAKE,
                              TELE_FIELDS, ObsParams, init_ring,
-                             init_tele, ring_as_tuple, ring_from_tuple,
-                             tele_as_tuple, tele_from_tuple)
+                             init_tele)
 
 # tick-start snapshots: the before-side of every delta obs_tick takes.
 # DevSnap copies the handful of device arrays a tick mutates; SchedSnap
@@ -370,12 +369,13 @@ def obs_tick(op: ObsParams, sp, tele: tuple, ring: tuple | None, *, i, j,
 class FleetObs:
     """Host handle over one instrumented serve run.
 
-    Owns the telemetry/ring arrays and the begin/after-dispatch/
-    before-evict/end hooks the NumPy ``run_fleet`` loop calls around
-    each tick; the fused JAX path bypasses the hooks and threads the
-    same arrays through the scan carry (``backend_jax.run_serve`` writes
-    them back here). ``summary()`` is the JSON-able channel dump the
-    CLIs attach to their run summaries — two runs' summaries compare
+    Owns the telemetry/ring arrays that both serve paths advance with
+    :func:`obs_tick`: the NumPy host window
+    (``repro.fleet.scheduler._HostServe``) evaluates it per tick and
+    writes the arrays back here at each window's end; the fused JAX
+    path threads them through the scan carry (``backend_jax.run_serve``
+    writes them back here). ``summary()`` is the JSON-able channel dump
+    the CLIs attach to their run summaries — two runs' summaries compare
     bit-exactly with ``==``.
     """
 
@@ -389,49 +389,6 @@ class FleetObs:
         self.tele = init_tele(op)
         self.ring = init_ring(op) if op.mode == "trace" else None
         self.cs = power_cumsum(params.power) if sp.forecast else None
-        self._b = None
-        self._sb = None
-        self._assign = np.zeros(op.n, dtype=bool)
-        self._assign_wl = np.zeros(op.n, dtype=np.int64)
-        self._pre_evict = np.zeros(op.n, dtype=bool)
-
-    # -- NumPy driver hooks (run_fleet reference loop) ----------------------
-
-    def host_begin(self, fs, ss) -> None:
-        """Tick start, before submit/dispatch: snapshot the deltas'
-        before-side."""
-        self._b = dev_snap(fs, copy=True)
-        self._sb = sched_snap(ss, np)
-        self._assign = np.zeros(self.op.n, dtype=bool)
-
-    def host_after_dispatch(self, fs) -> None:
-        """After the dispatch pass (dispatch ticks only): the
-        ``p_pending`` rising edge is this tick's assignment set."""
-        self._assign = fs.p_pending & ~self._b.p_pending
-        self._assign_wl = fs.p_wl.copy()
-
-    def host_before_evict(self, fs) -> None:
-        """After the device tick, before collect/evict: snapshot who
-        still holds an assignment (the evict pass's falling edge)."""
-        self._pre_evict = fs.p_pending | fs.has_work
-
-    def host_end(self, i: int, is_tick: bool, fs, ss) -> None:
-        """Tick end: evaluate the shared update with ``xp=numpy``."""
-        p = self.p
-        col = (i % p.T) if p.phase is None else (i + p.phase) % p.T
-        pw = p.power[p.trace_index, col]
-        evict_mask = self._pre_evict & ~(fs.p_pending | fs.has_work)
-        ring = ring_as_tuple(self.ring) if self.ring is not None else None
-        tele, ring = obs_tick(
-            self.op, self.sp, tele_as_tuple(self.tele), ring, i=i, j=i,
-            is_tick=is_tick, pw=pw, eff=p.eff, dt=p.dt, b=self._b,
-            sb=self._sb, assign_mask=self._assign,
-            assign_wl=self._assign_wl, evict_mask=evict_mask, fs=fs,
-            ss=ss, power=p.power, cs=self.cs,
-            trace_index=p.trace_index, phase=p.phase, T=p.T, xp=np)
-        self.tele = tele_from_tuple(tele)
-        if ring is not None:
-            self.ring = ring_from_tuple(ring)
 
     # -- reporting ----------------------------------------------------------
 

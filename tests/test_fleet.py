@@ -8,7 +8,8 @@ from repro.core.energy import Capacitor, get_trace
 from repro.core.intermittent import IntermittentExecutor
 from repro.core.policies import Greedy, Smart
 from repro.core.profile_tables import harris_cost_table
-from repro.fleet.scheduler import FleetScheduler, RequestStream, run_fleet
+from repro.fleet.scheduler import (FleetScheduler, RequestStream,
+                                   _HostServe, run_fleet)
 from repro.fleet.worker import FleetWorkerPool, stack_traces
 from repro.fleet.workloads import har_workload, harris_workload, lm_workload
 from repro.launch.fleet import (build_dispatch_pool, make_power_matrix,
@@ -196,20 +197,26 @@ def test_straggler_eviction_requeues_pending_on_dead_worker():
     pool.v[0] = pool.v_on
     sched = FleetScheduler(pool, [wl], grace_s=5.0, max_retries=0,
                            shed_after_s=1e9)
-    sched.submit(0.0, np.array([0]))
-    sched.dispatch(0.0, 0)
-    assert pool.p_pending[0]
+    serve = _HostServe(pool, sched, dispatch_every=10, obs=None)
+    step = pool.step
+
+    def brown_out_then_step(i):
+        if i == 0:
+            # ...but browns out between dispatch and acquire: the
+            # assignment is stuck
+            assert pool.p_pending[0]
+            pool.on[0] = False
+            pool.v[0] = pool.v_off
+        step(i)
+
+    pool.step = brown_out_then_step
+    serve.window(np.ones((1, 1), dtype=np.int64), 0)  # tick 0 dispatches
     assert sched.inflight_count == 1
-    # ...but browns out before acquiring: the assignment is stuck
-    pool.on[0] = False
-    pool.v[0] = pool.v_off
     t_fire = None
-    for i in range(12000):
-        t = i * DT
-        pool.step(i)
-        sched.collect(t, evict=(i % 10 == 0))
+    for i0 in range(1, 12000, 10):
+        serve.window(np.zeros((10, 1), dtype=np.int64), i0)
         if sched.inflight_count == 0:
-            t_fire = t
+            t_fire = i0 * DT
             break
     assert t_fire is not None, "assignment never evicted"
     assert int(sched.state.evicted) == 1

@@ -32,7 +32,7 @@ def _acc41():
 
 def _local_pair(power, n_workers, policy, *, duration_ticks=None, cap=None,
                 capacitance_f=None, v_max=None, active_power_w=None,
-                seed=0, use_pallas=False):
+                seed=0):
     rng = np.random.default_rng(seed)
     kw = dict(workloads=[_costs40()], policy=policy,
               accuracy_table=_acc41(), mode="local",
@@ -42,8 +42,7 @@ def _local_pair(power, n_workers, policy, *, duration_ticks=None, cap=None,
               cap=cap, capacitance_f=capacitance_f, v_max=v_max,
               active_power_w=active_power_w)
     a = FleetWorkerPool(power, DT, backend="numpy", **kw)
-    b = FleetWorkerPool(power, DT, backend="jax", use_pallas=use_pallas,
-                        interpret=use_pallas, **kw)
+    b = FleetWorkerPool(power, DT, backend="jax", **kw)
     sa = a.run(duration_ticks)
     sb = b.run(duration_ticks)
     return a, b, sa, sb
@@ -227,6 +226,18 @@ def _assert_sched_agreement(out):
     assert np.array_equal(pa.state.e_work, pb.state.e_work)
 
 
+def test_jax_dispatch_pool_has_no_host_macro_step():
+    """A dispatch-mode jax pool advances only with its scheduler, in the
+    fused serve scan: the host-scheduled macro-step raises and names
+    run_serve."""
+    wls = [har_workload(), lm_workload()]
+    power = make_power_matrix(["SOM"], 1, 10.0, DT, seed=5)
+    pool = build_dispatch_pool(power, DT, 4, wls, 5, backend="jax")
+    with pytest.raises(ValueError, match="run_serve"):
+        pool.step_macro(0, 10)
+    assert pool.steps_done == 0
+
+
 @pytest.mark.parametrize("sched", ["reactive", "forecast"])
 def test_fused_sched_single_worker_matches_host_ticks(sched):
     wls = [har_workload(), lm_workload()]
@@ -335,39 +346,6 @@ def test_forecaster_closed_forms():
     g = forecast_gain(np.array([1e-9, 0.5, 1.0]), 100)
     assert g[0] > 0.99 and g[2] < 0.02
     assert 0.0 < g[1] < g[0]
-
-
-# ---------------------------------------------------------------------------
-# pallas harvest kernel (interpret mode on CPU hosts)
-# ---------------------------------------------------------------------------
-
-
-def test_pallas_harvest_kernel_matches_reference():
-    import jax.numpy as jnp
-
-    from repro.core.energy import capacitor_harvest
-    from repro.kernels.fleet_step import harvest_step
-
-    rng = np.random.default_rng(0)
-    n = 1000  # deliberately not a tile multiple: exercises padding
-    v = rng.uniform(0.0, 3.6, n).astype(np.float32)
-    p = rng.uniform(0.0, 1e-3, n).astype(np.float32)
-    C, vmax = hetero_capacitors(n, seed=1)
-    C = C.astype(np.float32)
-    vmax = vmax.astype(np.float32)
-    out = harvest_step(jnp.asarray(v), jnp.asarray(p), jnp.asarray(C),
-                       jnp.asarray(vmax), eff=0.8, dt=0.01, interpret=True)
-    ref = capacitor_harvest(v, p, np.float32(0.01), capacitance_f=C,
-                            booster_eff=np.float32(0.8), v_max=vmax)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-6)
-
-
-def test_pallas_path_pool_agrees_on_counts():
-    power = power_matrix(["SOM", "RF"], 4, 60.0, DT, seed=5)
-    a, b, sa, sb = _local_pair(power, 8, Greedy(), seed=5, use_pallas=True)
-    assert sa.emitted == sb.emitted
-    assert sa.skipped == sb.skipped
-    assert sa.power_cycles == sb.power_cycles
 
 
 # ---------------------------------------------------------------------------

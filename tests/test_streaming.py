@@ -486,26 +486,32 @@ class TestStreamingServe:
                                    chunk_ticks=300)
         assert _blob(whole) == _blob(chunked)
 
-    def test_obs_tele_chunked_equality(self):
+    @pytest.mark.parametrize("mode", ["tele", "trace"])
+    def test_obs_tele_chunked_equality(self, mode):
         # the in-scan telemetry plane sees GLOBAL tick indices from
-        # every chunk: windowed channels fill identically whether the
-        # trace runs as one launch, many launches, or the host loop
+        # every chunk: windowed channels (and, in trace mode, the event
+        # ring) fill identically whether the trace runs as one launch,
+        # many launches, or the host window across chunks
         from repro.obs import make_fleet_obs
-        from repro.obs.state import tele_as_tuple
+        from repro.obs.state import ring_as_tuple, tele_as_tuple
 
         def run(backend, chunk):
             pool, sch, stream, n_steps = _mk_serve(backend)
-            obs = make_fleet_obs("tele", pool.params, sch.params,
+            obs = make_fleet_obs(mode, pool.params, sch.params,
                                  n_steps, window=100)
             if chunk:
                 run_fleet_stream(pool, sch, stream, n_steps,
                                  chunk_ticks=chunk, obs=obs)
             else:
                 run_fleet(pool, sch, stream, n_steps, obs=obs)
-            return tele_as_tuple(obs.tele)
+            ring = () if obs.ring is None else ring_as_tuple(obs.ring)
+            return tele_as_tuple(obs.tele) + tuple(ring)
 
         whole = run("jax", 0)
+        if mode == "trace":
+            assert int(np.asarray(whole[-1]).sum()) > 0  # events pushed
         for got in (run("jax", 300), run("numpy", 300)):
+            assert len(got) == len(whole)
             for a, b in zip(whole, got):
                 np.testing.assert_array_equal(np.asarray(a),
                                               np.asarray(b))
